@@ -1,0 +1,24 @@
+"""Published per-chip peaks, keyed by JAX's ``device_kind``.
+
+Source: Google Cloud documentation, "TPU v5e": 16 GB of HBM at 819 GB/s,
+197 TFLOP/s bf16, 393 TOP/s int8 (the table of ``ops/device_bench.py``,
+PR 21, copied here so that the program cannot change the yardstick). A
+device kind that is not here is an error, never a default.
+"""
+
+from __future__ import annotations
+
+PEAKS = {
+    "TPU v5 lite": {
+        "hbm_bytes_per_s": 819e9,
+        "bf16_flop_per_s": 197e12,
+        "int8_op_per_s": 393e12,
+        "hbm_bytes": 16e9,
+    },
+}
+
+
+def peak(device_kind: str, what: str) -> float:
+    if device_kind not in PEAKS:
+        raise KeyError(f"no published peaks for device kind {device_kind!r}")
+    return PEAKS[device_kind][what]

@@ -1,0 +1,137 @@
+"""The plain reference of the TPC-H deployments: the query shapes and
+the covering index, in numpy over the generated tables, importing
+nothing of the program and taking nothing it made.
+
+``dtype`` is the precision of the DECIMAL columns. The configuration
+states float64; the control computes the same reference in float32.
+"""
+
+from __future__ import annotations
+
+from pathlib import Path
+
+import numpy as np
+
+SEED = np.uint32(0x9E3779B9)
+
+
+def columns_of(table) -> dict:
+    """{column: numpy array} of a pyarrow table's numeric and date
+    columns, dates as int32 days (no query shape or index here reads a
+    string column)."""
+    import pyarrow as pa
+
+    out = {}
+    for name in table.column_names:
+        arr = table.column(name)
+        if pa.types.is_string(arr.type):
+            continue
+        if pa.types.is_date32(arr.type):
+            arr = arr.cast(pa.int32())
+        out[name] = arr.to_numpy()
+    return out
+
+
+def concat(tables) -> dict:
+    cols = [columns_of(t) for t in tables]
+    return {c: np.concatenate([d[c] for d in cols]) for c in cols[0]}
+
+
+class Reference:
+    def __init__(self, lineitem: dict, dtype=np.float64):
+        self.li = {k: v.astype(dtype) if v.dtype.kind == "f" else v for k, v in lineitem.items()}
+        self.dtype = dtype
+        self._li_order = None
+
+    # -- the query shapes ----------------------------------------------------
+    def point(self, key: int) -> dict:
+        if self._li_order is None:
+            o = np.argsort(self.li["l_orderkey"], kind="stable")
+            self._li_order = (o, self.li["l_orderkey"][o])
+        o, keys = self._li_order
+        lo, hi = np.searchsorted(keys, key, "left"), np.searchsorted(keys, key, "right")
+        rows = o[lo:hi]
+        cols = ["l_orderkey", "l_partkey", "l_quantity", "l_extendedprice", "l_shipdate"]
+        return {c: self.li[c][rows] for c in cols}
+
+    def answer(self, shape: str, params: dict) -> dict:
+        if shape == "point":
+            return self.point(int(params["key"]))
+        raise KeyError(f"unknown query shape {shape!r}")
+
+
+# -- the covering index ------------------------------------------------------
+def _fmix32(h):
+    h = h.astype(np.uint32)
+    h ^= h >> np.uint32(16)
+    h = (h * np.uint32(0x85EBCA6B)).astype(np.uint32)
+    h ^= h >> np.uint32(13)
+    h = (h * np.uint32(0xC2B2AE35)).astype(np.uint32)
+    h ^= h >> np.uint32(16)
+    return h
+
+
+def bucket_of(keys: np.ndarray, num_buckets: int) -> np.ndarray:
+    """The index's bucket of each int64 key: the murmur3 finalizer over
+    the key's two 32-bit halves, seeded 0x9E3779B9, modulo the bucket
+    count (docs/03's bucketing rule; hyperspace_tpu/ops/hashing.py)."""
+    u = keys.astype(np.int64).view(np.uint64)
+    lo = (u & np.uint64(0xFFFFFFFF)).astype(np.uint32)
+    hi = (u >> np.uint64(32)).astype(np.uint32)
+    with np.errstate(over="ignore"):
+        h = _fmix32(np.full(len(keys), SEED, np.uint32) ^ _fmix32(lo ^ _fmix32(hi)))
+    return (h % np.uint32(num_buckets)).astype(np.int64)
+
+
+def _row_hashes(cols: dict, names) -> np.ndarray:
+    """A 64-bit hash of each row's bit patterns (splitmix64 finalizer
+    folded over the columns)."""
+    h = np.zeros(len(cols[names[0]]), np.uint64)
+    with np.errstate(over="ignore"):
+        for c in names:
+            v = cols[c]
+            bits = v.astype(np.float64).view(np.uint64) if v.dtype.kind == "f" else v.astype(np.int64).view(np.uint64)
+            h = (h ^ bits) * np.uint64(0x9E3779B97F4A7C15)
+            h ^= h >> np.uint64(30)
+            h *= np.uint64(0xBF58476D1CE4E5B9)
+            h ^= h >> np.uint64(27)
+            h *= np.uint64(0x94D049BB133111EB)
+            h ^= h >> np.uint64(31)
+    return h
+
+
+def rows_wrong(got: dict, want: dict, names) -> int:
+    """Rows of ``want`` that ``got`` does not hold, as multisets of rows
+    (by a 64-bit hash of each row's values; a wrong row that matched a
+    right one's hash is a 2**-64 chance), plus rows ``got`` holds beyond
+    ``want``'s count."""
+    ug, cg = np.unique(_row_hashes(got, names), return_counts=True)
+    uw, cw = np.unique(_row_hashes(want, names), return_counts=True)
+    _, ig, iw = np.intersect1d(ug, uw, assume_unique=True, return_indices=True)
+    held = int(np.minimum(cg[ig], cw[iw]).sum())
+    n_got, n_want = int(cg.sum()), int(cw.sum())
+    return (n_want - held) + max(n_got - n_want, 0)
+
+
+def index_numbers(files, source: dict, indexed: str, included, num_buckets: int) -> dict:
+    """What one built covering index holds against the source rows:
+    ``rows_wrong`` rows of the source not held exactly (or held too
+    often), ``rows_misbucketed`` rows in a bucket their key does not hash
+    to, ``order_breaks`` adjacent rows of one bucket out of key order."""
+    from benchmark.common import tcb
+
+    names = [indexed, *included]
+    parts, misbucketed, breaks = [], 0, 0
+    for f in files:
+        cols, footer = tcb.read(Path(f), names)
+        k = cols[indexed]
+        misbucketed += int(np.count_nonzero(bucket_of(k, num_buckets) != tcb.bucket_of_file(footer, f)))
+        breaks += int(np.count_nonzero(k[1:] < k[:-1]))
+        parts.append(cols)
+    got = {c: np.concatenate([p[c] for p in parts]) for c in names} if parts else {
+        c: source[c][:0] for c in names}
+    return {
+        "rows_wrong": rows_wrong(got, source, names),
+        "rows_misbucketed": misbucketed,
+        "order_breaks": breaks,
+    }
