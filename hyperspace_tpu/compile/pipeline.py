@@ -229,15 +229,10 @@ class CompiledPipeline:
                 entry.num_buckets,
             )
             try:
-                with span(
-                    "scan.device_dispatch",
-                    tier=getattr(table, "tier", "resident"),
-                    structure_keyed=True,
-                    mesh=table.n_devices,
-                ):
-                    m = mesh_cache.block_counts_batch(
-                        table, [predicate], metric_ns="compile.fused"
-                    )
+                # the mesh cache opens the scan.device_dispatch span
+                m = mesh_cache.block_counts_batch(
+                    table, [predicate], metric_ns="compile.fused"
+                )
                 counts = None if m is None else m[0]
             except Exception:  # noqa: BLE001 - device loss degrades
                 mesh_cache.drop(table)

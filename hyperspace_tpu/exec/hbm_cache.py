@@ -85,6 +85,23 @@ from ..ops.kernels import MASK_BLOCK_SUBLANES as _MASK_SUBLANES  # noqa: E402
 _TILE_ELEMS = _MASK_SUBLANES * _LANES
 
 
+def dispatch_span(table, n: int, structure_keyed: bool, **labels):
+    """The ``scan.device_dispatch`` span of one resident mask+count
+    dispatch of ``n`` stacked predicates: the program lookup and the
+    operand and literal gathering, the launch, and the wait until the
+    counts are on the host. It opens inside the residency caches, once
+    the predicates are known to narrow, so every caller's dispatch
+    carries the one name; its child ``scan.device_launch`` covers the
+    jitted call alone."""
+    return _trace_span(
+        "scan.device_dispatch",
+        n=n,
+        tier=getattr(table, "tier", "resident"),
+        structure_keyed=structure_keyed,
+        **labels,
+    )
+
+
 def _budget_bytes() -> int:
     from .bytecache import env_mb  # malformed env falls back, never raises
 
@@ -473,7 +490,7 @@ def _counts_fn(
     if use_pallas:
         inner = K._build_mask_call(narrowed, names, n_rows128)
 
-        def counts(cols):
+        def resident_counts(cols):
             m = inner(cols)
             return jnp.sum(
                 m.reshape(-1, BLOCK_ROWS).astype(jnp.int32), axis=1
@@ -484,14 +501,15 @@ def _counts_fn(
             {name: Column("int32", np.empty(0, dtype=np.int32)) for name in names}
         )
 
-        def counts(cols):
+        def resident_counts(cols):
             arrays = _flatten_operands(names, cols, specs)
             m = eval_mask(narrowed, shim, arrays)
             return jnp.sum(
                 m.reshape(-1, BLOCK_ROWS).astype(jnp.int32), axis=1
             )
 
-    fn = jax.jit(counts)
+    # the module is named jit_resident_counts in a device profile
+    fn = jax.jit(resident_counts)
     with _counts_fn_lock:
         if len(_counts_fn_cache) >= 256:
             _counts_fn_cache.pop(next(iter(_counts_fn_cache)))
@@ -631,7 +649,7 @@ def _batched_counts_fn(structures: tuple, slot_names: tuple, exprs: list,
     names_per_slot = list(slot_names)
     specs_by_name = dict(spec_map or ())
 
-    def batched(col_arrays: dict, lit_vecs: tuple):
+    def resident_counts_batched(col_arrays: dict, lit_vecs: tuple):
         union = tuple(col_arrays)
         flat_all = _flatten_operands(
             union,
@@ -647,7 +665,7 @@ def _batched_counts_fn(structures: tuple, slot_names: tuple, exprs: list,
             )
         return jnp.stack(outs)
 
-    fn = jax.jit(batched)
+    fn = jax.jit(resident_counts_batched)
     _batch_fns.put(key, fn)
     return fn
 
@@ -693,7 +711,7 @@ def _hybrid_counts_fn(
 
     if has_mask:
 
-        def counts(base_cols, delta_cols, del_mask):
+        def resident_hybrid_counts(base_cols, delta_cols, del_mask):
             mb = _side_counts(base_cols) & (del_mask.reshape(-1) == 0)
             cb = jnp.sum(
                 mb.reshape(-1, BLOCK_ROWS).astype(jnp.int32), axis=1
@@ -706,7 +724,7 @@ def _hybrid_counts_fn(
 
     else:
 
-        def counts(base_cols, delta_cols):
+        def resident_hybrid_counts(base_cols, delta_cols):
             mb = _side_counts(base_cols)
             cb = jnp.sum(
                 mb.reshape(-1, BLOCK_ROWS).astype(jnp.int32), axis=1
@@ -717,7 +735,7 @@ def _hybrid_counts_fn(
             )
             return jnp.concatenate([cb, cd])
 
-    fn = jax.jit(counts)
+    fn = jax.jit(resident_hybrid_counts)
     _hybrid_fns.put(key, fn)
     return fn
 
@@ -754,8 +772,9 @@ def _hybrid_batched_counts_fn(
     exprs = list(exprs)
     names_per_slot = list(slot_names)
 
-    def batched(base_arrays: dict, delta_arrays: dict, lit_vecs: tuple,
-                del_mask=None):
+    def resident_hybrid_counts_batched(
+        base_arrays: dict, delta_arrays: dict, lit_vecs: tuple, del_mask=None
+    ):
         outs = []
         live = (
             del_mask.reshape(-1) == 0 if del_mask is not None else None
@@ -776,7 +795,7 @@ def _hybrid_batched_counts_fn(
             outs.append(jnp.concatenate([cb, cd]))
         return jnp.stack(outs)
 
-    fn = jax.jit(batched)
+    fn = jax.jit(resident_hybrid_counts_batched)
     _hybrid_fns.put(key, fn)
     return fn
 
@@ -1696,25 +1715,25 @@ class HbmIndexCache(ResidentCacheBase):
         prepared = prepare_resident_predicate(table.columns, predicate)
         if prepared is None:
             return None
-        narrowed, names = prepared
-        specs = resident_specs_for(table.columns, names)
-        # the Pallas mask kernel reads raw planes only — packed words
-        # route through the XLA branch's fused decode
-        use_pallas = K.kernels_mode() != "off" and not any(specs)
-        fn = _counts_fn(
-            narrowed, names, table.n_pad // _LANES, use_pallas, specs
-        )
-        cols = resident_arrays_for(table.columns, names)
-        t0 = time.perf_counter()
-        with K._x32():
-            counts = np.asarray(fn(cols))
-        metrics.record_time("scan.resident.device", time.perf_counter() - t0)
-        if use_pallas:
-            metrics.incr("scan.path.pallas_mask")
-        n_blocks = -(-table.n_rows // BLOCK_ROWS)
-        metrics.incr("scan.resident.d2h_bytes", int(counts.nbytes))
-        _trace_bytes("d2h_bytes", int(counts.nbytes))
-        return counts[:n_blocks]
+        with dispatch_span(table, 1, False):
+            narrowed, names = prepared
+            specs = resident_specs_for(table.columns, names)
+            # the Pallas mask kernel reads raw planes only — packed words
+            # route through the XLA branch's fused decode
+            use_pallas = K.kernels_mode() != "off" and not any(specs)
+            fn = _counts_fn(
+                narrowed, names, table.n_pad // _LANES, use_pallas, specs
+            )
+            cols = resident_arrays_for(table.columns, names)
+            with K._x32(), _trace_span("scan.device_launch"):
+                pending = fn(cols)
+            counts = np.asarray(pending)
+            _trace_bytes("d2h_bytes", int(counts.nbytes))
+            if use_pallas:
+                metrics.incr("scan.path.pallas_mask")
+            n_blocks = -(-table.n_rows // BLOCK_ROWS)
+            metrics.incr("scan.resident.d2h_bytes", int(counts.nbytes))
+            return counts[:n_blocks]
 
     def block_counts_batch(
         self,
@@ -1749,42 +1768,48 @@ class HbmIndexCache(ResidentCacheBase):
             ]
         if any(p is None for p in prepared):
             return None
-        structures = tuple(_expr_structure(n) for n, _ in prepared)
-        slot_names = tuple(names for _, names in prepared)
-        # the union of every slot's (possibly plane-suffixed) columns,
-        # passed once — slots index into the shared dict
-        union_names = tuple(
-            dict.fromkeys(n for names in slot_names for n in names)
-        )
-        fn = _batched_counts_fn(
-            structures,
-            slot_names,
-            [n for n, _ in prepared],
-            table.n_pad // _LANES,
-            tuple(
-                zip(union_names, resident_specs_for(table.columns, union_names))
-            ),
-        )
-        cols = dict(
-            zip(union_names, resident_arrays_for(table.columns, union_names))
-        )
-        lit_vecs = []
-        for narrowed, _ in prepared:
-            vals: list = []
-            _expr_literals(narrowed, vals)
-            lit_vecs.append(np.asarray(vals, dtype=np.int32))
-        from ..ops import kernels as K
+        with dispatch_span(table, len(predicates), True):
+            structures = tuple(_expr_structure(n) for n, _ in prepared)
+            slot_names = tuple(names for _, names in prepared)
+            # the union of every slot's (possibly plane-suffixed) columns,
+            # passed once — slots index into the shared dict
+            union_names = tuple(
+                dict.fromkeys(n for names in slot_names for n in names)
+            )
+            fn = _batched_counts_fn(
+                structures,
+                slot_names,
+                [n for n, _ in prepared],
+                table.n_pad // _LANES,
+                tuple(
+                    zip(
+                        union_names,
+                        resident_specs_for(table.columns, union_names),
+                    )
+                ),
+            )
+            cols = dict(
+                zip(
+                    union_names,
+                    resident_arrays_for(table.columns, union_names),
+                )
+            )
+            lit_vecs = []
+            for narrowed, _ in prepared:
+                vals: list = []
+                _expr_literals(narrowed, vals)
+                lit_vecs.append(np.asarray(vals, dtype=np.int32))
+            from ..ops import kernels as K
 
-        t0 = time.perf_counter()
-        with K._x32():
-            counts = np.asarray(fn(cols, tuple(lit_vecs)))
-        metrics.record_time(f"{metric_ns}.device", time.perf_counter() - t0)
-        metrics.incr(f"{metric_ns}.dispatches")
-        metrics.incr(f"{metric_ns}.queries", len(predicates))
-        metrics.incr("scan.resident.d2h_bytes", int(counts.nbytes))
-        _trace_bytes("d2h_bytes", int(counts.nbytes))
-        n_blocks = -(-table.n_rows // BLOCK_ROWS)
-        return counts[:, :n_blocks]
+            with K._x32(), _trace_span("scan.device_launch"):
+                pending = fn(cols, tuple(lit_vecs))
+            counts = np.asarray(pending)
+            _trace_bytes("d2h_bytes", int(counts.nbytes))
+            metrics.incr(f"{metric_ns}.dispatches")
+            metrics.incr(f"{metric_ns}.queries", len(predicates))
+            metrics.incr("scan.resident.d2h_bytes", int(counts.nbytes))
+            n_blocks = -(-table.n_rows // BLOCK_ROWS)
+            return counts[:, :n_blocks]
 
     # -- delta residency (hybrid scan's appended side) -----------------------
     def delta_for(
@@ -2121,30 +2146,26 @@ class HbmIndexCache(ResidentCacheBase):
         narrowed, names = prepared
         if any(n.split("\x00", 1)[0] not in delta.columns for n in names):
             return None
-        fn = _hybrid_counts_fn(
-            narrowed,
-            names,
-            table.n_pad // _LANES,
-            delta.n_pad // _LANES,
-            delta.del_mask is not None,
-        )
-        bcols = resident_arrays_for(table.columns, names)
-        dcols = resident_arrays_for(delta.columns, names)
-        t0 = time.perf_counter()
-        with K._x32():
-            if delta.del_mask is not None:
-                counts = np.asarray(fn(bcols, dcols, delta.del_mask))
-            else:
-                counts = np.asarray(fn(bcols, dcols))
-        metrics.record_time(
-            "scan.resident_hybrid.device", time.perf_counter() - t0
-        )
-        metrics.incr("scan.resident.d2h_bytes", int(counts.nbytes))
-        _trace_bytes("d2h_bytes", int(counts.nbytes))
-        nb_pad = table.n_pad // BLOCK_ROWS
-        nb = -(-table.n_rows // BLOCK_ROWS)
-        nd = -(-delta.n_rows // BLOCK_ROWS)
-        return counts[:nb], counts[nb_pad : nb_pad + nd]
+        with dispatch_span(table, 1, False, hybrid=True):
+            fn = _hybrid_counts_fn(
+                narrowed,
+                names,
+                table.n_pad // _LANES,
+                delta.n_pad // _LANES,
+                delta.del_mask is not None,
+            )
+            bcols = resident_arrays_for(table.columns, names)
+            dcols = resident_arrays_for(delta.columns, names)
+            masks = (delta.del_mask,) if delta.del_mask is not None else ()
+            with K._x32(), _trace_span("scan.device_launch"):
+                pending = fn(bcols, dcols, *masks)
+            counts = np.asarray(pending)
+            _trace_bytes("d2h_bytes", int(counts.nbytes))
+            metrics.incr("scan.resident.d2h_bytes", int(counts.nbytes))
+            nb_pad = table.n_pad // BLOCK_ROWS
+            nb = -(-table.n_rows // BLOCK_ROWS)
+            nd = -(-delta.n_rows // BLOCK_ROWS)
+            return counts[:nb], counts[nb_pad : nb_pad + nd]
 
     def hybrid_block_counts_batch(
         self,
@@ -2179,47 +2200,49 @@ class HbmIndexCache(ResidentCacheBase):
             for n in names
         ):
             return None
-        structures = tuple(_expr_structure(n) for n, _ in prepared)
-        slot_names = tuple(names for _, names in prepared)
-        fn = _hybrid_batched_counts_fn(
-            structures,
-            slot_names,
-            [n for n, _ in prepared],
-            table.n_pad // _LANES,
-            delta.n_pad // _LANES,
-            delta.del_mask is not None,
-        )
-        union_names = tuple(
-            dict.fromkeys(n for names in slot_names for n in names)
-        )
-        bcols = dict(
-            zip(union_names, resident_arrays_for(table.columns, union_names))
-        )
-        dcols = dict(
-            zip(union_names, resident_arrays_for(delta.columns, union_names))
-        )
-        lit_vecs = []
-        for narrowed, _ in prepared:
-            vals: list = []
-            _expr_literals(narrowed, vals)
-            lit_vecs.append(np.asarray(vals, dtype=np.int32))
-        t0 = time.perf_counter()
-        with K._x32():
-            if delta.del_mask is not None:
-                counts = np.asarray(
-                    fn(bcols, dcols, tuple(lit_vecs), delta.del_mask)
+        with dispatch_span(table, len(predicates), True, hybrid=True):
+            structures = tuple(_expr_structure(n) for n, _ in prepared)
+            slot_names = tuple(names for _, names in prepared)
+            fn = _hybrid_batched_counts_fn(
+                structures,
+                slot_names,
+                [n for n, _ in prepared],
+                table.n_pad // _LANES,
+                delta.n_pad // _LANES,
+                delta.del_mask is not None,
+            )
+            union_names = tuple(
+                dict.fromkeys(n for names in slot_names for n in names)
+            )
+            bcols = dict(
+                zip(
+                    union_names,
+                    resident_arrays_for(table.columns, union_names),
                 )
-            else:
-                counts = np.asarray(fn(bcols, dcols, tuple(lit_vecs)))
-        metrics.record_time(f"{metric_ns}.device", time.perf_counter() - t0)
-        metrics.incr(f"{metric_ns}.dispatches")
-        metrics.incr(f"{metric_ns}.queries", len(predicates))
-        metrics.incr("scan.resident.d2h_bytes", int(counts.nbytes))
-        _trace_bytes("d2h_bytes", int(counts.nbytes))
-        nb_pad = table.n_pad // BLOCK_ROWS
-        nb = -(-table.n_rows // BLOCK_ROWS)
-        nd = -(-delta.n_rows // BLOCK_ROWS)
-        return [(c[:nb], c[nb_pad : nb_pad + nd]) for c in counts]
+            )
+            dcols = dict(
+                zip(
+                    union_names,
+                    resident_arrays_for(delta.columns, union_names),
+                )
+            )
+            lit_vecs = []
+            for narrowed, _ in prepared:
+                vals: list = []
+                _expr_literals(narrowed, vals)
+                lit_vecs.append(np.asarray(vals, dtype=np.int32))
+            masks = (delta.del_mask,) if delta.del_mask is not None else ()
+            with K._x32(), _trace_span("scan.device_launch"):
+                pending = fn(bcols, dcols, tuple(lit_vecs), *masks)
+            counts = np.asarray(pending)
+            _trace_bytes("d2h_bytes", int(counts.nbytes))
+            metrics.incr(f"{metric_ns}.dispatches")
+            metrics.incr(f"{metric_ns}.queries", len(predicates))
+            metrics.incr("scan.resident.d2h_bytes", int(counts.nbytes))
+            nb_pad = table.n_pad // BLOCK_ROWS
+            nb = -(-table.n_rows // BLOCK_ROWS)
+            nd = -(-delta.n_rows // BLOCK_ROWS)
+            return [(c[:nb], c[nb_pad : nb_pad + nd]) for c in counts]
 
     def delta_parts(
         self,
@@ -2231,21 +2254,25 @@ class HbmIndexCache(ResidentCacheBase):
         """The delta side's host leg: slice ONLY the 8192-row blocks the
         device counted matches in out of the (already decoded, host-held)
         appended batch, re-evaluate the predicate exactly there, project.
-        No parquet is touched — the decode was paid once at population."""
+        No parquet is touched — the decode was paid once at population.
+        Spanned as ``scan.delta_leg``."""
         from .delta import blocks_to_runs
 
-        cand = np.flatnonzero(counts)
-        metrics.incr("scan.resident.delta_blocks_touched", int(cand.size))
-        metrics.incr("scan.resident.delta_blocks_total", int(len(counts)))
-        if cand.size == 0:
-            return []
         parts = []
-        for lo, hi in blocks_to_runs(cand, BLOCK_ROWS, delta.n_rows):
-            sub = delta.host_batch.take(np.arange(lo, hi))
-            mask = eval_mask(predicate, sub)
-            idx = np.flatnonzero(mask)
-            if idx.size:
-                parts.append(sub.take(idx).select(list(output_columns)))
+        with _trace_span("scan.delta_leg") as leg:
+            cand = np.flatnonzero(counts)
+            metrics.incr("scan.resident.delta_blocks_touched", int(cand.size))
+            metrics.incr("scan.resident.delta_blocks_total", int(len(counts)))
+            if leg is not None:
+                leg.labels["delta_blocks"] = int(cand.size)
+            if cand.size == 0:
+                return []
+            for lo, hi in blocks_to_runs(cand, BLOCK_ROWS, delta.n_rows):
+                sub = delta.host_batch.take(np.arange(lo, hi))
+                mask = eval_mask(predicate, sub)
+                idx = np.flatnonzero(mask)
+                if idx.size:
+                    parts.append(sub.take(idx).select(list(output_columns)))
         return parts
 
     # -- join regions (the device-resident bucketed SMJ) ---------------------

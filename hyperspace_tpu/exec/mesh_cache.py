@@ -904,6 +904,7 @@ class MeshHbmCache(ResidentCacheBase):
         double-buffered window loop (residency.streaming)."""
         from ..ops import kernels as K
         from .hbm_cache import (
+            dispatch_span,
             prepare_resident_predicate,
             resident_arrays_for,
             resident_specs_for,
@@ -918,27 +919,26 @@ class MeshHbmCache(ResidentCacheBase):
         prepared = prepare_resident_predicate(table.columns, predicate)
         if prepared is None:
             return None
-        narrowed, names = prepared
-        fn = _mesh_counts_fn(
-            table.mesh,
-            repr(narrowed),
-            narrowed,
-            names,
-            table.cap,
-            table.block,
-            resident_specs_for(table.columns, names),
-        )
-        cols = dict(
-            zip(names, resident_arrays_for(table.columns, names))
-        )
-        t0 = time.perf_counter()
-        with K._x32():
-            counts = np.asarray(fn(cols))
-        metrics.record_time(
-            "scan.resident_mesh.device", time.perf_counter() - t0
-        )
-        metrics.incr("scan.resident_mesh.d2h_bytes", int(counts.nbytes))
-        return counts
+        with dispatch_span(table, 1, False, mesh=table.n_devices):
+            narrowed, names = prepared
+            fn = _mesh_counts_fn(
+                table.mesh,
+                repr(narrowed),
+                narrowed,
+                names,
+                table.cap,
+                table.block,
+                resident_specs_for(table.columns, names),
+            )
+            cols = dict(
+                zip(names, resident_arrays_for(table.columns, names))
+            )
+            with K._x32(), _trace_span("scan.device_launch"):
+                pending = fn(cols)
+            counts = np.asarray(pending)
+            _trace_bytes("d2h_bytes", int(counts.nbytes))
+            metrics.incr("scan.resident_mesh.d2h_bytes", int(counts.nbytes))
+            return counts
 
     def block_counts_batch(
         self,
@@ -961,6 +961,7 @@ class MeshHbmCache(ResidentCacheBase):
         from .hbm_cache import (
             _expr_literals,
             _expr_structure,
+            dispatch_span,
             prepare_resident_predicate,
             resident_arrays_for,
             resident_specs_for,
@@ -979,43 +980,50 @@ class MeshHbmCache(ResidentCacheBase):
             ]
         if any(p is None for p in prepared):
             return None
-        structures = tuple(_expr_structure(n) for n, _ in prepared)
-        slot_names = tuple(names for _, names in prepared)
-        union_names = tuple(
-            dict.fromkeys(n for names in slot_names for n in names)
-        )
-        fn = _mesh_batched_counts_fn(
-            table.mesh,
-            structures,
-            slot_names,
-            [n for n, _ in prepared],
-            table.cap,
-            table.block,
-            tuple(
-                zip(union_names, resident_specs_for(table.columns, union_names))
-            ),
-        )
-        cols = dict(
-            zip(union_names, resident_arrays_for(table.columns, union_names))
-        )
-        lit_vecs = []
-        for narrowed, _ in prepared:
-            vals: list = []
-            _expr_literals(narrowed, vals)
-            lit_vecs.append(np.asarray(vals, dtype=np.int32))
-        lit_vecs = tuple(lit_vecs)
-        t0 = time.perf_counter()
-        with K._x32():
-            counts = np.asarray(fn(cols, lit_vecs))
-        metrics.record_time(
-            f"{metric_ns}.mesh_device", time.perf_counter() - t0
-        )
-        metrics.incr(f"{metric_ns}.dispatches")
-        metrics.incr(f"{metric_ns}.queries", len(predicates))
-        metrics.incr("scan.resident_mesh.d2h_bytes", int(counts.nbytes))
-        # (D, N, n_blocks) -> per-predicate (D, n_blocks) slices, stacked
-        # predicate-major so callers index counts[i] like block_counts()
-        return np.swapaxes(counts, 0, 1)
+        with dispatch_span(
+            table, len(predicates), True, mesh=table.n_devices
+        ):
+            structures = tuple(_expr_structure(n) for n, _ in prepared)
+            slot_names = tuple(names for _, names in prepared)
+            union_names = tuple(
+                dict.fromkeys(n for names in slot_names for n in names)
+            )
+            fn = _mesh_batched_counts_fn(
+                table.mesh,
+                structures,
+                slot_names,
+                [n for n, _ in prepared],
+                table.cap,
+                table.block,
+                tuple(
+                    zip(
+                        union_names,
+                        resident_specs_for(table.columns, union_names),
+                    )
+                ),
+            )
+            cols = dict(
+                zip(
+                    union_names,
+                    resident_arrays_for(table.columns, union_names),
+                )
+            )
+            lit_vecs = []
+            for narrowed, _ in prepared:
+                vals: list = []
+                _expr_literals(narrowed, vals)
+                lit_vecs.append(np.asarray(vals, dtype=np.int32))
+            lit_vecs = tuple(lit_vecs)
+            with K._x32(), _trace_span("scan.device_launch"):
+                pending = fn(cols, lit_vecs)
+            counts = np.asarray(pending)
+            _trace_bytes("d2h_bytes", int(counts.nbytes))
+            metrics.incr(f"{metric_ns}.dispatches")
+            metrics.incr(f"{metric_ns}.queries", len(predicates))
+            metrics.incr("scan.resident_mesh.d2h_bytes", int(counts.nbytes))
+            # (D, N, n_blocks) -> per-predicate (D, n_blocks) slices, stacked
+            # predicate-major so callers index counts[i] like block_counts()
+            return np.swapaxes(counts, 0, 1)
 
     # -- host-side collection ------------------------------------------------
     def collect_parts(
@@ -1032,58 +1040,62 @@ class MeshHbmCache(ResidentCacheBase):
         the single-chip _resident_parts protocol per device shard,
         restricted to the query's (pruned) ``files``. ``path_metric=None``
         suppresses the path counter (the hybrid fused path fires
-        ``scan.path.resident_hybrid`` instead)."""
+        ``scan.path.resident_hybrid`` instead). Spanned as
+        ``scan.host_leg``."""
         wanted = {str(Path(f)) for f in files}
-        if path_metric is not None:
-            metrics.incr(path_metric)
-        metrics.incr(
-            "scan.resident_mesh.blocks_touched",
-            int(np.count_nonzero(counts)),
-        )
-        metrics.incr("scan.resident_mesh.blocks_total", int(counts.size))
-        need = list(
-            dict.fromkeys(list(output_columns) + sorted(predicate.columns()))
-        )
-        keyed: List[Tuple[Tuple[str, int], ColumnarBatch]] = []
-        for d in range(table.n_devices):
-            cand = np.flatnonzero(counts[d])
-            if cand.size == 0:
-                continue
-            # merge adjacent candidate blocks into device-local row runs,
-            # clipped to the device's real rows
-            runs: List[List[int]] = []
-            for blk in cand:
-                lo = int(blk) * table.block
-                hi = min((int(blk) + 1) * table.block, table.dev_rows[d])
-                if lo >= hi:
-                    continue  # pad-only tail block
-                if runs and runs[-1][1] == lo:
-                    runs[-1][1] = hi
-                else:
-                    runs.append([lo, hi])
-            for lo, hi in runs:
-                for path, flo, fhi, off in table.segments[d]:
-                    seg_len = fhi - flo
-                    a = max(lo, off)
-                    b = min(hi, off + seg_len)
-                    if a >= b or path not in wanted:
-                        continue
-                    r_lo = flo + (a - off)
-                    r_hi = flo + (b - off)
-                    batch = layout.cached_reader(path).read(
-                        need, row_range=(r_lo, r_hi)
-                    )
-                    mask = np.asarray(eval_mask(predicate, batch))
-                    idx = np.flatnonzero(mask)
-                    if idx.size:
-                        keyed.append(
-                            (
-                                (path, r_lo),
-                                batch.take(idx).select(output_columns),
-                            )
+        with _trace_span("scan.host_leg", files=len(wanted)) as leg:
+            if path_metric is not None:
+                metrics.incr(path_metric)
+            touched = int(np.count_nonzero(counts))
+            metrics.incr("scan.resident_mesh.blocks_touched", touched)
+            metrics.incr("scan.resident_mesh.blocks_total", int(counts.size))
+            if leg is not None:
+                leg.labels["host_blocks"] = touched
+            need = list(
+                dict.fromkeys(
+                    list(output_columns) + sorted(predicate.columns())
+                )
+            )
+            keyed: List[Tuple[Tuple[str, int], ColumnarBatch]] = []
+            for d in range(table.n_devices):
+                cand = np.flatnonzero(counts[d])
+                if cand.size == 0:
+                    continue
+                # merge adjacent candidate blocks into device-local row runs,
+                # clipped to the device's real rows
+                runs: List[List[int]] = []
+                for blk in cand:
+                    lo = int(blk) * table.block
+                    hi = min((int(blk) + 1) * table.block, table.dev_rows[d])
+                    if lo >= hi:
+                        continue  # pad-only tail block
+                    if runs and runs[-1][1] == lo:
+                        runs[-1][1] = hi
+                    else:
+                        runs.append([lo, hi])
+                for lo, hi in runs:
+                    for path, flo, fhi, off in table.segments[d]:
+                        seg_len = fhi - flo
+                        a = max(lo, off)
+                        b = min(hi, off + seg_len)
+                        if a >= b or path not in wanted:
+                            continue
+                        r_lo = flo + (a - off)
+                        r_hi = flo + (b - off)
+                        batch = layout.cached_reader(path).read(
+                            need, row_range=(r_lo, r_hi)
                         )
-        keyed.sort(key=lambda kv: kv[0])
-        return [b for _, b in keyed]
+                        mask = np.asarray(eval_mask(predicate, batch))
+                        idx = np.flatnonzero(mask)
+                        if idx.size:
+                            keyed.append(
+                                (
+                                    (path, r_lo),
+                                    batch.take(idx).select(output_columns),
+                                )
+                            )
+            keyed.sort(key=lambda kv: kv[0])
+            return [b for _, b in keyed]
 
     # -- delta residency (hybrid scan's appended side) -----------------------
     def delta_for(
@@ -1426,7 +1438,7 @@ class MeshHbmCache(ResidentCacheBase):
         encodings (caller routes the host union)."""
         from ..ops import kernels as K
         from .delta import prepare_hybrid_predicate
-        from .hbm_cache import resident_arrays_for
+        from .hbm_cache import dispatch_span, resident_arrays_for
 
         prepared = prepare_hybrid_predicate(
             table.columns, delta.oov, predicate
@@ -1436,36 +1448,34 @@ class MeshHbmCache(ResidentCacheBase):
         narrowed, names = prepared
         if any(n.split("\x00", 1)[0] not in delta.columns for n in names):
             return None
-        fn = _mesh_hybrid_counts_fn(
-            table.mesh,
-            repr(narrowed),
-            narrowed,
-            names,
-            table.cap,
-            table.block,
-            delta.cap,
-            delta.block,
-            delta.del_mask is not None,
-        )
-        bcols = dict(
-            zip(names, resident_arrays_for(table.columns, names))
-        )
-        dcols = dict(
-            zip(names, resident_arrays_for(delta.columns, names))
-        )
-        t0 = time.perf_counter()
-        with K._x32():
-            if delta.del_mask is not None:
-                counts = np.asarray(fn(bcols, dcols, delta.del_mask))
-            else:
-                counts = np.asarray(fn(bcols, dcols))
-        metrics.record_time(
-            "scan.resident_hybrid.mesh_device", time.perf_counter() - t0
-        )
-        metrics.incr("scan.resident_mesh.d2h_bytes", int(counts.nbytes))
-        _trace_bytes("d2h_bytes", int(counts.nbytes))
-        nb = table.n_blocks
-        return counts[:, :nb], counts[:, nb:]
+        with dispatch_span(
+            table, 1, False, mesh=table.n_devices, hybrid=True
+        ):
+            fn = _mesh_hybrid_counts_fn(
+                table.mesh,
+                repr(narrowed),
+                narrowed,
+                names,
+                table.cap,
+                table.block,
+                delta.cap,
+                delta.block,
+                delta.del_mask is not None,
+            )
+            bcols = dict(
+                zip(names, resident_arrays_for(table.columns, names))
+            )
+            dcols = dict(
+                zip(names, resident_arrays_for(delta.columns, names))
+            )
+            masks = (delta.del_mask,) if delta.del_mask is not None else ()
+            with K._x32(), _trace_span("scan.device_launch"):
+                pending = fn(bcols, dcols, *masks)
+            counts = np.asarray(pending)
+            _trace_bytes("d2h_bytes", int(counts.nbytes))
+            metrics.incr("scan.resident_mesh.d2h_bytes", int(counts.nbytes))
+            nb = table.n_blocks
+            return counts[:, :nb], counts[:, nb:]
 
     def delta_parts(
         self,
@@ -1477,24 +1487,27 @@ class MeshHbmCache(ResidentCacheBase):
         """The mesh delta's host leg: per device, slice only the counted
         blocks' rows out of the host-held appended batch (via dev_idx),
         re-evaluate exactly, project. No parquet per query."""
-        metrics.incr(
-            "scan.resident.delta_blocks_touched",
-            int(np.count_nonzero(counts)),
-        )
-        metrics.incr("scan.resident.delta_blocks_total", int(counts.size))
         from .delta import blocks_to_runs
 
         parts: List[ColumnarBatch] = []
-        for d in range(delta.n_devices):
-            cand = np.flatnonzero(counts[d])
-            if cand.size == 0:
-                continue
-            for lo, hi in blocks_to_runs(cand, delta.block, delta.dev_rows[d]):
-                sub = delta.host_batch.take(delta.dev_idx[d][lo:hi])
-                mask = eval_mask(predicate, sub)
-                idx = np.flatnonzero(np.asarray(mask))
-                if idx.size:
-                    parts.append(sub.take(idx).select(list(output_columns)))
+        touched = int(np.count_nonzero(counts))
+        with _trace_span("scan.delta_leg", delta_blocks=touched):
+            metrics.incr("scan.resident.delta_blocks_touched", touched)
+            metrics.incr("scan.resident.delta_blocks_total", int(counts.size))
+            for d in range(delta.n_devices):
+                cand = np.flatnonzero(counts[d])
+                if cand.size == 0:
+                    continue
+                for lo, hi in blocks_to_runs(
+                    cand, delta.block, delta.dev_rows[d]
+                ):
+                    sub = delta.host_batch.take(delta.dev_idx[d][lo:hi])
+                    mask = eval_mask(predicate, sub)
+                    idx = np.flatnonzero(np.asarray(mask))
+                    if idx.size:
+                        parts.append(
+                            sub.take(idx).select(list(output_columns))
+                        )
         return parts
 
     # -- join regions (the shuffle-free sharded resident SMJ) ----------------

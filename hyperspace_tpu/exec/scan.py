@@ -239,47 +239,49 @@ def _resident_parts(
     no result bytes ever cross the device link. Parts come back in
     ``files`` order, matching the host path's output order.
     ``path_metric=None`` suppresses the path counter (the hybrid fused
-    path fires its own ``scan.path.resident_hybrid`` instead)."""
+    path fires its own ``scan.path.resident_hybrid`` instead). Spanned as
+    ``scan.host_leg``, on the batched and the single path alike."""
     from .hbm_cache import BLOCK_ROWS
     from ..storage.layout import cached_reader
 
-    candid = np.flatnonzero(counts)
-    if path_metric is not None:
-        metrics.incr(path_metric)
-    metrics.incr("scan.resident.blocks_touched", int(len(candid)))
-    metrics.incr("scan.resident.blocks_total", int(len(counts)))
-    if candid.size == 0:
-        return []
-    # the exact host leg's footprint on whatever stage span is open
-    _trace_annotate(host_blocks=int(len(candid)))
-    need = list(dict.fromkeys(list(output_columns) + sorted(predicate.columns())))
-    parts: List[ColumnarBatch] = []
-    for f in files:
-        span = table.file_span(str(f))
-        if span is None:  # cannot happen (resident_for covered files)
-            continue
-        start, end = span
-        b_lo, b_hi = start // BLOCK_ROWS, -(-end // BLOCK_ROWS)
-        mine = candid[(candid >= b_lo) & (candid < b_hi)]
-        if mine.size == 0:
-            continue
-        # merge adjacent candidate blocks into contiguous row runs
-        runs: List[List[int]] = []
-        for b in mine:
-            lo = max(int(b) * BLOCK_ROWS, start) - start
-            hi = min((int(b) + 1) * BLOCK_ROWS, end) - start
-            if runs and runs[-1][1] == lo:
-                runs[-1][1] = hi
-            else:
-                runs.append([lo, hi])
-        reader = cached_reader(f)
-        for lo, hi in runs:
-            batch = reader.read(need, row_range=(lo, hi))
-            mask = np.asarray(eval_mask(predicate, batch))
-            idx = np.flatnonzero(mask)
-            if idx.size:
-                parts.append(batch.take(idx).select(output_columns))
-    return parts
+    with _trace_span("scan.host_leg", files=len(files)):
+        candid = np.flatnonzero(counts)
+        if path_metric is not None:
+            metrics.incr(path_metric)
+        metrics.incr("scan.resident.blocks_touched", int(len(candid)))
+        metrics.incr("scan.resident.blocks_total", int(len(counts)))
+        # the exact host leg's footprint, on its own span
+        _trace_annotate(host_blocks=int(len(candid)))
+        if candid.size == 0:
+            return []
+        need = list(dict.fromkeys(list(output_columns) + sorted(predicate.columns())))
+        parts: List[ColumnarBatch] = []
+        for f in files:
+            span = table.file_span(str(f))
+            if span is None:  # cannot happen (resident_for covered files)
+                continue
+            start, end = span
+            b_lo, b_hi = start // BLOCK_ROWS, -(-end // BLOCK_ROWS)
+            mine = candid[(candid >= b_lo) & (candid < b_hi)]
+            if mine.size == 0:
+                continue
+            # merge adjacent candidate blocks into contiguous row runs
+            runs: List[List[int]] = []
+            for b in mine:
+                lo = max(int(b) * BLOCK_ROWS, start) - start
+                hi = min((int(b) + 1) * BLOCK_ROWS, end) - start
+                if runs and runs[-1][1] == lo:
+                    runs[-1][1] = hi
+                else:
+                    runs.append([lo, hi])
+            reader = cached_reader(f)
+            for lo, hi in runs:
+                batch = reader.read(need, row_range=(lo, hi))
+                mask = np.asarray(eval_mask(predicate, batch))
+                idx = np.flatnonzero(mask)
+                if idx.size:
+                    parts.append(batch.take(idx).select(output_columns))
+        return parts
 
 
 def empty_batch_for(output_columns, dtypes) -> Optional[ColumnarBatch]:
@@ -414,26 +416,20 @@ def index_scan(
             # (identical result — same invariant as _routed_mask) and
             # drops the table so later queries don't retry a dead device
             try:
-                # the trace's "which tier, how many bytes" span: one
-                # fused mask+count dispatch plus its count-vector D2H
-                # (hbm_cache adds d2h_bytes via trace.add_bytes)
-                with _trace_span(
-                    "scan.device_dispatch",
-                    tier=getattr(table, "tier", "resident"),
-                    structure_keyed=bool(structure_keyed),
+                # the residency cache opens the trace's
+                # scan.device_dispatch span around the dispatch and D2H
+                if (
+                    structure_keyed
+                    and getattr(table, "tier", "resident") != "streaming"
                 ):
-                    if (
-                        structure_keyed
-                        and getattr(table, "tier", "resident") != "streaming"
-                    ):
-                        m = hbm_cache.block_counts_batch(
-                            table,
-                            [predicate],
-                            metric_ns="compile.fused",
-                        )
-                        counts = None if m is None else m[0]
-                    else:
-                        counts = hbm_cache.block_counts(table, predicate)
+                    m = hbm_cache.block_counts_batch(
+                        table,
+                        [predicate],
+                        metric_ns="compile.fused",
+                    )
+                    counts = None if m is None else m[0]
+                else:
+                    counts = hbm_cache.block_counts(table, predicate)
             except Exception:  # noqa: BLE001 - device loss degrades
                 hbm_cache.drop(table)
                 metrics.incr("scan.resident.device_failed")

@@ -69,6 +69,7 @@ from ..storage import layout
 from ..storage.columnar import Column, ColumnarBatch, is_string
 from ..telemetry.metrics import metrics
 from ..telemetry.trace import add_bytes as _trace_bytes
+from ..telemetry.trace import span as _trace_span
 from ..utils.memo import bounded_memo_put
 
 SPILL_DIR_NAME = ".spill"
@@ -1148,10 +1149,13 @@ class StreamingIndexWriter:
             self._pending = []
             self._pending_rows = 0
             self._process_chunk(tail)
-        # a staged run may still pend when the source was an exact
-        # multiple of the chunk capacity (no tail to force the flush)
-        self._flush_staged()
-        self._drain_spills()
+        # build.drain: the wait for the in-flight device merges, their
+        # D2H and gather, and the spill writes. A staged run may still
+        # pend when the source was an exact multiple of the chunk
+        # capacity (no tail to force the flush)
+        with _trace_span("build.drain", chunks=self._chunk_seq):
+            self._flush_staged()
+            self._drain_spills()
         if self._stager is not None:
             # flushed + drained: nothing pends — drop releases the slab
             # pair's host buffers and the shared HBM budget reservation
@@ -1177,6 +1181,13 @@ class StreamingIndexWriter:
                 self._t_pipeline_done - self._t_first_add,
             )
         self._finalized = True
+        # build.bucket_merge: the final per-bucket read, merge and write
+        # (or, in runs mode, the rename of the spills into place)
+        with _trace_span("build.bucket_merge", runs=len(self._spills)):
+            return self._write_final()
+
+    def _write_final(self) -> List[Path]:
+        """The final index files from the drained spills (sorted)."""
         t0 = time.perf_counter()
         written: List[Path] = []
         if self._spills and self.finalize_mode == "runs":

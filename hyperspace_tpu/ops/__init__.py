@@ -15,6 +15,9 @@ def ensure_x64() -> None:
 
     jax.config.update("jax_enable_x64", True)
     _enable_persistent_compile_cache(jax)
+    from ..telemetry.compiles import install
+
+    install()
 
 
 def configured_platform() -> str:

@@ -153,6 +153,11 @@ def _sort_by_bucket_and_keys(
     return out, sorted_ops[0], counts, perm
 
 
+# The jitted programs carry stable names (jax names the module
+# ``jit_<function name>``): ``build_bucket_sort``, ``_packed`` and
+# ``_staged`` (bucketize + sort) and ``build_sort_merge_runs`` (the
+# on-device run merge), so a device profile tells them apart.
+#
 # One jitted closure per (key schema, keys, num_buckets): jax.jit caches
 # by function object, so a closure defined inside build_partition_single
 # would RETRACE on every call — the persistent compile cache saves the
@@ -214,7 +219,7 @@ def _single_perm_kernel(dtypes_key: tuple, key_names: tuple, num_buckets: int):
     keys = list(key_names)
 
     @jax.jit
-    def kernel(arrays, vh, n_valid):
+    def build_bucket_sort(arrays, vh, n_valid):
         bucket = device_bucket_ids(arrays, dtypes, keys, vh, num_buckets)
         m = bucket.shape[0]
         bucket = jnp.where(
@@ -229,8 +234,8 @@ def _single_perm_kernel(dtypes_key: tuple, key_names: tuple, num_buckets: int):
 
     if len(_single_kernel_cache) >= 64:
         _single_kernel_cache.pop(next(iter(_single_kernel_cache)))
-    _single_kernel_cache[cache_key] = kernel
-    return kernel
+    _single_kernel_cache[cache_key] = build_bucket_sort
+    return build_bucket_sort
 
 
 def _narrow_pack(plan: List[Tuple[int, int]], num_buckets: int) -> bool:
@@ -276,7 +281,7 @@ def _single_perm_kernel_packed(
     keys = list(key_names)
 
     @jax.jit
-    def kernel(arrays, vh, n_valid, mins, shifts):
+    def build_bucket_sort_packed(arrays, vh, n_valid, mins, shifts):
         bucket = device_bucket_ids(arrays, dtypes, keys, vh, num_buckets)
         m = bucket.shape[0]
         iota = lax.iota(jnp.int32, m)
@@ -288,8 +293,8 @@ def _single_perm_kernel_packed(
 
     if len(_single_kernel_cache) >= 64:
         _single_kernel_cache.pop(next(iter(_single_kernel_cache)))
-    _single_kernel_cache[cache_key] = kernel
-    return kernel
+    _single_kernel_cache[cache_key] = build_bucket_sort_packed
+    return build_bucket_sort_packed
 
 
 def build_partition_single(
@@ -428,7 +433,7 @@ def _single_staged_kernel_packed(
     keys = list(key_names)
 
     @jax.jit
-    def kernel(arrays, vh, mins, shifts):
+    def build_bucket_sort_staged(arrays, vh, mins, shifts):
         bucket = device_bucket_ids(arrays, dtypes, keys, vh, num_buckets)
         m = bucket.shape[0]
         iota = lax.iota(jnp.int32, m)
@@ -441,8 +446,8 @@ def _single_staged_kernel_packed(
 
     if len(_single_kernel_cache) >= 64:
         _single_kernel_cache.pop(next(iter(_single_kernel_cache)))
-    _single_kernel_cache[cache_key] = kernel
-    return kernel
+    _single_kernel_cache[cache_key] = build_bucket_sort_staged
+    return build_bucket_sort_staged
 
 
 def _staged_merge_fn(nkeys: int):
@@ -462,7 +467,9 @@ def _staged_merge_fn(nkeys: int):
         return fn
 
     @jax.jit
-    def kernel(packed, perms, counts, cmins, cshifts, rmins, rshifts):
+    def build_sort_merge_runs(
+        packed, perms, counts, cmins, cshifts, rmins, rshifts
+    ):
         r, cap = packed.shape
         rem = packed
         fields: List = []
@@ -516,8 +523,8 @@ def _staged_merge_fn(nkeys: int):
 
     if len(_single_kernel_cache) >= 64:
         _single_kernel_cache.pop(next(iter(_single_kernel_cache)))
-    _single_kernel_cache[cache_key] = kernel
-    return kernel
+    _single_kernel_cache[cache_key] = build_sort_merge_runs
+    return build_sort_merge_runs
 
 
 class StagedChunk:

@@ -44,6 +44,7 @@ from ..exec.bytecache import vocab_heap_bytes
 from ..ops.bitpack import PackSpec, pack_plain
 from ..telemetry.metrics import metrics
 from ..telemetry.trace import add_bytes as _trace_bytes
+from ..telemetry.trace import span as _trace_span
 
 # window padding grain: BLOCK_ROWS (8192) is a multiple of the mask tile
 # (1024) and of every straddle-free word width, so one grain serves the
@@ -306,6 +307,7 @@ def stream_block_counts(table: StreamingResidentTable, predicate):
         BLOCK_ROWS,
         _LANES,
         _counts_fn,
+        dispatch_span,
         prepare_resident_predicate,
     )
     from ..ops import kernels as K
@@ -314,20 +316,19 @@ def stream_block_counts(table: StreamingResidentTable, predicate):
     if prepared is None:
         return None
     narrowed, names = prepared
-    t0 = time.perf_counter()
 
     def dispatch(cols, specs):
         fn = _counts_fn(
             narrowed, names, table.window_rows // _LANES, False, specs
         )
-        with K._x32():
+        with K._x32(), _trace_span("scan.device_launch"):
             return fn([cols[n] for n in names])
 
-    parts = _windowed_counts(table, dispatch, names)
-    metrics.record_time("scan.resident.device", time.perf_counter() - t0)
-    counts = np.concatenate(parts)
+    with dispatch_span(table, 1, False):
+        parts = _windowed_counts(table, dispatch, names)
+        counts = np.concatenate(parts)
+        _trace_bytes("d2h_bytes", int(counts.nbytes))
     metrics.incr("scan.resident.d2h_bytes", int(counts.nbytes))
-    _trace_bytes("d2h_bytes", int(counts.nbytes))
     n_blocks = -(-table.n_rows // BLOCK_ROWS)
     return counts[:n_blocks]
 
@@ -344,6 +345,7 @@ def stream_block_counts_batch(
         _batched_counts_fn,
         _expr_literals,
         _expr_structure,
+        dispatch_span,
         prepare_resident_predicate,
     )
     from ..ops import kernels as K
@@ -366,7 +368,6 @@ def stream_block_counts_batch(
         _expr_literals(narrowed, vals)
         lit_vecs.append(np.asarray(vals, dtype=np.int32))
     lit_vecs = tuple(lit_vecs)
-    t0 = time.perf_counter()
 
     def dispatch(cols, specs):
         spec_map = tuple(zip(union_names, specs))
@@ -377,16 +378,16 @@ def stream_block_counts_batch(
             table.window_rows // _LANES,
             spec_map,
         )
-        with K._x32():
+        with K._x32(), _trace_span("scan.device_launch"):
             return fn(cols, lit_vecs)
 
-    parts = _windowed_counts(table, dispatch, union_names)
-    metrics.record_time("serve.batch.device", time.perf_counter() - t0)
+    with dispatch_span(table, len(predicates), True):
+        parts = _windowed_counts(table, dispatch, union_names)
+        counts = np.concatenate(parts, axis=1)
+        _trace_bytes("d2h_bytes", int(counts.nbytes))
     metrics.incr("serve.batch.dispatches")
     metrics.incr("serve.batch.queries", len(predicates))
-    counts = np.concatenate(parts, axis=1)
     metrics.incr("scan.resident.d2h_bytes", int(counts.nbytes))
-    _trace_bytes("d2h_bytes", int(counts.nbytes))
     n_blocks = -(-table.n_rows // BLOCK_ROWS)
     return counts[:, :n_blocks]
 
@@ -550,7 +551,7 @@ def mesh_stream_block_counts(table: MeshStreamingResidentTable, predicate):
     streaming twin of MeshHbmCache.block_counts. None when the
     predicate cannot ride the resident encodings; device errors
     propagate (caller drops + degrades)."""
-    from ..exec.hbm_cache import prepare_resident_predicate
+    from ..exec.hbm_cache import dispatch_span, prepare_resident_predicate
     from ..exec.mesh_cache import _mesh_counts_fn
     from ..ops import kernels as K
 
@@ -558,7 +559,6 @@ def mesh_stream_block_counts(table: MeshStreamingResidentTable, predicate):
     if prepared is None:
         return None
     narrowed, names = prepared
-    t0 = time.perf_counter()
 
     def dispatch(cols, specs):
         fn = _mesh_counts_fn(
@@ -570,18 +570,16 @@ def mesh_stream_block_counts(table: MeshStreamingResidentTable, predicate):
             table.block,
             specs,
         )
-        with K._x32():
+        with K._x32(), _trace_span("scan.device_launch"):
             return fn(cols)
 
-    parts = _run_window_loop(
-        table, lambda w: _mesh_upload_window(table, names, w), dispatch
-    )
-    metrics.record_time(
-        "scan.resident_mesh.device", time.perf_counter() - t0
-    )
-    counts = np.concatenate(parts, axis=1)
+    with dispatch_span(table, 1, False, mesh=table.n_devices):
+        parts = _run_window_loop(
+            table, lambda w: _mesh_upload_window(table, names, w), dispatch
+        )
+        counts = np.concatenate(parts, axis=1)
+        _trace_bytes("d2h_bytes", int(counts.nbytes))
     metrics.incr("scan.resident_mesh.d2h_bytes", int(counts.nbytes))
-    _trace_bytes("d2h_bytes", int(counts.nbytes))
     return counts
 
 
@@ -598,6 +596,7 @@ def mesh_stream_block_counts_batch(
     from ..exec.hbm_cache import (
         _expr_literals,
         _expr_structure,
+        dispatch_span,
         prepare_resident_predicate,
     )
     from ..exec.mesh_cache import _mesh_batched_counts_fn
@@ -621,7 +620,6 @@ def mesh_stream_block_counts_batch(
         _expr_literals(narrowed, vals)
         lit_vecs.append(np.asarray(vals, dtype=np.int32))
     lit_vecs = tuple(lit_vecs)
-    t0 = time.perf_counter()
 
     def dispatch(cols, specs):
         spec_map = tuple(zip(union_names, specs))
@@ -634,19 +632,19 @@ def mesh_stream_block_counts_batch(
             table.block,
             spec_map,
         )
-        with K._x32():
+        with K._x32(), _trace_span("scan.device_launch"):
             return fn(cols, lit_vecs)
 
-    parts = _run_window_loop(
-        table,
-        lambda w: _mesh_upload_window(table, union_names, w),
-        dispatch,
-    )
-    metrics.record_time(f"{metric_ns}.mesh_device", time.perf_counter() - t0)
+    with dispatch_span(table, len(predicates), True, mesh=table.n_devices):
+        parts = _run_window_loop(
+            table,
+            lambda w: _mesh_upload_window(table, union_names, w),
+            dispatch,
+        )
+        # per-window (D, N, W // block) -> (D, N, blocks) -> predicate-major
+        counts = np.concatenate(parts, axis=2)
+        _trace_bytes("d2h_bytes", int(counts.nbytes))
     metrics.incr(f"{metric_ns}.dispatches")
     metrics.incr(f"{metric_ns}.queries", len(predicates))
-    # per-window (D, N, W // block) -> (D, N, blocks) -> predicate-major
-    counts = np.concatenate(parts, axis=2)
     metrics.incr("scan.resident_mesh.d2h_bytes", int(counts.nbytes))
-    _trace_bytes("d2h_bytes", int(counts.nbytes))
     return np.swapaxes(counts, 0, 1)

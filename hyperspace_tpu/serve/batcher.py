@@ -347,8 +347,10 @@ def execute_batch(
             parts += hbm_cache.delta_parts(
                 delta, r.predicate, r.output_columns, delta_c
             )
-            metrics.incr("scan.path.resident_hybrid")
             results.append(_concat_or_empty(parts, r))
+        # counted once per batch: a counter's lock taken per rider, outside
+        # the riders' leg spans, is contended by every serving thread
+        metrics.incr("scan.path.resident_hybrid", len(requests))
         metrics.incr("serve.batch.coalesced", len(requests))
         return results
     if requests[0].mesh is not None:

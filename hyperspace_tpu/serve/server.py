@@ -554,9 +554,14 @@ class QueryServer:
                         ticket.trace.root.labels["result_cache"] = "hit"
                     self._finish(ticket, result=cached)
                     return ticket
-            resident = (
-                None if latched else batcher.classify(self.session, plan)
-            )
+            resident = None
+            if not latched:
+                # file pruning, residency lookup, zone gate and predicate
+                # narrowing: the batchability decision, per submission
+                with span("serve.classify") as classify_span:
+                    resident = batcher.classify(self.session, plan)
+                if classify_span is not None:
+                    classify_span.labels["batchable"] = resident is not None
         except Exception as e:  # noqa: BLE001 - planning failure = query failure
             # planning failures (unknown columns, vanished files) belong
             # to the QUERY, not the server: the ticket carries them and

@@ -337,6 +337,9 @@ def compile_snapshot(
     r = registry if registry is not None else metrics
     out: Dict[str, object] = {
         "pipelines_lowered": r.counter("compile.lowered"),
+        # XLA backend compiles (telemetry.compiles); the span that paid
+        # for each carries it as its ``xla_compile_s`` label
+        "backend_compiles": r.counter("compile.backend.compiles"),
         "lower_errors": r.counter("compile.lower_error"),
         "cache_hits": r.counter("compile.cache.hit"),
         "cache_misses": r.counter("compile.cache.miss"),

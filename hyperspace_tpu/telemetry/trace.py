@@ -25,11 +25,19 @@ at the query entry points; the library span sites then no-op).
 Clock: ``time.monotonic()`` throughout — the serve tier's ticket
 timestamps (submitted_at/started_at) are monotonic, and queue-wait
 spans are built from them directly.
+
+Device profiles: while a ``jax.profiler`` session is active, every span
+opened by ``span()`` also holds a ``jax.profiler.TraceAnnotation`` of
+the same name, so the program's stages appear on the profile's host
+plane, on the same clock as the device ops. With no session the extra
+cost is one ``TraceAnnotation.is_enabled()`` check; ``add_span``
+intervals (explicit past timestamps) stay out of the profile.
 """
 
 from __future__ import annotations
 
 import itertools
+import sys
 import threading
 import time
 from contextlib import contextmanager
@@ -216,18 +224,41 @@ class _Activation:
         return False
 
 
+_PROFILER_ANNOTATION = None  # jax.profiler.TraceAnnotation, once jax is imported
+
+
+def _profiler_annotation(name: str):
+    """An entered ``TraceAnnotation`` of ``name`` while a profiler
+    session is active, else None. A process that has not imported jax
+    has no session, so tracing never imports it."""
+    global _PROFILER_ANNOTATION
+    cls = _PROFILER_ANNOTATION
+    if cls is None:
+        if "jax" not in sys.modules:
+            return None
+        from jax.profiler import TraceAnnotation as cls
+
+        _PROFILER_ANNOTATION = cls
+    if not cls.is_enabled():
+        return None
+    note = cls(name)
+    note.__enter__()
+    return note
+
+
 class _SpanCtx:
     """Class-based context manager behind ``span()`` (hot path; see
     _Activation). Enters to the Span, or None with no active trace. An
     exception propagating out marks the span failed before re-raising."""
 
-    __slots__ = ("_name", "_labels", "_span", "_token")
+    __slots__ = ("_name", "_labels", "_span", "_token", "_note")
 
     def __init__(self, name: str, labels: Dict[str, Any]):
         self._name = name
         self._labels = labels
         self._span: Optional[Span] = None
         self._token = None
+        self._note = None
 
     def __enter__(self) -> Optional[Span]:
         parent = _ACTIVE.get()
@@ -237,6 +268,7 @@ class _SpanCtx:
         parent.children.append(s)
         self._token = _ACTIVE.set(s)
         self._span = s
+        self._note = _profiler_annotation(self._name)
         return s
 
     def __exit__(self, et, ev, tb) -> bool:
@@ -247,6 +279,8 @@ class _SpanCtx:
             s.status = "error"
             s.error = repr(ev)
         s.t1 = time.monotonic()
+        if self._note is not None:
+            self._note.__exit__(None, None, None)
         _ACTIVE.reset(self._token)
         return False
 
@@ -281,5 +315,10 @@ def start_trace(name: str, **labels: Any):
     finishes/records it (query entry points gate on
     conf.telemetry_tracing_enabled() BEFORE calling this)."""
     t = QueryTrace(name, **labels)
-    with t.activate():
-        yield t
+    note = _profiler_annotation(name)
+    try:
+        with t.activate():
+            yield t
+    finally:
+        if note is not None:
+            note.__exit__(None, None, None)
