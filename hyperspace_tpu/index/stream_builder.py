@@ -415,7 +415,7 @@ class _DeviceRunStager:
     (BoundedSlots), the same high-water rule as per-chunk dispatch."""
 
     # reservation rule: staged planes (12 B/row × R) + merge working set
-    # (stack copies + outputs + tournament temporaries, ~2× the planes)
+    # (stack copies + the sort's operands and outputs, ~2× the planes)
     # — one named constant so the charge and the doc stay in one place
     STAGED_BYTES_PER_ROW = 36
 

@@ -450,18 +450,22 @@ def _single_staged_kernel_packed(
     return build_bucket_sort_staged
 
 
-def _staged_merge_fn(nkeys: int):
+def _staged_merge_fn(nkeys: int, narrow: bool):
     """The on-device k-way run merge: takes R staged chunks' sorted
     composites (each packed with its own chunk plan), normalizes them
     onto ONE run-level plan — unpack with the chunk's mins/shifts,
-    re-bias, re-pack with the run's — and merges via the same stable
-    pairwise searchsorted tournament as the host merge_sorted_orders
-    (adjacent pairs, left run wins ties), entirely in one executable.
-    Chunk and run mins/shifts are DEVICE OPERANDS, so one compiled
-    program serves every run of a given (chunk count, key count) shape.
-    Returns (global row order into the R concatenated original chunks,
-    summed per-bucket counts) — the run's ONLY D2H."""
-    cache_key = ("staged-merge", nkeys)
+    re-bias, re-pack with the run's — and merges them with ONE stable
+    sort of the R runs flattened in chunk order, each row's original
+    index riding as payload. Stability makes ties keep concatenation
+    order: the left chunk wins and in-run order holds, exactly as the
+    host merge_sorted_orders resolves them. The sort operand is int32
+    when ``narrow`` (the run plan fits 31 bits, the _narrow_pack rule)
+    and int64 otherwise. Chunk and run mins/shifts are DEVICE OPERANDS,
+    so one compiled program serves every run of a given (chunk count,
+    key count, width) shape. Returns (global row order into the R
+    concatenated original chunks, summed per-bucket counts) — the run's
+    ONLY D2H."""
+    cache_key = ("staged-merge", nkeys, narrow)
     fn = _single_kernel_cache.get(cache_key)
     if fn is not None:
         return fn
@@ -487,39 +491,12 @@ def _staged_merge_fn(nkeys: int):
             )
         base = jnp.arange(r, dtype=jnp.int64)[:, None] * jnp.int64(cap)
         orig = (base + perms.astype(jnp.int64)).astype(jnp.int32)
-        runs = [(comp[c], orig[c]) for c in range(r)]
-        while len(runs) > 1:
-            nxt = []
-            for j in range(0, len(runs) - 1, 2):
-                ak, ai = runs[j]
-                bk, bi = runs[j + 1]
-                la, lb = ak.shape[0], bk.shape[0]
-                pos_a = jnp.arange(la, dtype=jnp.int32) + jnp.searchsorted(
-                    bk, ak, side="left"
-                ).astype(jnp.int32)
-                pos_b = jnp.arange(lb, dtype=jnp.int32) + jnp.searchsorted(
-                    ak, bk, side="right"
-                ).astype(jnp.int32)
-                mk = (
-                    jnp.zeros(la + lb, ak.dtype)
-                    .at[pos_a]
-                    .set(ak)
-                    .at[pos_b]
-                    .set(bk)
-                )
-                mi = (
-                    jnp.zeros(la + lb, jnp.int32)
-                    .at[pos_a]
-                    .set(ai)
-                    .at[pos_b]
-                    .set(bi)
-                )
-                nxt.append((mk, mi))
-            if len(runs) % 2:
-                nxt.append(runs[-1])
-            runs = nxt
-        _mk, mi = runs[0]
-        return mi, counts.sum(axis=0)
+        if narrow:
+            comp = comp.astype(jnp.int32)
+        _keys, order = lax.sort(
+            [comp.reshape(-1), orig.reshape(-1)], num_keys=1, is_stable=True
+        )
+        return order, counts.sum(axis=0)
 
     if len(_single_kernel_cache) >= 64:
         _single_kernel_cache.pop(next(iter(_single_kernel_cache)))
@@ -628,7 +605,10 @@ def merge_staged_chunks(
     )
     rmins = jnp.asarray(np.array([mn for mn, _ in run_plan], dtype=np.int64))
     rshifts = jnp.asarray(np.array([kb for _, kb in run_plan], dtype=np.int32))
-    fn = _staged_merge_fn(nkeys)
+    narrow = _narrow_pack(run_plan, num_buckets)
+    if narrow:
+        metrics.incr("build.device.merge_narrow")
+    fn = _staged_merge_fn(nkeys, narrow)
     order_dev, counts_dev = fn(
         packed, perms, counts, cmins, cshifts, rmins, rshifts
     )

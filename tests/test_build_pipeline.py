@@ -883,6 +883,66 @@ def _staged_build(tmp_path, tag, device, keys=("orderkey", "qty"),
     return _bucket_bytes(out)
 
 
+@pytest.mark.parametrize("width", ["narrow", "wide"])
+@pytest.mark.parametrize("r", [2, 3, 4])
+def test_device_run_merge_matches_host_merge(r, width):
+    """The on-device run merge against the host tournament over the same
+    staged runs, with heavy key ties (fewer distinct keys than a chunk
+    holds): same order (left chunk wins ties, in-run order kept) and the
+    same counts. A narrow run plan (<= 31 bits) sorts as int32 and counts
+    ``build.device.merge_narrow``; a key spanning about 40 bits sorts as
+    int64 and does not."""
+    from hyperspace_tpu.ops import build as ops_build
+    from hyperspace_tpu.ops.hashing import bucket_ids_host, key_repr
+    from hyperspace_tpu.telemetry.metrics import metrics
+
+    cap, nb = 512, 8
+    rng = np.random.default_rng(61 + r)
+    distinct = (
+        np.arange(200, dtype=np.int64)
+        if width == "narrow"
+        else rng.integers(0, 1 << 40, 64).astype(np.int64)
+    )
+    chunks = [
+        ColumnarBatch.from_pydict(
+            {"k": rng.choice(distinct, cap)}, schema={"k": "int64"}
+        )
+        for _ in range(r)
+    ]
+    metrics.reset()
+    staged, union = [], None
+    for b in chunks:
+        encoded, bounds = ops_build.stage_encode(b, ["k"])
+        plan = ops_build.run_pack_plan(bounds, nb)
+        union = bounds if union is None else [
+            (min(a, mn), max(c, mx))
+            for (a, c), (mn, mx) in zip(union, bounds)
+        ]
+        s, _ = ops_build.stage_chunk_packed(encoded, b.schema(), ["k"], nb, plan)
+        staged.append(s)
+    run_plan = ops_build.run_pack_plan(union, nb)
+    order_dev, counts_dev = ops_build.merge_staged_chunks(staged, run_plan, nb)
+    assert metrics.counter("build.device.merge_narrow") == (
+        1 if width == "narrow" else 0
+    )
+
+    merged = ColumnarBatch.concat(chunks)
+    bucket = bucket_ids_host([key_repr(merged.columns["k"])], nb)
+    comp = ops_build._pack_sort_keys(
+        [sort_encoding(merged.columns["k"])], bucket, nb
+    )
+    runs = []
+    for c, s in enumerate(staged):
+        rows = c * cap + np.asarray(s.perm).astype(np.int64)
+        runs.append((comp[rows], rows))
+    np.testing.assert_array_equal(
+        np.asarray(order_dev), ops_build.merge_sorted_orders(runs)
+    )
+    np.testing.assert_array_equal(
+        np.asarray(counts_dev)[:nb], np.bincount(bucket, minlength=nb)
+    )
+
+
 def test_probe_cache_key_includes_device_mode():
     """The host_width lesson applied to the device engine: a per-chunk
     round-trip verdict must not bind a double-buffered staged run —
@@ -906,32 +966,54 @@ def test_probe_cache_key_includes_device_mode():
     )
 
 
-def test_staged_device_build_matches_per_chunk_bytes(tmp_path):
+def _tied_sample(n, seed):
+    """Few distinct orderkeys, each on many rows: the ties of lineitem's
+    l_orderkey, many times over."""
+    b = _int_sample(n, seed)
+    rng = np.random.default_rng(seed)
+    b.columns["orderkey"].data[:] = rng.integers(0, 300, n)
+    return b
+
+
+@pytest.mark.parametrize(
+    "full_chunks, keys, make",
+    [
+        (4, ("orderkey", "qty"), _int_sample),
+        # the sf1-build shape in miniature: two staged chunks, then a
+        # partial tail that flushes them as one 2-chunk run
+        (2, ("orderkey",), _tied_sample),
+    ],
+    ids=["distinct", "tied"],
+)
+def test_staged_device_build_matches_per_chunk_bytes(
+    tmp_path, full_chunks, keys, make
+):
     """Byte parity per-chunk vs staged (serial AND pipelined), with a
     partial tail chunk in the stream; the staged side must also pay
     runChunks-fold fewer blocking D2H calls."""
     from hyperspace_tpu.telemetry.metrics import metrics
 
-    b = _int_sample(4 * 512 + 100, seed=29)
+    b = make(full_chunks * 512 + 100, seed=29)
     metrics.reset()
     a_bytes = _staged_build(
-        tmp_path, "per_chunk", DeviceBuildConfig.per_chunk(), batch=b
+        tmp_path, "per_chunk", DeviceBuildConfig.per_chunk(), keys=keys,
+        batch=b,
     )
     a_calls = metrics.counter("build.stream.d2h_calls")
     metrics.reset()
     s_bytes = _staged_build(
-        tmp_path, "staged", DeviceBuildConfig(True, 4), batch=b
+        tmp_path, "staged", DeviceBuildConfig(True, 4), keys=keys, batch=b
     )
     s_calls = metrics.counter("build.stream.d2h_calls")
-    assert metrics.counter("build.device.staged_chunks") == 4
+    assert metrics.counter("build.device.staged_chunks") == full_chunks
     assert metrics.counter("build.device.staged_runs") == 1
     assert a_bytes == s_bytes
-    # 4 full chunks: per-chunk pays 4 blocking fetches + 1 tail; the
+    # per-chunk pays one blocking fetch a full chunk + 1 tail; the
     # staged run pays ONE (+ the tail's per-chunk fetch)
-    assert a_calls == 5 and s_calls == 2
+    assert a_calls == full_chunks + 1 and s_calls == 2
     p_bytes = _staged_build(
-        tmp_path, "staged_pipe", DeviceBuildConfig(True, 4), batch=b,
-        pipeline=pipelined(),
+        tmp_path, "staged_pipe", DeviceBuildConfig(True, 4), keys=keys,
+        batch=b, pipeline=pipelined(),
     )
     assert p_bytes == a_bytes
     assert slab_budget.held_bytes() == 0

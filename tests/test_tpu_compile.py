@@ -128,3 +128,23 @@ def test_build_step_compiles(one_chip):
         _spec((1,), jnp.int64, one_chip),
         _spec((1,), jnp.int32, one_chip),
     ).compile()
+
+
+@pytest.mark.parametrize("narrow", [True, False], ids=["int32", "int64"])
+def test_run_merge_compiles(one_chip, narrow):
+    # the staged build's device run merge (one stable sort of two
+    # re-packed runs), at a small chunk for the same reason
+    import jax.numpy as jnp
+
+    from hyperspace_tpu.ops.build import _staged_merge_fn
+
+    r, rows, buckets = 2, 4096, 200
+    _staged_merge_fn(1, narrow).lower(
+        _spec((r, rows), jnp.int64, one_chip),
+        _spec((r, rows), jnp.int32, one_chip),
+        _spec((r, buckets), jnp.int32, one_chip),
+        _spec((r, 1), jnp.int64, one_chip),
+        _spec((r, 1), jnp.int32, one_chip),
+        _spec((1,), jnp.int64, one_chip),
+        _spec((1,), jnp.int32, one_chip),
+    ).compile()
