@@ -168,6 +168,20 @@ class HyperspaceConf:
     def mesh_bucket_axis(self) -> str:
         return str(self.get(C.TPU_MESH_BUCKET_AXIS, C.TPU_MESH_BUCKET_AXIS_DEFAULT))
 
+    def mesh_devices(self) -> int:
+        v = self.get(C.TPU_MESH_DEVICES, C.TPU_MESH_DEVICES_DEFAULT)
+        try:
+            n = int(v)
+        except (TypeError, ValueError):
+            n = 0
+        if n < 1:
+            from .exceptions import HyperspaceException
+
+            raise HyperspaceException(
+                f"{C.TPU_MESH_DEVICES} must be a positive integer; got {v!r}."
+            )
+        return n
+
     def build_mode(self) -> str:
         v = str(self.get(C.BUILD_MODE, C.BUILD_MODE_DEFAULT)).lower()
         if v not in C.BUILD_MODES:

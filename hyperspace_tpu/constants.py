@@ -368,6 +368,13 @@ SIGNATURE_PROVIDER = "hyperspace.index.signatureProvider"
 # (data) parallelism, and the on-disk row-block alignment for HBM streaming.
 TPU_MESH_BUCKET_AXIS = "hyperspace.tpu.mesh.bucketAxis"
 TPU_MESH_BUCKET_AXIS_DEFAULT = "buckets"
+# Devices of the session's bucket mesh when HyperspaceSession is given no
+# ``mesh``: above 1 the session builds a 1-D mesh over the first n local
+# devices (builds shard through the ICI all_to_all; queries run the
+# shard_map engine); 1 keeps the single-device paths. More devices than
+# are visible raises at session construction, never falls back.
+TPU_MESH_DEVICES = "hyperspace.tpu.mesh.devices"
+TPU_MESH_DEVICES_DEFAULT = 1
 STORAGE_BLOCK_ALIGN = 128  # bytes; lane-friendly alignment for column buffers
 # Below this many total rows a mesh query executes host-side: the fixed
 # dispatch+transfer latency of a shard_map call cannot win on small data

@@ -1024,9 +1024,12 @@ class StreamingIndexWriter:
         ):
             # multi-chip chunk: shard_map bucketize + ICI all_to_all, then
             # spill each device's (bucket-grouped) shard as its own run
-            # (synchronous — per-device results come back materialized)
+            # (synchronous — per-device results come back materialized;
+            # spans build.sharded_plan / build.sharded_exchange)
             from ..ops.build import build_partition_sharded
 
+            metrics.incr("build.engine.device")
+            metrics.incr("build.engine.sharded")
             per_device, _ = build_partition_sharded(
                 batch, self.indexed_cols, self.num_buckets, self.mesh
             )

@@ -902,19 +902,24 @@ def _sharded_build_fn(
         PartitionSpec(axis),
         PartitionSpec(axis),
     )
-    fn = jax.jit(
-        shard_map(
-            shard_fn,
-            mesh=mesh,
-            in_specs=in_specs,
-            out_specs=out_specs,
-            check_vma=False,
-        )
+    sharded = shard_map(
+        shard_fn,
+        mesh=mesh,
+        in_specs=in_specs,
+        out_specs=out_specs,
+        check_vma=False,
     )
+
+    # named like the single-chip programs: the profile shows
+    # ``jit_build_sharded_exchange``
+    @jax.jit
+    def build_sharded_exchange(arrays, valid, vh):
+        return sharded(arrays, valid, vh)
+
     if len(_sharded_build_cache) >= 64:
         _sharded_build_cache.pop(next(iter(_sharded_build_cache)))
-    _sharded_build_cache[key] = fn
-    return fn
+    _sharded_build_cache[key] = build_sharded_exchange
+    return build_sharded_exchange
 
 
 def _exchange_cap(
@@ -1196,90 +1201,112 @@ def build_partition_sharded(
     (batch, bucket_ids) of valid rows that landed on device d — grouped by
     bucket and key-sorted — and ``global_counts[b]`` is the global row
     count of bucket b. Device d owns buckets ``{b : b % D == d}``.
+
+    Two spans split the call: ``build.sharded_plan`` (host bucket hash,
+    exchange capacity, pack plan, the H2D puts) and
+    ``build.sharded_exchange`` (the program, then the D2H of every
+    exchange slot and the per-device split). ``build.sharded.rows`` and
+    ``build.sharded.slots`` count the rows and the ``D·D·cap`` slots the
+    all_to_all carries.
     """
+    from ..telemetry.trace import add_bytes, span
+    from ..utils.intmath import next_pow2
+
     axis = mesh.axis_names[0]
     D = mesh.devices.size
     n = batch.num_rows
     dtypes = batch.schema()
 
-    # Host-side twin hash for capacity planning (static shapes for XLA).
-    host_bucket = bucket_ids_host(
-        [key_repr(batch.columns[k]) for k in key_names], num_buckets
-    )
-    host_dest = owner_of_bucket_array(host_bucket, D)
-
-    from ..utils.intmath import next_pow2
-
-    # shard rows quantized to a power of two so repeated chunked calls of
-    # similar sizes share one executable
-    shard_rows = next_pow2(max(-(-n // D), 1))
-    n_pad = shard_rows * D
-    # max rows any one src shard sends to any one dst device, power-of-two
-    # quantized for the same reason (skew varies chunk to chunk)
-    cap = next_pow2(_exchange_cap(host_dest, shard_rows, n, D, D))
-
-    def pad(a: np.ndarray) -> np.ndarray:
-        return np.pad(a, (0, n_pad - n))
-
-    valid_np = pad(np.ones(n, dtype=bool))
-    sharding = NamedSharding(mesh, PartitionSpec(axis))
-    dev_arrays = {
-        name: jax.device_put(pad(encode_for_device(batch.columns[name])), sharding)
-        for name in batch.column_names
-    }
-    valid = jax.device_put(valid_np, sharding)
-    replicated = NamedSharding(mesh, PartitionSpec())
-    vh = {
-        k: jax.device_put(vocab_hashes(batch.columns[k]), replicated)
-        for k in key_names
-        if is_string(dtypes[k])
-    }
-    # the exchange pads with zeros: the pack's bounds must cover them
-    bounds = [_packed_minmax(encode_for_device(batch.columns[k])) for k in key_names]
-    plan = None
-    if all(b is not None for b in bounds):
-        plan = _pack_plan(
-            [(min(lo, 0), max(hi, 0)) for lo, hi in bounds],
-            max(int(num_buckets), 1).bit_length(),
+    with span("build.sharded_plan", rows=n) as plan_span:
+        # Host-side twin hash for capacity planning (static shapes for XLA).
+        host_bucket = bucket_ids_host(
+            [key_repr(batch.columns[k]) for k in key_names], num_buckets
         )
-    narrow = None if plan is None else _narrow_pack(plan, num_buckets)
-    if plan is not None:
-        vh[_PACK_MINS] = jax.device_put(
-            np.array([mn for mn, _ in plan], dtype=np.int64), replicated
-        )
-        vh[_PACK_SHIFTS] = jax.device_put(
-            np.array([kb for _, kb in plan], dtype=np.int32), replicated
-        )
+        host_dest = owner_of_bucket_array(host_bucket, D)
 
-    fn = _sharded_build_fn(
-        mesh,
-        axis,
-        tuple(dtypes.items()),
-        tuple(key_names),
-        tuple(sorted(vh)),
-        num_buckets,
-        cap,
-        narrow,
-    )
-    out_arrays, out_bucket, counts_all, n_valid_all = fn(dev_arrays, valid, vh)
+        # shard rows quantized to a power of two so repeated chunked calls
+        # of similar sizes share one executable
+        shard_rows = next_pow2(max(-(-n // D), 1))
+        n_pad = shard_rows * D
+        # max rows any one src shard sends to any one dst device, power-of-
+        # two quantized for the same reason (skew varies chunk to chunk)
+        cap = next_pow2(_exchange_cap(host_dest, shard_rows, n, D, D))
+        if plan_span is not None:
+            plan_span.labels.update(cap=cap, shard_rows=shard_rows)
 
-    counts_all = np.asarray(counts_all).reshape(D, num_buckets)
-    n_valid_all = np.asarray(n_valid_all).reshape(D)
-    per_device: List[Tuple[ColumnarBatch, np.ndarray]] = []
-    rows_per_dev = D * cap
-    host_arrays = {
-        name: decode_from_device(dtypes[name], np.asarray(a))
-        for name, a in out_arrays.items()
-    }
-    host_bucket_out = np.asarray(out_bucket)
-    for d in range(D):
-        nv = int(n_valid_all[d])
-        sl = slice(d * rows_per_dev, d * rows_per_dev + nv)
-        cols = {
-            name: Column(dtypes[name], host_arrays[name][sl], batch.columns[name].vocab)
+        def pad(a: np.ndarray) -> np.ndarray:
+            return np.pad(a, (0, n_pad - n))
+
+        def put(a: np.ndarray, sharding):
+            add_bytes("h2d_bytes", a.nbytes)
+            return jax.device_put(a, sharding)
+
+        sharding = NamedSharding(mesh, PartitionSpec(axis))
+        dev_arrays = {
+            name: put(pad(encode_for_device(batch.columns[name])), sharding)
             for name in batch.column_names
         }
-        per_device.append((ColumnarBatch(cols), host_bucket_out[sl]))
+        valid = put(pad(np.ones(n, dtype=bool)), sharding)
+        replicated = NamedSharding(mesh, PartitionSpec())
+        vh = {
+            k: put(vocab_hashes(batch.columns[k]), replicated)
+            for k in key_names
+            if is_string(dtypes[k])
+        }
+        # the exchange pads with zeros: the pack's bounds must cover them
+        bounds = [_packed_minmax(encode_for_device(batch.columns[k])) for k in key_names]
+        plan = None
+        if all(b is not None for b in bounds):
+            plan = _pack_plan(
+                [(min(lo, 0), max(hi, 0)) for lo, hi in bounds],
+                max(int(num_buckets), 1).bit_length(),
+            )
+        narrow = None if plan is None else _narrow_pack(plan, num_buckets)
+        if plan is not None:
+            vh[_PACK_MINS] = put(
+                np.array([mn for mn, _ in plan], dtype=np.int64), replicated
+            )
+            vh[_PACK_SHIFTS] = put(
+                np.array([kb for _, kb in plan], dtype=np.int32), replicated
+            )
+
+    with span("build.sharded_exchange", cap=cap, narrow=narrow):
+        fn = _sharded_build_fn(
+            mesh,
+            axis,
+            tuple(dtypes.items()),
+            tuple(key_names),
+            tuple(sorted(vh)),
+            num_buckets,
+            cap,
+            narrow,
+        )
+        out_arrays, out_bucket, counts_all, n_valid_all = fn(dev_arrays, valid, vh)
+
+        counts_all = np.asarray(counts_all).reshape(D, num_buckets)
+        n_valid_all = np.asarray(n_valid_all).reshape(D)
+        per_device: List[Tuple[ColumnarBatch, np.ndarray]] = []
+        rows_per_dev = D * cap
+        host_arrays = {
+            name: decode_from_device(dtypes[name], np.asarray(a))
+            for name, a in out_arrays.items()
+        }
+        host_bucket_out = np.asarray(out_bucket)
+        add_bytes(
+            "d2h_bytes",
+            counts_all.nbytes + n_valid_all.nbytes + host_bucket_out.nbytes
+            + sum(a.nbytes for a in host_arrays.values()),
+        )
+        for d in range(D):
+            nv = int(n_valid_all[d])
+            sl = slice(d * rows_per_dev, d * rows_per_dev + nv)
+            cols = {
+                name: Column(dtypes[name], host_arrays[name][sl], batch.columns[name].vocab)
+                for name in batch.column_names
+            }
+            per_device.append((ColumnarBatch(cols), host_bucket_out[sl]))
+    metrics.incr("build.sharded.rows", n)
+    metrics.incr("build.sharded.slots", D * D * cap)
     global_counts = counts_all.sum(axis=0)
     # Sanity: every input row landed exactly once.
     if int(global_counts.sum()) != n:

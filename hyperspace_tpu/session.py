@@ -119,6 +119,12 @@ class Catalog:
 class HyperspaceSession:
     def __init__(self, conf: Optional[HyperspaceConf] = None, mesh=None):
         self.conf = conf or HyperspaceConf()
+        if mesh is None and self.conf.mesh_devices() > 1:
+            # a bucket mesh from conf (hyperspace.tpu.mesh.devices); more
+            # devices than are visible raises in make_mesh
+            from .parallel.mesh import make_mesh
+
+            mesh = make_mesh(self.conf.mesh_devices(), self.conf.mesh_bucket_axis())
         self.mesh = mesh
         # the residency tier ladder's knobs (hyperspace.residency.*) set
         # PROCESS defaults here: the resident caches are process-global
