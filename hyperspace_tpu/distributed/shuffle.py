@@ -70,9 +70,10 @@ _shuffle_cache: dict = {}
 def _shuffle_fn(mesh: Mesh, dtypes_sig: tuple, cap: int):
     """The one-round repartition program: scatter rows into (D, cap)
     blocks by destination device, all_to_all every plane + the target
-    bucket ids + the validity mask. Mirrors the build kernel's exchange
-    (ops/build.py _sharded_build_fn) minus the sort-by-key epilogue —
-    the join arms downstream do their own sorting."""
+    bucket ids + the validity mask. No sort-by-key epilogue — the join
+    arms downstream do their own sorting. (The build kernel's exchange,
+    ops/build.py _sharded_build_fn, slices a destination-sorted shard
+    instead of scattering, and carries row ids rather than columns.)"""
     axis = mesh.axis_names[0]
     key = (mesh, dtypes_sig, cap)
     fn = _shuffle_cache.get(key)

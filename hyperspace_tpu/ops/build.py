@@ -10,10 +10,12 @@ as XLA programs:
   is the leading sort key, so partitioning and per-bucket ordering happen
   in a single O(n log n) device sort;
 * multi-device: ``shard_map`` over the bucket mesh axis — local bucketize,
-  scatter into fixed-capacity per-destination blocks, ``all_to_all`` over
+  group by destination into fixed-capacity blocks, ``all_to_all`` over
   ICI (replacing Spark's netty shuffle service), then the same local
   (bucket, key...) sort. Bucket b lands on device ``b % n_devices``
-  (parallel.mesh.owner_of_bucket).
+  (parallel.mesh.owner_of_bucket). On one controller only the sort words
+  and row ids travel, and the host takes the rows in the order that comes
+  back.
 
 Static shapes throughout: the exchange uses a host-computed per-(src,dst)
 capacity so XLA sees fixed block sizes; validity is a boolean mask, and
@@ -812,6 +814,9 @@ _sharded_build_cache: dict = {}
 # they cannot collide with the vocab-hash entries keyed by column)
 _PACK_MINS = "\x00pack_mins"
 _PACK_SHIFTS = "\x00pack_shifts"
+# the plane of row ids the program makes and returns (never a column name
+# either)
+_ROW_ID = "\x00row_id"
 
 
 def _sharded_build_fn(
@@ -822,83 +827,103 @@ def _sharded_build_fn(
     vh_names: tuple,
     num_buckets: int,
     cap: int,
-    narrow: Optional[bool] = None,
+    narrow: Optional[bool],
+    carry: tuple,
 ):
     """Build (and cache) the jitted shard_map program for one
-    (mesh, schema, keys, num_buckets, capacity) signature. The streaming
-    build calls this per chunk; without the cache every chunk would
-    re-trace and re-compile, forfeiting the fixed-executable steady state
-    the chunked design exists for. ``cap`` and the shard row count are
-    quantized to powers of two by the caller so per-chunk skew variation
-    doesn't mint new executables. With a pack plan the received rows sort
-    by the packed (bucket, keys...) composite, int32 when ``narrow`` and
-    int64 otherwise (``_pack_composite``, as the single-chip kernel does),
+    (mesh, schema, keys, num_buckets, capacity, carried columns)
+    signature. The streaming build calls this per chunk; without the
+    cache every chunk would re-trace and re-compile, forfeiting the
+    fixed-executable steady state the chunked design exists for. ``cap``
+    and the shard row count are quantized to powers of two by the caller
+    so per-chunk skew variation doesn't mint new executables.
+
+    Each row crosses the all_to_all as its sort words, its row id (its
+    index in the padded global array, made from the shard's index) and
+    the columns named in ``carry``; each device returns its rows' ids and
+    carried columns in (bucket, keys..., row id) order, with its
+    per-bucket counts and its valid row count. The row id as the last
+    sort key keeps ties in source-row order, as the one-device build
+    does. With a pack plan the sort word is the packed (bucket, keys...)
+    composite, made on the source shard, int32 when ``narrow`` and int64
+    otherwise (``_pack_composite``, as the single-chip kernel does),
     whose mins and shifts arrive as the replicated ``vh`` entries
-    _PACK_MINS/_PACK_SHIFTS: the same order as the multi-operand sort, a
-    fraction of its compile. ``narrow=None`` (keys that cannot be packed)
-    keeps the multi-operand sort."""
-    key = (mesh, axis, dtypes_sig, key_names, vh_names, num_buckets, cap, narrow)
+    _PACK_MINS/_PACK_SHIFTS; ``narrow=None`` (keys that cannot be packed)
+    sends the bucket and the keys' sort operands. A process that holds
+    every row takes them itself by row id (``carry=()``); one that cannot
+    reach other processes' rows carries every column.
+
+    No plane is scattered: the shard sorts by destination, and each
+    (D, cap) block is a slice at the destination's start whose slots past
+    its count take a fill that sorts after every row. Both sorts are
+    unstable (the row id makes the receiver's order total): a stable sort
+    doubles the TPU compile."""
+    key = (mesh, axis, dtypes_sig, key_names, vh_names, num_buckets, cap, narrow, carry)
     fn = _sharded_build_cache.get(key)
     if fn is not None:
         return fn
     dtypes = dict(dtypes_sig)
+    keys = list(key_names)
     D = mesh.devices.size
 
     def shard_fn(arrays, valid, vh):
         # local shapes: (shard_rows,)
-        bucket = device_bucket_ids(arrays, dtypes, list(key_names), vh, num_buckets)
-        # invalid rows -> out of range; placement via the ONE shared rule
-        dest = jnp.where(valid, owner_of_bucket_device(bucket, D), D)
-        m = dest.shape[0]
-        iota = lax.iota(jnp.int32, m)
-        sorted_dest, perm = lax.sort([dest, iota], num_keys=1)
-        counts = jnp.bincount(dest, length=D)
-        starts = jnp.concatenate([jnp.zeros(1, counts.dtype), jnp.cumsum(counts)])[:D + 1]
-        pos = iota - starts[jnp.clip(sorted_dest, 0, D)].astype(jnp.int32)
-
-        def exchange(x):
-            buf = jnp.zeros((D, cap) + x.shape[1:], x.dtype)
-            buf = buf.at[sorted_dest, pos].set(x[perm], mode="drop")
-            return lax.all_to_all(buf, axis, split_axis=0, concat_axis=0, tiled=False)
-
-        vmask = jnp.zeros((D, cap), jnp.bool_)
-        vmask = vmask.at[sorted_dest, pos].set(valid[perm], mode="drop")
-        vmask = lax.all_to_all(vmask, axis, split_axis=0, concat_axis=0, tiled=False)
-
-        recv = {name: exchange(x).reshape((D * cap,) + x.shape[1:]) for name, x in arrays.items()}
-        recv_bucket = exchange(bucket).reshape(D * cap)
-        vflat = vmask.reshape(D * cap)
-
-        masked_bucket = jnp.where(vflat, recv_bucket, num_buckets)
+        bucket = device_bucket_ids(arrays, dtypes, keys, vh, num_buckets)
+        m = bucket.shape[0]
+        row_id = lax.axis_index(axis).astype(jnp.int32) * m + lax.iota(jnp.int32, m)
+        # (plane, fill) pairs: the sort words, the row id, the carried columns
         if narrow is not None:
             packed = _pack_composite(
-                recv, key_names, masked_bucket,
-                vh[_PACK_MINS], vh[_PACK_SHIFTS], narrow,
+                arrays, keys, bucket, vh[_PACK_MINS], vh[_PACK_SHIFTS], narrow
             )
-            _, order = lax.sort(
-                [packed, lax.iota(jnp.int32, packed.shape[0])], num_keys=1
-            )
-            out = {name: arr[order] for name, arr in recv.items()}
-            sorted_bucket = masked_bucket[order]
+            words = [(packed, jnp.iinfo(packed.dtype).max)]
         else:
-            out, sorted_bucket, _, _perm = _sort_by_bucket_and_keys(
-                recv, masked_bucket, list(key_names), num_buckets
+            words = [(bucket, num_buckets)] + [
+                (_ordered_sort_operand(arrays[k]), 0) for k in keys
+            ]
+        travel = words + [(row_id, 0)] + [(arrays[name], 0) for name in carry]
+
+        # invalid (padding) rows -> out of range, so they never leave the
+        # shard; placement via the ONE shared rule
+        dest = jnp.where(valid, owner_of_bucket_device(bucket, D), D)
+        grouped = lax.sort(
+            [dest] + [x for x, _ in travel], num_keys=1, is_stable=False
+        )
+        starts = jnp.searchsorted(grouped[0], jnp.arange(D + 1, dtype=jnp.int32))
+        in_block = lax.iota(jnp.int32, cap)[None, :] < (starts[1:] - starts[:-1])[:, None]
+
+        def exchange(x, fill):
+            padded = jnp.concatenate([x, jnp.full((cap,), fill, x.dtype)])
+            blocks = jnp.stack(
+                [lax.dynamic_slice_in_dim(padded, starts[d], cap) for d in range(D)]
             )
-        local_counts = jnp.bincount(masked_bucket, length=num_buckets)
-        n_valid = vflat.sum().astype(jnp.int32)[None]  # rank-1 for out_specs
-        return out, sorted_bucket, local_counts, n_valid
+            blocks = jnp.where(in_block, blocks, jnp.asarray(fill, x.dtype))
+            recv = lax.all_to_all(blocks, axis, split_axis=0, concat_axis=0, tiled=False)
+            return recv.reshape(D * cap)
+
+        recv = [exchange(x, fill) for x, (_, fill) in zip(grouped[1:], travel)]
+        nw = len(words)
+        out = lax.sort(recv, num_keys=nw + 1, is_stable=False)
+        # rows come grouped by bucket: bucket b starts at the first sort
+        # word at or above b's lowest
+        lows = jnp.arange(num_buckets + 1, dtype=out[0].dtype)
+        if narrow is not None:
+            lows = jnp.left_shift(lows, vh[_PACK_SHIFTS].sum().astype(lows.dtype))
+        bounds = jnp.searchsorted(out[0], lows)
+        local_counts = bounds[1:] - bounds[:-1]
+        n_valid = bounds[num_buckets:]  # rank-1 for out_specs
+        carried = dict(zip((_ROW_ID,) + carry, out[nw:]))
+        return carried, local_counts, n_valid
 
     from jax import shard_map
 
-    names = [name for name, _ in dtypes_sig]
     in_specs = (
-        {name: PartitionSpec(axis) for name in names},
+        {name: PartitionSpec(axis) for name, _ in dtypes_sig},
         PartitionSpec(axis),
         {k: PartitionSpec() for k in vh_names},
     )
     out_specs = (
-        {name: PartitionSpec(axis) for name in names},
-        PartitionSpec(axis),
+        {name: PartitionSpec(axis) for name in (_ROW_ID,) + carry},
         PartitionSpec(axis),
         PartitionSpec(axis),
     )
@@ -1141,6 +1166,8 @@ def build_partition_sharded_multihost(
         for k, v in vh_np.items()
     }
 
+    # a process cannot take rows another process holds: every column
+    # travels, and comes back in sorted order
     fn = _sharded_build_fn(
         mesh,
         axis,
@@ -1149,8 +1176,10 @@ def build_partition_sharded_multihost(
         tuple(sorted(vh_np)),
         num_buckets,
         cap,
+        None,
+        tuple(local_batch.column_names),
     )
-    out_arrays, out_bucket, counts_all, n_valid_all = fn(dev_arrays, valid, vh_dev)
+    out_arrays, counts_all, n_valid_all = fn(dev_arrays, valid, vh_dev)
 
     # replicate the global bucket counts (the per-device counts array is
     # distributed; only a replicated reduction is host-readable everywhere)
@@ -1165,12 +1194,13 @@ def build_partition_sharded_multihost(
     shard_of = {s.device: s for s in out_arrays[local_batch.column_names[0]].addressable_shards}
     per_local: List[Tuple[ColumnarBatch, np.ndarray]] = []
     nv_shards = {s.device: s for s in n_valid_all.addressable_shards}
-    bucket_shards = {s.device: s for s in out_bucket.addressable_shards}
+    count_shards = {s.device: s for s in counts_all.addressable_shards}
     col_shards = {
         name: {s.device: s for s in out_arrays[name].addressable_shards}
         for name in local_batch.column_names
     }
     vocabs = {name: local_batch.columns[name].vocab for name in local_batch.column_names}
+    buckets = np.arange(num_buckets, dtype=np.int32)
     for dev in shard_of:
         nv = int(np.asarray(nv_shards[dev].data)[0])
         cols = {
@@ -1183,8 +1213,9 @@ def build_partition_sharded_multihost(
             )
             for name in local_batch.column_names
         }
+        # rows come grouped by bucket, so the counts give each row's bucket
         per_local.append(
-            (ColumnarBatch(cols), np.asarray(bucket_shards[dev].data)[:nv])
+            (ColumnarBatch(cols), np.repeat(buckets, np.asarray(count_shards[dev].data)))
         )
     return per_local, global_counts
 
@@ -1202,12 +1233,17 @@ def build_partition_sharded(
     bucket and key-sorted — and ``global_counts[b]`` is the global row
     count of bucket b. Device d owns buckets ``{b : b % D == d}``.
 
+    Only the key columns go up; the all_to_all carries each row's sort
+    words and its row id in the chunk, and each device sends back its
+    rows' order. The host, which holds every row, takes them in that
+    order (the multihost twin cannot, and moves every column).
+
     Two spans split the call: ``build.sharded_plan`` (host bucket hash,
     exchange capacity, pack plan, the H2D puts) and
-    ``build.sharded_exchange`` (the program, then the D2H of every
-    exchange slot and the per-device split). ``build.sharded.rows`` and
-    ``build.sharded.slots`` count the rows and the ``D·D·cap`` slots the
-    all_to_all carries.
+    ``build.sharded_exchange`` (the program, the D2H of the row ids and
+    counts, and the host take of each device's rows). ``build.sharded.rows``
+    and ``build.sharded.slots`` count the rows and the ``D·D·cap`` slots
+    the all_to_all carries, ``build.sharded.row_id_chunks`` the calls.
     """
     from ..telemetry.trace import add_bytes, span
     from ..utils.intmath import next_pow2
@@ -1242,10 +1278,8 @@ def build_partition_sharded(
             return jax.device_put(a, sharding)
 
         sharding = NamedSharding(mesh, PartitionSpec(axis))
-        dev_arrays = {
-            name: put(pad(encode_for_device(batch.columns[name])), sharding)
-            for name in batch.column_names
-        }
+        enc = {k: encode_for_device(batch.columns[k]) for k in key_names}
+        dev_keys = {k: put(pad(enc[k]), sharding) for k in key_names}
         valid = put(pad(np.ones(n, dtype=bool)), sharding)
         replicated = NamedSharding(mesh, PartitionSpec())
         vh = {
@@ -1253,14 +1287,12 @@ def build_partition_sharded(
             for k in key_names
             if is_string(dtypes[k])
         }
-        # the exchange pads with zeros: the pack's bounds must cover them
-        bounds = [_packed_minmax(encode_for_device(batch.columns[k])) for k in key_names]
+        # the padding rows never leave their shard: the pack's bounds
+        # need cover only the chunk's rows
+        bounds = [_packed_minmax(enc[k]) for k in key_names]
         plan = None
         if all(b is not None for b in bounds):
-            plan = _pack_plan(
-                [(min(lo, 0), max(hi, 0)) for lo, hi in bounds],
-                max(int(num_buckets), 1).bit_length(),
-            )
+            plan = _pack_plan(bounds, max(int(num_buckets), 1).bit_length())
         narrow = None if plan is None else _narrow_pack(plan, num_buckets)
         if plan is not None:
             vh[_PACK_MINS] = put(
@@ -1270,43 +1302,48 @@ def build_partition_sharded(
                 np.array([kb for _, kb in plan], dtype=np.int32), replicated
             )
 
-    with span("build.sharded_exchange", cap=cap, narrow=narrow):
+    # bytes a slot carries: the sort words (the composite, or the bucket
+    # and each key's sort operand) and the int32 row id
+    if plan is not None:
+        word_bytes = 4 if narrow else 8
+    else:
+        word_bytes = 4 + sum(enc[k].dtype.itemsize for k in key_names)
+    payload_bytes = D * D * cap * (word_bytes + 4)
+    with span(
+        "build.sharded_exchange", cap=cap, narrow=narrow, payload_bytes=payload_bytes
+    ):
         fn = _sharded_build_fn(
             mesh,
             axis,
-            tuple(dtypes.items()),
+            tuple((k, dtypes[k]) for k in key_names),
             tuple(key_names),
             tuple(sorted(vh)),
             num_buckets,
             cap,
             narrow,
+            (),
         )
-        out_arrays, out_bucket, counts_all, n_valid_all = fn(dev_arrays, valid, vh)
+        carried, counts_all, n_valid_all = fn(dev_keys, valid, vh)
 
+        row_ids = np.asarray(carried[_ROW_ID])
         counts_all = np.asarray(counts_all).reshape(D, num_buckets)
         n_valid_all = np.asarray(n_valid_all).reshape(D)
+        add_bytes(
+            "d2h_bytes", row_ids.nbytes + counts_all.nbytes + n_valid_all.nbytes
+        )
         per_device: List[Tuple[ColumnarBatch, np.ndarray]] = []
         rows_per_dev = D * cap
-        host_arrays = {
-            name: decode_from_device(dtypes[name], np.asarray(a))
-            for name, a in out_arrays.items()
-        }
-        host_bucket_out = np.asarray(out_bucket)
-        add_bytes(
-            "d2h_bytes",
-            counts_all.nbytes + n_valid_all.nbytes + host_bucket_out.nbytes
-            + sum(a.nbytes for a in host_arrays.values()),
-        )
+        buckets = np.arange(num_buckets, dtype=np.int32)
         for d in range(D):
             nv = int(n_valid_all[d])
-            sl = slice(d * rows_per_dev, d * rows_per_dev + nv)
-            cols = {
-                name: Column(dtypes[name], host_arrays[name][sl], batch.columns[name].vocab)
-                for name in batch.column_names
-            }
-            per_device.append((ColumnarBatch(cols), host_bucket_out[sl]))
+            rows = batch.take(row_ids[d * rows_per_dev : d * rows_per_dev + nv])
+            # the bytes the device round trip of the columns gave
+            _canonicalize_f64(rows)
+            # rows come grouped by bucket, so the counts give each row's bucket
+            per_device.append((rows, np.repeat(buckets, counts_all[d])))
     metrics.incr("build.sharded.rows", n)
     metrics.incr("build.sharded.slots", D * D * cap)
+    metrics.incr("build.sharded.row_id_chunks")
     global_counts = counts_all.sum(axis=0)
     # Sanity: every input row landed exactly once.
     if int(global_counts.sum()) != n:

@@ -116,6 +116,54 @@ def test_sharded_build_matches_single(tmp_path, keys):
     )
 
 
+def tie_sample(n=2600, seed=5):
+    """Keys with heavy duplicates (ties across shards), a first stretch of
+    one key (its source shard sends nothing to the other devices), the
+    same keys far from zero (a narrow pack that the zero padding rows
+    would not fit), a float64 key with -0.0 (no pack plan: the unpacked
+    exchange) and a row-sequence column that shows the order of ties."""
+    rng = np.random.default_rng(seed)
+    k = rng.integers(0, 40, n).astype(np.int64)
+    k[: n // 5] = 7
+    x = rng.integers(-3, 4, n).astype(np.float64) * 0.5
+    x[rng.random(n) < 0.1] = -0.0
+    return ColumnarBatch.from_pydict(
+        {
+            "k": k,
+            "far": k + 10**12,
+            "s": rng.choice([b"AIR", b"MAIL", b"RAIL", b"SHIP"], n).astype(object),
+            "x": x,
+            "seq": np.arange(n, dtype=np.int64),
+            "p": np.where(rng.random(n) < 0.2, -0.0, rng.random(n)),
+        },
+        schema={"k": "int64", "far": "int64", "s": "string", "x": "float64", "seq": "int64", "p": "float64"},
+    )
+
+
+@pytest.mark.parametrize("n_devices", [4, 8])
+@pytest.mark.parametrize("keys", [["k"], ["far"], ["s"], ["x"], ["s", "k"]])
+def test_sharded_rows_equal_single_bucket_by_bucket(keys, n_devices):
+    """Each device's rows of bucket b are the one-device build's rows of
+    bucket b, in the same order (ties in row order) and the same bytes."""
+    b = tie_sample()
+    nb = 12
+    single, counts = build_partition_single(b, keys, nb)
+    per_device, global_counts = build_partition_sharded(b, keys, nb, make_mesh(n_devices))
+    np.testing.assert_array_equal(global_counts, counts)
+    offsets = np.concatenate([[0], np.cumsum(counts)])
+    for bkt in range(nb):
+        dev_batch, bucket_ids = per_device[owner_of_bucket(bkt, n_devices)]
+        got = dev_batch.take(np.flatnonzero(bucket_ids == bkt))
+        want = single.take(np.arange(offsets[bkt], offsets[bkt + 1]))
+        for name in b.column_names:
+            g, w = got.columns[name], want.columns[name]
+            if g.vocab is not None:
+                assert g.to_values().tolist() == w.to_values().tolist()
+            else:
+                assert g.data.dtype == w.data.dtype
+                assert g.data.tobytes() == w.data.tobytes(), (bkt, name)
+
+
 @pytest.mark.parametrize("engine", ["device", "host"])
 def test_write_index_data_and_scan_row_parity(tmp_path, engine):
     b = sample(1500, seed=3)

@@ -31,7 +31,13 @@ INDEXED = ["l_orderkey"]
 INCLUDED = ["l_partkey", "l_quantity", "l_extendedprice", "l_discount", "l_shipdate"]
 NUM_BUCKETS = 200
 CHUNK_ROWS = 4096  # 12,000 rows at the scale below: two full chunks and a tail
-NEW_COUNTERS = ("build.engine.device", "build.engine.sharded", "build.sharded.rows", "build.sharded.slots")
+NEW_COUNTERS = (
+    "build.engine.device",
+    "build.engine.sharded",
+    "build.sharded.rows",
+    "build.sharded.slots",
+    "build.sharded.row_id_chunks",
+)
 
 
 # --- the conf key -----------------------------------------------------------
@@ -133,6 +139,54 @@ def test_sharded_build_holds_the_one_device_builds_rows(built):
         np.testing.assert_array_equal(mesh[b], one[b])
 
 
+def test_sharded_build_files_are_byte_equal_to_the_one_device_builds(built):
+    root, _, _, _, _ = built
+    mesh, one = _index_files(root, "mesh"), _index_files(root, "one")
+    assert [layout.bucket_of_file(f) for f in mesh] == [layout.bucket_of_file(f) for f in one]
+    for m, o in zip(mesh, one):
+        assert m.read_bytes() == o.read_bytes(), m.name
+
+
+@pytest.mark.parametrize("indexed", [["k"], ["s"], ["x"]])
+def test_sharded_streaming_build_keeps_ties_in_the_one_device_order(tmp_path, indexed):
+    """Duplicate keys across shards and chunks, a first stretch of one key
+    (its shard sends nothing to three devices), string keys and a float64
+    key with no pack plan: the mesh build's files equal the one-device
+    build's byte for byte, so ties keep the source-row order."""
+    import pyarrow as pa
+    import pyarrow.parquet as pq
+
+    rng = np.random.default_rng(17)
+    n = 2600  # 1024-row chunks: two full chunks and a tail
+    k = rng.integers(0, 40, n).astype(np.int64)
+    k[:600] = 7
+    x = rng.integers(-3, 4, n).astype(np.float64) * 0.5
+    x[rng.random(n) < 0.1] = -0.0
+    table = pa.table(
+        {
+            "k": k,
+            "s": rng.choice(["AIR", "MAIL", "RAIL", "SHIP"], n),
+            "x": x,
+            "seq": np.arange(n, dtype=np.int64),
+            "p": np.where(rng.random(n) < 0.2, -0.0, rng.random(n)),
+        }
+    )
+    src = tmp_path / "t"
+    src.mkdir()
+    for i, start in enumerate(range(0, n, 700)):
+        pq.write_table(table.slice(start, 700), src / f"part-{i}.parquet")
+    included = [c for c in table.column_names if c not in indexed]
+    before = metrics.snapshot()["counters"].get("build.sharded.row_id_chunks", 0)
+    for name, conf in (("mesh", {C.TPU_MESH_DEVICES: 4}), ("one", {})):
+        s = _session(tmp_path, name, **{C.BUILD_CHUNK_ROWS: 1024}, **conf)
+        Hyperspace(s).create_index(s.read.parquet(str(src)), IndexConfig("li", indexed, included))
+    assert metrics.snapshot()["counters"]["build.sharded.row_id_chunks"] - before == 3
+    mesh, one = _index_files(tmp_path, "mesh"), _index_files(tmp_path, "one")
+    assert mesh and [layout.bucket_of_file(f) for f in mesh] == [layout.bucket_of_file(f) for f in one]
+    for m, o in zip(mesh, one):
+        assert m.read_bytes() == o.read_bytes(), m.name
+
+
 def test_sharded_build_passes_the_plain_reference(built):
     from benchmark.configs import tpch_reference as ref
 
@@ -147,6 +201,11 @@ def test_sharded_build_counts_and_spans(built):
     assert moved["build.engine.device"] == moved["build.engine.sharded"] == chunks
     assert moved["build.sharded.rows"] == lineitem.num_rows
     assert moved["build.sharded.slots"] >= lineitem.num_rows
+    # every chunk crossed the exchange as sort words and row ids
+    assert moved["build.sharded.row_id_chunks"] == chunks
+    # what a slot carried when every index column crossed: the columns,
+    # the int32 bucket and the validity byte
+    column_slot_bytes = sum(lineitem.schema.field(c).type.bit_width // 8 for c in INDEXED + INCLUDED) + 4 + 1
     ingest = trace.find("build.ingest_dispatch")
     plans = [c for c in ingest.children if c.name == "build.sharded_plan"]
     exchanges = [c for c in ingest.children if c.name == "build.sharded_exchange"]
@@ -154,7 +213,8 @@ def test_sharded_build_counts_and_spans(built):
     assert len(plans) == len(exchanges) == chunks - 1
     for p, e in zip(plans, exchanges):
         assert set(p.labels) >= {"rows", "cap", "shard_rows", "h2d_bytes"}
-        assert set(e.labels) >= {"cap", "narrow", "d2h_bytes"}
+        assert set(e.labels) >= {"cap", "narrow", "d2h_bytes", "payload_bytes"}
+        assert 0 < e.labels["payload_bytes"] < 4 * 4 * e.labels["cap"] * column_slot_bytes
         assert p.labels["rows"] == CHUNK_ROWS and e.labels["cap"] == p.labels["cap"]
         assert p.labels["h2d_bytes"] > 0 and e.labels["d2h_bytes"] > 0
         assert p.t1 <= e.t0
@@ -191,6 +251,8 @@ def test_sharded_program_carries_its_name():
     mesh = make_mesh(4)
     row = NamedSharding(mesh, PartitionSpec(mesh.axis_names[0]))
     S = jax.ShapeDtypeStruct
-    fn = B._sharded_build_fn(mesh, mesh.axis_names[0], (("k", "int64"),), ("k",), (), 8, 64)
+    fn = B._sharded_build_fn(mesh, mesh.axis_names[0], (("k", "int64"),), ("k",), (), 8, 64, None, ())
     text = fn.lower({"k": S((1024,), jnp.int64, sharding=row)}, S((1024,), jnp.bool_, sharding=row), {}).as_text()
     assert text.split("module @", 1)[1].split(" ", 1)[0] == "jit_build_sharded_exchange"
+    # the blocks are slices of the destination-sorted shard, not scatters
+    assert "scatter" not in text
