@@ -285,7 +285,6 @@ class CreateActionBase:
                 finalize_mode=self.conf.build_finalize_mode(),
                 chunk_tasks=chunk_tasks,
                 pipeline=pipeline,
-                device=self.conf.build_device(),
             )
         batch = self.prepare_index_batch(relation, indexed, included, lineage, tracker)
         return write_index_data(

@@ -992,8 +992,8 @@ def contexts_from_paths(
 ) -> List[Tuple[ModuleContext, str, bool]]:
     """Parse every ``.py`` under ``paths`` into build_project inputs.
     Module names are derived relative to each root's parent, so passing
-    ``repo/hyperspace_tpu repo/scripts repo/bench.py`` yields
-    ``hyperspace_tpu.*``, ``scripts.*`` and ``bench``. Unparseable files
+    ``repo/hyperspace_tpu repo/scripts repo/chip_smoke.py`` yields
+    ``hyperspace_tpu.*``, ``scripts.*`` and ``chip_smoke``. Unparseable files
     are skipped here — per-file analysis reports them as HS000."""
     from .core import iter_python_files
 

@@ -35,14 +35,13 @@ SCOPE = (
 # Modules whose purpose IS the device<->host boundary: kernels marshal
 # arguments and read results back, the scan/distributed layers rematerialize
 # masks and partials on host, the HBM/mesh caches fence residency, the
-# scan gate / device bench measure the link itself, and floatbits/bitpack
+# scan gate measures the link itself, and floatbits/bitpack
 # ARE the transport formats (host-side encode of the f64 ordered planes
 # and of the bit-packed residency words).
 BOUNDARY_MODULES = (
     "hyperspace_tpu/ops/__init__.py",
     "hyperspace_tpu/ops/build.py",
     "hyperspace_tpu/ops/kernels.py",
-    "hyperspace_tpu/ops/device_bench.py",
     "hyperspace_tpu/ops/floatbits.py",
     "hyperspace_tpu/ops/bitpack.py",
     "hyperspace_tpu/exec/scan.py",

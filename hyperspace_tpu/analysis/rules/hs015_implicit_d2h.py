@@ -29,13 +29,12 @@ _BOUNDARY_SUFFIXES = (
     ".ops",
     ".ops.build",
     ".ops.kernels",
-    ".ops.device_bench",
     ".ops.floatbits",
     ".ops.bitpack",
 )
-# non-library top-level trees: CLI scripts and benches print results to
-# a human — their trailing readback is the program's output
-_SKIP_TOP_SEGMENTS = {"scripts", "tests", "bench"}
+# non-library top-level trees: CLI scripts print results to a human —
+# their trailing readback is the program's output
+_SKIP_TOP_SEGMENTS = {"scripts", "tests"}
 
 _KIND_VERB = {
     "float": "float() casts",

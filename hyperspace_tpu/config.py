@@ -256,34 +256,6 @@ class HyperspaceConf:
             queue_depth=max(1, int(self.get(C.BUILD_QUEUE_DEPTH, auto.queue_depth))),
         )
 
-    def build_device(self):
-        """The DeviceBuildConfig from the
-        ``hyperspace.index.build.device.*`` knobs (docs/14-build-
-        pipeline.md, device-resident build): ``doubleBuffer`` rotates
-        the fixed host slab pair under the H2D, ``runChunks`` sets how
-        many device-sorted chunks accumulate into one HBM-resident run
-        before the on-device merge ships it. ``runChunks`` below 1
-        clamps to 1 (the per-chunk round-trip mode)."""
-        from .index.stream_builder import DeviceBuildConfig
-
-        return DeviceBuildConfig(
-            double_buffer=self._to_bool(
-                self.get(
-                    C.BUILD_DEVICE_DOUBLE_BUFFER,
-                    C.BUILD_DEVICE_DOUBLE_BUFFER_DEFAULT,
-                )
-            ),
-            run_chunks=max(
-                1,
-                int(
-                    self.get(
-                        C.BUILD_DEVICE_RUN_CHUNKS,
-                        C.BUILD_DEVICE_RUN_CHUNKS_DEFAULT,
-                    )
-                ),
-            ),
-        )
-
     def compaction_enabled(self) -> bool:
         v = str(self.get(C.INDEX_COMPACTION, C.INDEX_COMPACTION_DEFAULT)).lower()
         if v not in C.INDEX_COMPACTION_MODES:

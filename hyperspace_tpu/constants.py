@@ -55,11 +55,12 @@ BUILD_FINALIZE_MODE_DEFAULT = BUILD_FINALIZE_MERGE
 # auto mode streams when the source files exceed this many bytes on disk
 BUILD_STREAMING_THRESHOLD_BYTES = "hyperspace.index.build.streamingThresholdBytes"
 BUILD_STREAMING_THRESHOLD_BYTES_DEFAULT = 256 * 1024 * 1024
-# Streaming-build chunk engine: device (fused XLA bucketize+sort), host
-# (numpy lexsort twin), or auto — probe both on early chunks and route the
-# rest to the measured winner (a thin device link makes the per-chunk D2H
-# readback dominate). The chosen engine is observable as build.engine.*
-# counters.
+# Build engine: device (fused XLA bucketize+sort), host (numpy lexsort
+# twin), or auto — resolved once per build by one static rule
+# (index.builder.resolve_build_engine): host on a CPU platform, for an
+# in-memory build below INMEMORY_HOST_MAX_ROWS, or when the first device
+# touch failed; device otherwise. The chosen engine is observable as
+# build.engine.* counters.
 BUILD_ENGINE = "hyperspace.index.build.engine"
 BUILD_ENGINE_AUTO = "auto"
 BUILD_ENGINE_DEVICE = "device"
@@ -69,25 +70,14 @@ BUILD_ENGINE_DEFAULT = BUILD_ENGINE_AUTO
 # Pipelined build (docs/14-build-pipeline.md): worker counts and queue
 # depths of the staged ingest→dispatch→spill-compute→spill-write→merge
 # pipeline. pipeline=off runs every stage inline on the caller thread
-# (zero background threads — the deterministic A/B baseline of bench
-# config 13 and the debugging escape hatch). Worker counts accept an int
+# (zero background threads — the deterministic reference of the parity
+# tests and the debugging escape hatch). Worker counts accept an int
 # or "auto" (derived from the host core count).
 BUILD_PIPELINE = "hyperspace.index.build.pipeline"
 BUILD_PIPELINE_ON = "on"
 BUILD_PIPELINE_OFF = "off"
 BUILD_PIPELINE_MODES = (BUILD_PIPELINE_ON, BUILD_PIPELINE_OFF)
 BUILD_PIPELINE_DEFAULT = BUILD_PIPELINE_ON
-# Device-resident streaming build (docs/14-build-pipeline.md): the
-# device engine's steady-state shape. doubleBuffer rotates a fixed pair
-# of host staging slabs under the H2D so chunk k+1's upload overlaps
-# chunk k's kernel; runChunks (R) accumulates R device-sorted chunks in
-# HBM and merges them into ONE spill run on device — R× fewer blocking
-# D2H calls, R× fewer runs for finalize. runChunks=1 is the per-chunk
-# round-trip mode (the bench-18 A side and the byte-parity anchor).
-BUILD_DEVICE_DOUBLE_BUFFER = "hyperspace.index.build.device.doubleBuffer"
-BUILD_DEVICE_DOUBLE_BUFFER_DEFAULT = True
-BUILD_DEVICE_RUN_CHUNKS = "hyperspace.index.build.device.runChunks"
-BUILD_DEVICE_RUN_CHUNKS_DEFAULT = 4
 BUILD_INGEST_WORKERS = "hyperspace.index.build.ingestWorkers"
 BUILD_SPILL_COMPUTE_WORKERS = "hyperspace.index.build.spillComputeWorkers"
 BUILD_SPILL_WRITE_WORKERS = "hyperspace.index.build.spillWriteWorkers"
@@ -169,8 +159,8 @@ INDEX_COMPACTION_MAX_STEPS_PER_SWEEP_DEFAULT = 1
 # How (run file, bucket) segment reads execute: "planned" (default)
 # merges adjacent/near-adjacent ranges into one ordered sweep per run
 # file fanned across the worker pool; "naive" issues one ranged read per
-# segment — the pre-planner behavior, kept as the A/B lever bench
-# config 17 pulls (HYPERSPACE_TPU_SEGMENT_IO overrides both).
+# segment — the pre-planner behavior, kept as an A/B lever
+# (HYPERSPACE_TPU_SEGMENT_IO overrides both).
 STORAGE_SEGMENT_IO = "hyperspace.storage.segmentIo"
 STORAGE_SEGMENT_IO_PLANNED = "planned"
 STORAGE_SEGMENT_IO_NAIVE = "naive"
@@ -333,8 +323,8 @@ EVENT_LOGGER_CLASS = "hyperspace.eventLoggerClass"
 # Per-query span tracing (telemetry/trace.py; docs/18-observability.md).
 # "on" opens a trace per collect()/served ticket (span sites then record;
 # the flight recorder rings completed traces); "off" restores the
-# pre-tracing entry points — the A/B lever the bench config-10 overhead
-# gate pulls. No reference analog: Spark delegates this to its UI.
+# pre-tracing entry points — the A/B lever for tracing overhead. No
+# reference analog: Spark delegates this to its UI.
 TELEMETRY_TRACING = "hyperspace.telemetry.tracing"
 TELEMETRY_TRACING_ON = "on"
 TELEMETRY_TRACING_OFF = "off"
@@ -390,8 +380,7 @@ TPU_PROFILE_DIR = "hyperspace.tpu.profile.dir"
 # --- whole-plan compilation (hyperspace_tpu/compile) -------------------------
 # Lower an optimized plan subtree to ONE fused pipeline (docs/17): "auto"
 # compiles every executed plan (interpreter stays the fallback leg),
-# "off" restores pure per-operator interpretation (the A/B lever bench
-# config 16 pulls).
+# "off" restores pure per-operator interpretation (the A/B lever).
 COMPILE_MODE = "hyperspace.compile.mode"
 COMPILE_MODE_AUTO = "auto"
 COMPILE_MODE_OFF = "off"

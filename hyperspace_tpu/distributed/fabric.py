@@ -116,7 +116,7 @@ class QueryFabric:
         """Stand the serve front up over ``{host: session}``: one
         QueryServer per host session plus the health-directed
         QueryRouter fronting them — the one assembly path every
-        multi-host serving test, bench config 20, and a real pod share,
+        multi-host serving test and a real pod share,
         so the failure-domain wiring (health director, hedges, retry
         budgets) is never re-plumbed by hand."""
         from ..serve.server import QueryServer, ServeConfig

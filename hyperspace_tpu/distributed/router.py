@@ -246,7 +246,7 @@ class QueryRouter:
 
     ``health_policy`` shapes the failure-domain state machine,
     ``retry_policy`` the failover backoff; ``hedging=False`` disables
-    tail hedges (the A-leg of bench config 20 measures exactly that)."""
+    tail hedges (the A-leg of a hedging A/B)."""
 
     def __init__(
         self,

@@ -2,9 +2,9 @@
 
 Round-2 verdict weak #2: the scan's device gate was a static constant
 (``MIN_DEVICE_ROWS = 1_000_000``) with no evidence the threshold was right
-on any given deployment, while the build engine routes by measurement.
-This module applies the build's probe design (index/stream_builder.py) to
-the scan path. Per padded-size class (pow2 of the file's row count):
+on any given deployment. This module routes the non-resident scan by an
+in-process measurement instead. Per padded-size class (pow2 of the file's
+row count):
 
 1. the first eligible batch runs the HOST mask, timed;
 2. a compile-free LINK check times moving the predicate's column bytes
@@ -16,13 +16,11 @@ the scan path. Per padded-size class (pow2 of the file's row count):
    bearer) and the one after is the timed warm device round; the measured
    winner takes every later batch in that size class.
 
-Verdicts memoize in-process and persist to the same cross-process disk
-memo as the build probe (``scan.<platform>`` key prefix, same 24h TTL).
+Verdicts memoize in-process only: each process measures its own.
 Batches under ``PROBE_MIN_ROWS`` never probe: at small sizes the probe
 itself (a device transfer, potentially a compile) costs more than any
-possible win — the same reasoning as the build's partial-chunk rule — so
-they route host unconditionally, which also keeps small-fixture test runs
-deterministic.
+possible win — so they route host unconditionally, which also keeps
+small-fixture test runs deterministic.
 
 RESIDENCY-AWARENESS: the gate's link arithmetic prices the per-query
 H2D upload — which HBM-resident tables (exec/hbm_cache.py) have already
@@ -74,16 +72,6 @@ class ScanGate:
             st = self._state.setdefault(n_pad, {})
             if "winner" in st:
                 return st["winner"]
-            check_disk = not st.get("disk_checked")
-            st["disk_checked"] = True  # at most one file read per class
-        persisted = self._load_disk(n_pad) if check_disk else None
-        with self._lock:
-            if persisted is not None and "winner" not in st:
-                st["winner"] = persisted
-                st["source"] = "disk"
-                metrics.incr("scan.gate.winner_from_disk_cache")
-            if "winner" in st:
-                return st["winner"]
             if "host_s" not in st:
                 return "probe-host"
             if "link_pending" in st:
@@ -130,10 +118,8 @@ class ScanGate:
             st = self._state.setdefault(n_pad, {})
             st.pop("link_pending", None)
             if "winner" in st:
-                # a disk verdict landed while this probe was in flight
-                # (decide()'s one-shot disk check races the probe ladder):
-                # the persisted verdict stands — never overwrite it with
-                # this stray probe's conclusion
+                # a device failure pinned host while this probe was in
+                # flight: that verdict stands
                 return
             if link_s is None:
                 # no usable device: decide host now, don't keep probing
@@ -148,7 +134,7 @@ class ScanGate:
                     metrics.incr("scan.gate.chose_host_by_link")
                 else:
                     return  # link is fast: device probe stages may proceed
-        self._persist(n_pad)
+        self._count_winner(n_pad)
 
     def wait_probe(
         self, n_rows: Optional[int] = None, timeout: float = 10.0
@@ -214,7 +200,7 @@ class ScanGate:
             # post-release re-read raced record_device_failure's pin
             winner = st["winner"]
         if winner_new:
-            self._persist(n_pad)
+            self._count_winner(n_pad)
         return winner
 
     def _time_link(self, arrays: dict, n_rows: int) -> Optional[float]:
@@ -240,24 +226,10 @@ class ScanGate:
             metrics.incr("scan.gate.probe_link_error")
             return None
 
-    def _disk_key(self, n_pad: int) -> tuple:
-        from ..index.stream_builder import _engine_cache_key
-
-        platform = _engine_cache_key(0)[0]  # (platform, capacity, width)
-        return (f"scan.{platform}", n_pad)
-
-    def _load_disk(self, n_pad: int) -> Optional[str]:
-        from ..index.stream_builder import _load_persisted_winner
-
-        return _load_persisted_winner(self._disk_key(n_pad))
-
-    def _persist(self, n_pad: int) -> None:
-        from ..index.stream_builder import _persist_winner
-
+    def _count_winner(self, n_pad: int) -> None:
         with self._lock:
             winner = self._state[n_pad]["winner"]
         metrics.incr(f"scan.gate.chose_{winner}")
-        _persist_winner(self._disk_key(n_pad), winner)
 
     # -- observability -------------------------------------------------------
     def snapshot(self) -> dict:
@@ -272,7 +244,7 @@ class ScanGate:
             for k in ("host_s", "link_s", "device_s"):
                 if k in st:
                     row[k] = round(st[k], 5)
-            for k in ("winner", "by", "source"):
+            for k in ("winner", "by"):
                 if k in st:
                     row[k] = st[k]
             out[str(n_pad)] = row

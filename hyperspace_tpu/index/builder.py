@@ -57,7 +57,7 @@ def write_index_data(
     into ``out_dir``. Returns written paths. ``mesh`` selects the sharded
     (ICI all_to_all) path; None routes between the single-device kernel
     and its host twin (``engine``: device | host | auto — see
-    _route_inmemory_engine). ``host_workers`` > 1 runs the host twin's
+    resolve_build_engine). ``host_workers`` > 1 runs the host twin's
     one big stable sort across that many threads
     (ops.build.build_partition_host_parallel — identical output): the
     in-memory build has a single sort, so intra-sort parallelism is the
@@ -91,7 +91,8 @@ def write_index_data(
             for s, e in zip(starts, ends):
                 write_bucket(int(bucket_ids[s]), dev_batch.take(np.arange(s, e)))
     else:
-        if _route_inmemory_engine(engine, batch.num_rows) == "host":
+        engine = resolve_build_engine(engine, batch.num_rows, streaming=False)
+        if engine == "host":
             from ..ops.build import build_partition_host_parallel
 
             metrics.incr("build.engine.host")
@@ -116,20 +117,38 @@ def write_index_data(
 # In-memory builds run ONE kernel launch, so a fresh XLA compile (tens of
 # seconds on TPU) cannot amortize the way the streaming build's per-chunk
 # executable does. (build_partition_single's jitted closure is cached per
-# (schema, keys, buckets) now, so repeats DO reuse the executable — but a
+# (schema, keys, buckets), so repeats DO reuse the executable — but a
 # one-shot build's FIRST launch still bears the compile.) Below this many
-# rows the host twin is therefore the sure win; above it the device
-# sort's throughput can cover the compile. (The streaming probe cache
-# deliberately does NOT override here: its measurements come from a warm
-# per-chunk executable, a premise one-shot builds don't share.)
+# rows the host twin is therefore the sure win.
 INMEMORY_HOST_MAX_ROWS = 1 << 22
 
 
-def _route_inmemory_engine(engine: str, n_rows: int) -> str:
-    if engine in ("device", "host"):
-        return engine
+def resolve_build_engine(engine: str, n_rows: int, streaming: bool) -> str:
+    """The engine of one build (``hyperspace.index.build.engine``),
+    resolved once per build by both writers. ``device`` and ``host`` pin.
+    ``auto`` is host on a CPU platform, host for an in-memory build below
+    INMEMORY_HOST_MAX_ROWS rows, host when the process's first device
+    touch failed (counted ``build.engine.device_unreachable``), and device
+    otherwise: every chunk of a streaming build, its tail included. A
+    failed first touch raises DeviceFault where the device is required —
+    ``device`` pinned, or a TPU platform (utils/deviceprobe.py)."""
+    from .. import ops
+    from ..utils.deviceprobe import first_device_touch_ok
+
+    if engine == "host":
+        return "host"
+    if engine == "device":
+        first_device_touch_ok(required=True)
+        return "device"
     if engine != "auto":
         raise HyperspaceException(
             f"Unknown build engine {engine!r}; expected device, host, or auto."
         )
-    return "host" if n_rows < INMEMORY_HOST_MAX_ROWS else "device"
+    if ops.configured_platform() == "cpu":
+        return "host"
+    if not streaming and n_rows < INMEMORY_HOST_MAX_ROWS:
+        return "host"
+    if not first_device_touch_ok():
+        metrics.incr("build.engine.device_unreachable")
+        return "host"
+    return "device"

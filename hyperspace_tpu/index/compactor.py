@@ -37,7 +37,7 @@ every runs-layout segment read): the buckets queries actually touch
 become join-competitive per-bucket files first. Convergence — no run
 files AND no multi-small-file buckets left — produces exactly
 ``optimize(quick)``'s file layout (same partition rule, same merge
-procedure, same part order), which the bench config-17 gate pins.
+procedure, same part order), which tests/test_compactor.py pins.
 """
 
 from __future__ import annotations

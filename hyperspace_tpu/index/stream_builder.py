@@ -36,14 +36,14 @@ Chunk ORDER is preserved end to end (ordered ingest, sequence-numbered
 runs, run-ordered stable merges), so the built index is byte-identical to
 a serial build — ``BuildPipelineConfig.serial()`` (conf
 ``hyperspace.index.build.pipeline=off``) runs the same code inline with
-zero threads, which is the A/B baseline of bench config 13. A failure in
-any stage latches a shared ``FirstError``; every stage drains, teardown
-joins every worker, and the FIRST error re-raises on the main thread.
+zero threads, the reference the parity tests compare against. A failure
+in any stage latches a shared ``FirstError``; every stage drains,
+teardown joins every worker, and the FIRST error re-raises on the main
+thread.
 """
 
 from __future__ import annotations
 
-import json
 import os
 import queue
 import shutil
@@ -70,13 +70,18 @@ from ..storage.columnar import Column, ColumnarBatch, is_string
 from ..telemetry.metrics import metrics
 from ..telemetry.trace import add_bytes as _trace_bytes
 from ..telemetry.trace import span as _trace_span
-from ..utils.memo import bounded_memo_put
+from .builder import resolve_build_engine
 
 SPILL_DIR_NAME = ".spill"
 
 # Device-engine in-flight chunk cap (HBM high-water), independent of the
 # spill-compute pool width — see StreamingIndexWriter.__init__.
 DEVICE_INFLIGHT_CHUNKS = 3
+
+# Device-sorted chunks the run stager keeps in HBM and merges into ONE
+# spill run on device: R× fewer blocking D2H calls, R× fewer runs for
+# finalize to merge (docs/14-build-pipeline.md, device-resident build).
+RUN_CHUNKS = 4
 
 
 @dataclass(frozen=True)
@@ -85,9 +90,9 @@ class BuildPipelineConfig:
     ``hyperspace.index.build.*`` knobs (docs/14-build-pipeline.md).
 
     ``enabled=False`` is the SERIAL mode: every stage runs inline on the
-    caller thread with zero background threads — the deterministic A/B
-    baseline (bench config 13) and the debugging escape hatch. In-flight
-    chunk memory under the pipelined mode is bounded by
+    caller thread with zero background threads — the deterministic
+    reference the parity tests compare against, and the debugging escape
+    hatch. In-flight chunk memory under the pipelined mode is bounded by
     ``ingest_workers + spill_compute_workers + spill_write_workers +
     2·queue_depth`` chunks; on the device engine the dispatched-but-
     unfetched chunks (HBM high-water) are bounded by
@@ -125,170 +130,11 @@ class BuildPipelineConfig:
 
     def host_width(self) -> int:
         """Effective host-sort parallelism: how many spill-compute
-        workers can really run host partition+sorts side by side. Folded
-        into the engine-probe cache key so a 1-core verdict never binds
-        a 16-core run (and vice versa)."""
+        workers can really run host partition+sorts side by side — the
+        thread count of the in-memory build's one host sort."""
         if not self.enabled:
             return 1
         return max(1, min(self.spill_compute_workers, os.cpu_count() or 1))
-
-
-@dataclass(frozen=True)
-class DeviceBuildConfig:
-    """The device engine's streaming-mode knobs — the
-    ``hyperspace.index.build.device.*`` family (docs/14-build-pipeline.md,
-    device-resident build).
-
-    ``double_buffer`` rotates a fixed PAIR of host staging slabs under
-    the H2D (the PR-8 streaming-residency slab discipline applied to the
-    build): chunk k+1's bytes stream from a stable, pinnable buffer
-    while chunk k's kernel runs, and the dispatch loop stops allocating
-    per chunk. ``run_chunks`` (R) accumulates R device-sorted chunks in
-    HBM and merges them into ONE spill run with an on-device k-way merge
-    — R× fewer blocking D2H calls, R× fewer runs for finalize to merge.
-
-    ``run_chunks=1`` is the per-chunk round-trip mode — the bench-18 A
-    side and the byte-identical parity anchor; both knobs fold into the
-    engine-probe cache key (mode_token) so a per-chunk verdict never
-    binds a double-buffered run, and vice versa."""
-
-    double_buffer: bool = True
-    run_chunks: int = 4
-
-    @staticmethod
-    def default() -> "DeviceBuildConfig":
-        return DeviceBuildConfig()
-
-    @staticmethod
-    def per_chunk() -> "DeviceBuildConfig":
-        return DeviceBuildConfig(double_buffer=False, run_chunks=1)
-
-    def mode_token(self) -> str:
-        return f"db{int(bool(self.double_buffer))}-r{int(self.run_chunks)}"
-
-# Per-process memo of the auto engine probe's winner ("device" | "host"),
-# keyed by (JAX backend platform, padded chunk capacity). The probe
-# measures the host↔device LINK as much as the kernels — a property of the
-# process's runtime — so later builds skip straight to the measured winner
-# instead of re-paying a full device round trip (and its compile) per
-# index. Capacity stays in the key because the device/host ratio flips
-# with chunk size (host sort is O(n log n) on real rows, device D2H scales
-# with the padded capacity); capacities are already power-of-two quantized
-# so the memo stays small — and bounded_memo_put makes that bound
-# explicit instead of an argument in a comment (hslint HS006).
-_ENGINE_CACHE: Dict[tuple, str] = {}
-_ENGINE_CACHE_MAX = 64
-
-
-def _engine_cache_key(
-    chunk_capacity: int,
-    host_width: Optional[int] = None,
-    device_mode: Optional[str] = None,
-) -> tuple:
-    """(platform, capacity, host width, device mode) memo key. The
-    platform MUST be derived without initializing the jax backend: cold
-    backend init costs seconds, and paying it just to
-    look up a verdict that says "host" would charge every pure-host
-    build the device tax the memo exists to avoid. The configured
-    platform string (env / jax.config) is a faithful proxy — it is what
-    decides which backend WOULD initialize.
-
-    ``host_width`` is the build's effective host-sort parallelism
-    (BuildPipelineConfig.host_width): the host engine's throughput
-    scales with the spill-compute pool while the device engine's does
-    not, so a verdict measured at width 1 must not bind a width-16 run —
-    the widths get separate slots (and separate persisted entries).
-    ``None`` means "the default pipeline's width on this machine".
-
-    ``device_mode`` is the DEVICE engine's throughput shape
-    (DeviceBuildConfig.mode_token — double-buffer × runChunks): the
-    host_width lesson applied to the other engine. A per-chunk
-    round-trip verdict must not bind a double-buffered staged run (the
-    staged engine pays 1/R of the D2H the probe measured), and vice
-    versa — the modes get separate slots. ``None`` means the default
-    device mode."""
-    from ..ops import configured_platform
-
-    if host_width is None:
-        host_width = BuildPipelineConfig.default().host_width()
-    if device_mode is None:
-        device_mode = DeviceBuildConfig.default().mode_token()
-    return (
-        configured_platform(),
-        chunk_capacity,
-        int(host_width),
-        str(device_mode),
-    )
-
-
-def _probe_cache_path() -> Optional[Path]:
-    """Cross-process home of the probe memo. The verdict is a property of
-    the MACHINE (backend platform + link bandwidth + chunk capacity), not
-    of one process, so a fresh process should not re-pay the probe's
-    device compile + round trip — that cost is why the recorded cold
-    ``build_s`` trailed the external baseline in round 2. Overridable via
-    ``HYPERSPACE_TPU_PROBE_CACHE`` (empty string disables; tests disable
-    it so probe-path assertions stay hermetic)."""
-    env = os.environ.get("HYPERSPACE_TPU_PROBE_CACHE")
-    if env is not None:
-        return Path(env) if env else None
-    return Path(os.path.expanduser("~/.cache/hyperspace_tpu/engine_probe.json"))
-
-
-# One day: long enough that a bench/CI process never re-probes, short
-# enough that one session's verdict cannot permanently rule an engine
-# out — the per-process memo's self-healing must survive the move to
-# disk.
-PROBE_CACHE_TTL_S = 24 * 3600.0
-
-
-def _load_persisted_winner(key: tuple) -> Optional[str]:
-    p = _probe_cache_path()
-    if p is None:
-        return None
-    try:
-        text = p.read_text()
-    except OSError:  # absent/unreadable cache = no verdict (common case)
-        return None
-    try:
-        data = json.loads(text)
-    except ValueError:
-        # corrupt cache silently disables cross-process probe reuse —
-        # every future build re-pays the probe; make that visible
-        metrics.incr("build.engine.probe_cache_corrupt")
-        return None
-    if not isinstance(data, dict):
-        # valid JSON that is not an object (truncated/clobbered write)
-        metrics.incr("build.engine.probe_cache_corrupt")
-        return None
-    v = data.get(":".join(str(p) for p in key))
-    if not isinstance(v, dict) or v.get("winner") not in ("device", "host"):
-        return None
-    try:
-        if time.time() - float(v["ts"]) > PROBE_CACHE_TTL_S:
-            return None
-    except (KeyError, TypeError, ValueError):  # missing/malformed ts = stale
-        return None
-    return v["winner"]
-
-
-def _persist_winner(key: tuple, choice: str) -> None:
-    p = _probe_cache_path()
-    if p is None:
-        return
-    try:
-        p.parent.mkdir(parents=True, exist_ok=True)
-        try:
-            data = json.loads(p.read_text())
-        except (OSError, ValueError):  # fresh or corrupt file: start over
-            data = {}
-        data[":".join(str(p) for p in key)] = {"winner": choice, "ts": time.time()}
-        tmp = p.with_name(p.name + f".tmp-{uuid.uuid4().hex[:8]}")
-        tmp.write_text(json.dumps(data, indent=0))
-        os.replace(tmp, p)  # atomic: concurrent writers last-write-win
-    except Exception:  # noqa: BLE001 - caching must never fail a build
-        # but a persistently unwritable cache silently re-probes forever
-        metrics.incr("build.engine.probe_cache_write_error")
 
 
 def sort_encoding(col: Column) -> np.ndarray:
@@ -353,13 +199,13 @@ def merge_sorted_runs(runs: List[ColumnarBatch], key_names: List[str]) -> Column
 
 class _HostSlabPair:
     """The fixed pair of host staging buffers under the device engine's
-    H2D (the ``doubleBuffer`` knob): the dispatch loop ROTATES slots
-    instead of allocating per chunk, so chunk k+1's bytes stream from a
-    stable — on a real TPU runtime, pinnable — buffer while chunk k's
-    kernel runs. Before a slot is refilled the loop fences on the
-    device work that consumed its previous upload (two chunks back —
-    long finished in steady state, so the fence only ever waits when
-    the host has genuinely outrun the device)."""
+    H2D: the dispatch loop ROTATES slots instead of allocating per chunk,
+    so chunk k+1's bytes stream from a stable — on a real TPU runtime,
+    pinnable — buffer while chunk k's kernel runs. Before a slot is
+    refilled the loop fences on the device work that consumed its
+    previous upload (two chunks back — long finished in steady state, so
+    the fence only ever waits when the host has genuinely outrun the
+    device)."""
 
     def __init__(self) -> None:
         self._bufs: List[Optional[Dict[str, np.ndarray]]] = [None, None]
@@ -397,7 +243,7 @@ class _HostSlabPair:
 class _DeviceRunStager:
     """Accumulates device-sorted chunks into HBM-resident runs
     (docs/14-build-pipeline.md, device-resident build): chunk k's packed
-    composite + permutation stay ON DEVICE until ``run_chunks`` chunks
+    composite + permutation stay ON DEVICE until ``RUN_CHUNKS`` chunks
     have landed — or the run's 63-bit pack budget would overflow, or
     finalize arrives — then ONE on-device k-way merge produces the run
     order and ONE non-blocking D2H ships it to the spill stages. The
@@ -419,10 +265,9 @@ class _DeviceRunStager:
     # — one named constant so the charge and the doc stay in one place
     STAGED_BYTES_PER_ROW = 36
 
-    def __init__(self, writer: "StreamingIndexWriter", device: "DeviceBuildConfig"):
+    def __init__(self, writer: "StreamingIndexWriter"):
         self.w = writer
-        self.device = device
-        self.slab = _HostSlabPair() if device.double_buffer else None
+        self.slab = _HostSlabPair()
         self.pending: List = []  # ops.build.StagedChunk
         self.batches: List[ColumnarBatch] = []
         self.union: Optional[List[tuple]] = None
@@ -437,7 +282,7 @@ class _DeviceRunStager:
             return self._reserved
         cap = self.w.chunk_capacity
         slab_bytes = 2 * sum(int(a.nbytes) for a in encoded.values())
-        staged = self.STAGED_BYTES_PER_ROW * cap * self.device.run_chunks
+        staged = self.STAGED_BYTES_PER_ROW * cap * RUN_CHUNKS
         self._reserved = slab_budget.try_reserve(
             self._budget_tag, slab_bytes + staged
         )
@@ -472,19 +317,18 @@ class _DeviceRunStager:
             # position — reserved now so later per-chunk spills (the
             # tail, an ineligible chunk) always order after it
             self.seq = self.w._next_seq()
-        bufs = self.slab.stage(encoded) if self.slab is not None else encoded
+        bufs = self.slab.stage(encoded)
         staged, h2d_bytes = stage_chunk_packed(
             bufs, batch.schema(), self.w.indexed_cols, self.w.num_buckets, plan
         )
-        if self.slab is not None:
-            self.slab.fence(staged.packed)
+        self.slab.fence(staged.packed)
         metrics.incr("build.stream.h2d_bytes", h2d_bytes)
         metrics.incr("build.device.staged_chunks")
         _trace_bytes("h2d_bytes", h2d_bytes)
         self.union = union
         self.pending.append(staged)
         self.batches.append(batch)
-        if len(self.pending) >= self.device.run_chunks:
+        if len(self.pending) >= RUN_CHUNKS:
             self.flush()
 
     def flush(self) -> None:
@@ -548,8 +392,7 @@ class _DeviceRunStager:
         self.batches = []
         self.union = None
         self.seq = None
-        if self.slab is not None:
-            self.slab.drop()
+        self.slab.drop()
         slab_budget.release(self._budget_tag)
         self._reserved = None
 
@@ -574,7 +417,6 @@ class StreamingIndexWriter:
         engine: str = "auto",
         finalize_mode: str = "merge",
         pipeline: Optional[BuildPipelineConfig] = None,
-        device: Optional[DeviceBuildConfig] = None,
     ):
         if chunk_capacity < 1:
             raise HyperspaceException("chunk_capacity must be positive.")
@@ -594,13 +436,10 @@ class StreamingIndexWriter:
         self.extra_meta = extra_meta
         self.mesh = mesh
         self.pipeline = pipeline if pipeline is not None else BuildPipelineConfig.default()
-        self.device = device if device is not None else DeviceBuildConfig.default()
-        # chunk engine: device | host | auto (host probe on chunk 0, link
-        # check, device compile on chunk 1, device probe on chunk 2, then
-        # the measured winner — see _route_engine; constants.BUILD_ENGINE
-        # documents why this exists)
+        # chunk engine: device | host | auto, resolved on the first
+        # single-device chunk (index.builder.resolve_build_engine)
         self._engine = engine
-        self._probe: Dict[str, float] = {}
+        self._chunk_engine: Optional[str] = None
         self._spill_dir = self.out_dir / SPILL_DIR_NAME
         self._spills: List[Path] = []
         self._spill_counts: List[np.ndarray] = []
@@ -636,146 +475,6 @@ class StreamingIndexWriter:
         self._t_first_add: Optional[float] = None
         self._t_pipeline_done: Optional[float] = None
 
-    def _route_engine(self, batch_rows: int) -> str:
-        """Which engine runs THIS chunk. Fixed engines pass through. Auto
-        probes HOST FIRST (chunk 0, cheap, no compile), then checks the
-        raw device link: if moving one chunk's bytes D2H already takes
-        longer than the whole host sort, the device path cannot win and
-        its (potentially tens-of-seconds) XLA compile is never paid —
-        the thin-link case. Otherwise chunk 1 runs on device
-        (compile bearer), chunk 2 is the timed device round trip, and the
-        measured winner takes the rest.
-
-        Probes run ONLY on full-capacity chunks: a partial tail is an
-        unrepresentative sample (a 100-row host sort "beating" the link's
-        fixed dispatch overhead would poison the per-capacity memo for
-        the whole process). Partial chunks without a verdict route by the
-        in-memory size policy and publish nothing."""
-        if self._engine in ("device", "host"):
-            return self._engine
-        key = self._cache_key()
-        cached = _ENGINE_CACHE.get(key)
-        if cached is not None:
-            return cached
-        persisted = _load_persisted_winner(key)
-        # honor a disk verdict of "host" unconditionally (host is always
-        # compile-free), but a "device" verdict only for full-capacity
-        # chunks: a fresh process's small partial build would pay the cold
-        # XLA compile the sub-capacity size policy exists to avoid
-        if persisted is not None and (
-            persisted == "host" or batch_rows >= self.chunk_capacity
-        ):
-            bounded_memo_put(_ENGINE_CACHE, key, persisted, _ENGINE_CACHE_MAX)
-            metrics.incr("build.engine.winner_from_disk_cache")
-            return persisted
-        if batch_rows < self.chunk_capacity:
-            from .builder import INMEMORY_HOST_MAX_ROWS
-
-            return "host" if batch_rows < INMEMORY_HOST_MAX_ROWS else "device"
-        ci = len(self._chunk_times)
-        if ci == 0:
-            return "probe-host"
-        if ci == 1:
-            return "device"
-        if ci == 2:
-            return "probe-device"
-        return self._decide_winner()
-
-    def _cache_key(self) -> tuple:
-        return _engine_cache_key(
-            self.chunk_capacity,
-            self.pipeline.host_width(),
-            self.device.mode_token(),
-        )
-
-    def _host_scale(self) -> float:
-        """How much faster than the single-threaded probe measurement the
-        host engine effectively runs under this pipeline: spill-compute
-        workers sort chunks side by side (up to the core count), while
-        the device engine still serializes on the one device — the
-        election must compare like with like."""
-        return float(self.pipeline.host_width())
-
-    def _link_rules_out_device(self, sample: ColumnarBatch) -> bool:
-        """True when a timed, compile-free device round trip of the
-        device path's unavoidable transfer floor — KEY columns H2D plus
-        a 4-byte-per-row permutation D2H (value columns never transit;
-        ops.build returns the sort permutation) — already exceeds the
-        measured host sort time: the device engine cannot win, whatever
-        its kernel speed."""
-        host_s = self._probe.get("host_s")
-        if host_s is None:
-            return False
-        # the process's FIRST device touch pays one-time backend init;
-        # it doubles as the untimed warmup (timing backend init as link
-        # bandwidth would rule out the device engine for good). On a TPU
-        # a failed touch raises (deviceprobe).
-        from ..utils.deviceprobe import first_device_touch_ok
-
-        if not first_device_touch_ok():
-            metrics.incr("build.engine.device_unreachable")
-            self._probe["unreachable"] = True
-            return True  # unreachable: the device engine cannot win
-        try:
-            import jax
-            # staged OUTSIDE the timed window: the real device path never
-            # uploads the permutation — only its D2H readback counts
-            perm_back = jax.device_put(
-                np.zeros(sample.num_rows, dtype=np.int32)
-            )
-            perm_back.block_until_ready()
-            t0 = time.perf_counter()
-            total = 0
-            for name in self.indexed_cols:
-                col = sample.columns[name]
-                arr = jax.device_put(col.data)
-                arr.block_until_ready()
-                total += col.data.nbytes
-            np.asarray(perm_back)  # hslint: disable=HS015 - link probe MEASURES this readback; the timed bytes are the point
-            total += sample.num_rows * 4
-            link_s = time.perf_counter() - t0
-        except Exception:  # noqa: BLE001 - probing must never fail a build
-            # a failed link probe routes host with no evidence why builds
-            # stopped using the device — count it
-            metrics.incr("build.engine.probe_link_error")
-            return False
-        metrics.record_time("build.engine.probe_link", link_s)
-        # compare against the host engine's EFFECTIVE per-chunk cost under
-        # this pipeline (pool-parallel host sorts), not the raw one-core
-        # probe time — the stricter bar the device must actually clear
-        return total > 0 and link_s > host_s / self._host_scale()
-
-    def _publish_winner(self, choice: str, by_link: bool = False) -> None:
-        """The ONE place the probe verdict is recorded: probe state, the
-        per-(platform, capacity) memo, and the observability counters.
-        An UNREACHABLE-device verdict latches in-process only — it is a
-        condition of this process, not a measured link property, and
-        persisting it would rule the device engine out machine-wide for
-        the probe cache's 24h TTL."""
-        self._probe["winner"] = 1.0 if choice == "host" else 0.0
-        key = self._cache_key()
-        bounded_memo_put(_ENGINE_CACHE, key, choice, _ENGINE_CACHE_MAX)
-        if not self._probe.get("unreachable"):
-            _persist_winner(key, choice)
-        metrics.incr(f"build.engine.auto_chose_{choice}")
-        if by_link:
-            metrics.incr("build.engine.auto_chose_host_by_link")
-
-    def _decide_winner(self) -> str:
-        """Pick (and memoize) the probed winner; also called from
-        finalize() so a 3-chunk build publishes its measurement for the
-        next build in this process."""
-        if "winner" not in self._probe:
-            dev = self._probe.get("device_s")
-            host = self._probe.get("host_s")
-            host_eff = None if host is None else host / self._host_scale()
-            self._publish_winner(
-                "host"
-                if host_eff is not None and (dev is None or host_eff < dev)
-                else "device"
-            )
-        return "host" if self._probe["winner"] else "device"
-
     def _try_stage_chunk(self, batch: ColumnarBatch) -> bool:
         """Route one chunk into the device run stager if eligible.
         Ineligible chunks FLUSH any pending run first — runs must never
@@ -783,10 +482,7 @@ class StreamingIndexWriter:
         the on-disk run order — then return False for the per-chunk
         path. Every decline is counted (the host tail is never silent,
         the compile/agg decline discipline applied here)."""
-        if self.device.run_chunks < 2:
-            metrics.incr("build.device.staging_declined.disabled")
-            return False
-        if self.device.run_chunks * self.chunk_capacity > (1 << 31) - 1:
+        if RUN_CHUNKS * self.chunk_capacity > (1 << 31) - 1:
             # the merged order ships as int32 (4 B/row, matching the
             # per-chunk permutation): runs beyond 2^31 rows cannot
             metrics.incr("build.device.staging_declined.width")
@@ -797,14 +493,6 @@ class StreamingIndexWriter:
             # flushing first preserves run order
             metrics.incr("build.device.staging_declined.tail")
             self._flush_staged()
-            return False
-        if (
-            self._engine != "device"
-            and _ENGINE_CACHE.get(self._cache_key()) != "device"
-        ):
-            # auto mode mid-probe: chunk 1's pre-verdict device dispatch
-            # must stay the per-chunk compile bearer the probe times
-            metrics.incr("build.device.staging_declined.probe")
             return False
         dtypes = batch.schema()
         if any(is_string(dtypes[k]) for k in self.indexed_cols):
@@ -839,7 +527,7 @@ class StreamingIndexWriter:
             self._flush_staged()
             return False
         if self._stager is None:
-            self._stager = _DeviceRunStager(self, self.device)
+            self._stager = _DeviceRunStager(self)
         if not self._stager.ensure_reserved(encoded):
             metrics.incr("build.device.staging_declined.budget")
             self._flush_staged()
@@ -1043,25 +731,14 @@ class StreamingIndexWriter:
                 counts = np.bincount(bucket_ids, minlength=self.num_buckets)
                 self._spill_run_at(self._next_seq(), dev_batch, counts)
         else:
-            engine = self._route_engine(batch.num_rows)
-            if engine in ("device", "probe-device") and not first_device_touch_ok(
-                required=self._engine == "device"
-            ):
-                # a device verdict from the in-process memo or a persisted
-                # winner, on a host whose device failed its first touch
-                # (an explicit build.engine=device raised above): route
-                # this process host-side (in-process latch only: the disk
-                # verdict stays for the next process)
-                metrics.incr("build.engine.device_unreachable")
-                self._probe["unreachable"] = True
-                bounded_memo_put(
-                    _ENGINE_CACHE, self._cache_key(), "host", _ENGINE_CACHE_MAX
+            if self._chunk_engine is None:
+                self._chunk_engine = resolve_build_engine(
+                    self._engine, batch.num_rows, streaming=True
                 )
-                engine = "host"
-            if engine == "device" and self._try_stage_chunk(batch):
+            if self._chunk_engine == "device" and self._try_stage_chunk(batch):
                 # device-resident staging: the chunk's sorted composite
                 # stays in HBM awaiting its run merge — no per-chunk
-                # spill; the stager enqueues one spill per R chunks
+                # spill; the stager enqueues one spill per RUN_CHUNKS
                 metrics.incr("build.engine.device")
                 self._chunk_times.append(time.perf_counter() - t0)
                 metrics.record_time(
@@ -1072,31 +749,15 @@ class StreamingIndexWriter:
                 metrics.incr("build.stream.chunks")
                 metrics.incr("build.stream.rows", batch.num_rows)
                 return
-            if engine in ("host", "probe-host"):
+            if self._chunk_engine == "host":
                 from ..ops.build import build_partition_host
 
-                self._flush_staged()
                 metrics.incr("build.engine.host")
-                if engine == "probe-host":
-                    t1 = time.perf_counter()
-                    result = build_partition_host(
-                        batch, self.indexed_cols, self.num_buckets
-                    )
-                    self._probe["host_s"] = time.perf_counter() - t1
-                    metrics.record_time(
-                        "build.engine.probe_host", self._probe["host_s"]
-                    )
-                    if self._link_rules_out_device(result[0]):
-                        # D2H alone beats the whole host sort: decide now
-                        # and never pay the device compile
-                        self._publish_winner("host", by_link=True)
-                    finish = lambda r=result: r  # noqa: E731
-                else:
-                    # the host sort runs on the spill thread, overlapping
-                    # the prefetch thread's source decode
-                    finish = lambda b=batch: build_partition_host(  # noqa: E731
-                        b, self.indexed_cols, self.num_buckets
-                    )
+                # the host sort runs on the spill thread, overlapping
+                # the prefetch thread's source decode
+                finish = lambda b=batch: build_partition_host(  # noqa: E731
+                    b, self.indexed_cols, self.num_buckets
+                )
             else:
                 from ..ops.build import build_partition_single
 
@@ -1120,16 +781,6 @@ class StreamingIndexWriter:
                         return inner()
                     finally:
                         self._device_slots.release()
-                if engine == "probe-device":
-                    # synchronous D2H here on the main thread so the probe
-                    # time covers the full device round trip
-                    t1 = time.perf_counter()
-                    result = finish()
-                    self._probe["device_s"] = time.perf_counter() - t1
-                    metrics.record_time(
-                        "build.engine.probe_device", self._probe["device_s"]
-                    )
-                    finish = lambda r=result: r  # noqa: E731
             self._chunk_times.append(time.perf_counter() - t0)
             metrics.record_time("build.stream.dispatch", self._chunk_times[-1])
             self._enqueue_spill(finish)
@@ -1164,15 +815,6 @@ class StreamingIndexWriter:
             # pair's host buffers and the shared HBM budget reservation
             self._stager.drop()
             self._stager = None
-        if (
-            self._engine == "auto"
-            and "device_s" in self._probe
-            and "host_s" in self._probe
-        ):
-            # short builds (3 chunks) complete both probes but never reach
-            # the deciding chunk — publish the measurement for the next
-            # build in this process
-            self._decide_winner()
         if self._t_first_add is not None:
             self._t_pipeline_done = time.perf_counter()
             # the denominator of every stage's occupancy: busy-time sums
@@ -1276,9 +918,9 @@ class StreamingIndexWriter:
             metrics.record_time("build.stream.merge_write", write_s)
             shutil.rmtree(self._spill_dir, ignore_errors=True)
         metrics.record_time("build.stream.finalize", time.perf_counter() - t0)
-        # publish the compile/steady split (bench.py reports rows/s from
-        # these; round-1 verdict weak #2 asked for exactly this split);
-        # stats is the single source of the split definition
+        # publish the compile/steady split (round-1 verdict weak #2 asked
+        # for exactly this split); stats is the single source of the
+        # split definition
         st = self.stats
         if "first_chunk_s" in st:
             metrics.record_time("build.stream.first_chunk", st["first_chunk_s"])
@@ -1302,12 +944,8 @@ class StreamingIndexWriter:
             "chunk_capacity": float(self.chunk_capacity),
         }
         if self._chunk_times:
-            # the SETUP bearer is whichever early chunk paid the one-off
-            # costs: in auto mode the XLA compile lands on the chunk-1
-            # dispatch and the probes on chunks 0/2, so the bearer is the
-            # max over the probe window rather than literally chunk 0
-            probe_window = 3 if self._engine == "auto" else 1
-            bearer = max(self._chunk_times[:probe_window])
+            # chunk 0 bears the one-off costs (the XLA compile)
+            bearer = self._chunk_times[0]
             out["first_chunk_s"] = bearer
             if (
                 len(self._chunk_times) > 1
@@ -1392,7 +1030,6 @@ def write_index_data_streaming(
     finalize_mode: str = "merge",
     chunk_tasks: Optional[Iterable] = None,
     pipeline: Optional[BuildPipelineConfig] = None,
-    device: Optional[DeviceBuildConfig] = None,
 ) -> List[Path]:
     """Drive a StreamingIndexWriter over source chunks. A failure
     anywhere tears the pipeline down (no parked workers, no orphan spill
@@ -1423,7 +1060,6 @@ def write_index_data_streaming(
         engine=engine,
         finalize_mode=finalize_mode,
         pipeline=pipe,
-        device=device,
     )
     if chunks is None and chunk_tasks is None:
         raise HyperspaceException(

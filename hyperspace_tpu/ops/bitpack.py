@@ -4,8 +4,7 @@ A resident predicate column costs one full int32 lane per row today even
 when its value domain needs far fewer bits — a 7-value shipmode column
 is 3 bits of information in a 32-bit slot. At SF100 that waste is the
 difference between a table fitting the HBM budget and the engine falling
-off the device fast path entirely (BENCH_SCALE_SF100; ROADMAP
-"Residency beyond HBM"). This module supplies the two compounding codecs
+off the device fast path entirely (ROADMAP R5). This module supplies the two compounding codecs
 of the residency tier ladder (docs/15-streaming-residency.md):
 
 * **plain pack** — values re-based to their minimum (frame of reference)

@@ -164,7 +164,7 @@ def _sort_by_bucket_and_keys(
 # by function object, so a closure defined inside build_partition_single
 # would RETRACE on every call — the persistent compile cache saves the
 # XLA compile but the per-call retrace (~100ms+) was still charged to
-# every streamed chunk and every device microbench repeat. Array shapes
+# every streamed chunk. Array shapes
 # vary freely under one cached closure (jit's own shape cache).
 _single_kernel_cache: dict = {}
 
@@ -345,7 +345,7 @@ def build_partition_single(
     arrays = {k: jnp.asarray(b) for k, b in host_bufs.items()}
     if defer:
         # streaming-writer dispatch: account the link both ways so the
-        # staged path's R-fold D2H reduction is measurable (bench 18)
+        # staged path's R-fold D2H reduction is measurable
         metrics.incr(
             "build.stream.h2d_bytes",
             sum(int(b.nbytes) for b in host_bufs.values()),
@@ -393,7 +393,7 @@ def build_partition_single(
         perm = np.asarray(perm_dev)[:n].astype(np.int64, copy=False)
         if defer:
             # one blocking device round trip per chunk — the call count
-            # the staged run merge divides by runChunks
+            # the staged run merge divides by RUN_CHUNKS
             metrics.incr("build.stream.d2h_calls")
             metrics.incr(
                 "build.stream.d2h_bytes", 4 * n_pad + 8 * num_buckets
@@ -424,7 +424,8 @@ def _single_staged_kernel_packed(
     COMPOSITE stays on device alongside the permutation — the merge
     operand of the on-device run merge (_staged_merge_fn). Nothing is
     fetched here; the only D2H the staged path ever pays is the merged
-    run's permutation, one call per ``runChunks`` chunks. Staged chunks
+    run's permutation, one call per ``RUN_CHUNKS`` chunks
+    (index/stream_builder.py). Staged chunks
     are always full-capacity (the tail routes per-chunk), so there is no
     n_valid operand: every row is real."""
     cache_key = ("perm-packed-staged", dtypes_key, key_names, num_buckets, narrow)
@@ -561,12 +562,10 @@ def stage_chunk_packed(
 ) -> Tuple[StagedChunk, int]:
     """Dispatch one full-capacity chunk through the staged kernel and
     leave its sorted composite + permutation resident on device.
-    ``host_bufs`` are the chunk's transport buffers — the writer's slab
-    pair slot under doubleBuffer (pre-staged, pinnable, reused every
-    other chunk) or the chunk's own encoded buffers (the
-    doubleBuffer=off A/B leg). Returns the staged handle and the H2D
-    byte count. Caller guarantees: no string key columns, full-capacity
-    chunk, ``plan`` fits 63 bits."""
+    ``host_bufs`` are the chunk's transport buffers — a slot of the
+    writer's slab pair (pre-staged, pinnable, reused every other chunk).
+    Returns the staged handle and the H2D byte count. Caller guarantees:
+    no string key columns, full-capacity chunk, ``plan`` fits 63 bits."""
     h2d_bytes = 0
     arrays = {}
     for k in key_names:

@@ -4,8 +4,7 @@ The streaming build (index/stream_builder.py) is a staged pipeline —
 ingest decode → device/host partition+sort → spill compute (D2H + decode)
 → spill write → per-bucket merge. Before this module each stage was at
 most ONE thread (a single spill worker behind a depth-1 queue), so at
-SF100 the build serialized on one host core (BENCH_SCALE_SF100:
-phase_spill_compute_s 270s of a 348s build). These are the shared
+SF100 the build serialized on one host core. These are the shared
 primitives every stage now runs on:
 
 * :class:`FirstError` — a cross-stage failure latch: the FIRST exception

@@ -13,7 +13,7 @@ Four pieces, layered over the FileSystem seam and the Action protocol:
 * ``faults``   — deterministic fault injection for the chaos harness;
 * ``chaos``    — the same replayable-schedule discipline one tier up:
                  scheduled host crash / stall / flap / slow faults at
-                 the serve boundary (bench config 20's FaultPlan).
+                 the serve boundary (a FaultPlan).
 
 See docs/12-reliability.md for the protocol walk-through.
 """

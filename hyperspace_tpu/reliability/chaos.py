@@ -6,8 +6,8 @@ applies the same replayable-schedule discipline one tier up, to whole
 hosts behind the query router. A ``FaultPlan`` is an explicit list of
 ``HostFault`` rules evaluated against each host's own submission
 counter — run the same plan against the same query sequence and the
-same submissions are hit, which is what lets bench config 20 hard-gate
-"zero failed tickets under chaos" instead of eyeballing flaky runs.
+same submissions are hit, which is what lets a test hard-gate "zero
+failed tickets under chaos" instead of eyeballing flaky runs.
 
 * ``crash`` — at the host's N-th submission, close the underlying
   server for good: every in-flight leg fails with ``ServerClosed``, the

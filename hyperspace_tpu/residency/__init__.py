@@ -4,8 +4,7 @@ than the HBM budget on the device fast path.
 PR 5's join regions and the base resident caches share one failure mode
 at scale: once a table's raw int32 planes exceed the HBM budget, the
 caches refuse it outright and every query pays the host path — the
-admit/deny cliff BENCH_SCALE_SF100 measured (join speedups collapsing to
-~1.1-1.3x at 600 M rows). PystachIO and Theseus (PAPERS.md) both reach
+admit/deny cliff. PystachIO and Theseus (PAPERS.md) both reach
 the same conclusion: storage->device movement must be a first-class
 pipeline, not a boolean. This package supplies the ladder
 

@@ -2,7 +2,7 @@
 
 The device-resident streaming build (docs/14-build-pipeline.md) pins
 device memory OUTSIDE the residency caches: the double-buffered upload
-slab pair and up to ``runChunks`` staged sorted chunks awaiting their
+slab pair and up to ``RUN_CHUNKS`` staged sorted chunks awaiting their
 on-device run merge. Those bytes come out of the SAME physical HBM the
 tier ladder budgets, so they must share the one budget instead of
 silently oversubscribing it: a build that stages 3 GB of runs while the

@@ -277,7 +277,7 @@ class QueryServer:
         self._waits: "deque[float]" = deque(maxlen=4096)
         self._ewma_service_s = 0.01
         # scheduler-turn log: which tenant each dispatch slot went to —
-        # the fairness evidence stats()/bench config 15 score
+        # the fairness evidence stats() scores
         self._dispatch_order: "deque[str]" = deque(maxlen=4096)
         self._workers_killed = 0
         self._recovery_sweeps = 0

@@ -273,7 +273,7 @@ def pick_tenant_locked(
     highest-credit tenant is picked and pays the eligible total. Over N
     picks with stable eligibility each tenant is picked ~N*w/W times
     with bounded burstiness (never more than one extra turn ahead of
-    its entitlement) — the fairness bound bench config 15 scores."""
+    its entitlement) — the fairness bound stats() scores."""
     eligible: List[TenantState] = []
     for t in tenants.values():
         if not t.queue:

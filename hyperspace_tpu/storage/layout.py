@@ -429,8 +429,7 @@ def read_batches(
 # ONE ordered sequential sweep per file through the shared TcbReader
 # handles — fanned across the host worker pool. ``io.segment.*`` counters
 # and per-sweep trace spans make the plan observable; the ``naive`` mode
-# (one read per segment — the pre-planner behavior) is the A/B lever
-# bench config 17 pulls.
+# (one read per segment — the pre-planner behavior) is the A/B lever.
 
 # merge ranges whose gap is at most this many rows: reading a small gap
 # through is cheaper than a second seek/ranged request, and the slice
@@ -540,7 +539,7 @@ def execute_segment_reads(
     fanned across the host worker pool, returning the per-(path, bucket)
     column batches. ``coalesce=False`` (or segment IO mode ``naive``)
     issues one ranged read per segment instead — the pre-planner
-    behavior the config-17 A/B measures against."""
+    behavior the A/B lever measures against."""
     if not sweeps:
         return {}
     if coalesce is None:

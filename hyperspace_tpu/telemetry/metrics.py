@@ -14,7 +14,8 @@ Usage::
     metrics.incr("join.path.pallas")
 
 Counters and timers accumulate in a process-global registry; ``snapshot()``
-returns a plain dict (surfaced by bench.py and explain(verbose)).
+returns a plain dict (surfaced by the benchmark harness and
+explain(verbose)).
 
 Concurrent serving adds a second axis: with many queries in flight the
 global pool alone cannot say which query paid which cost. ``scoped()``
@@ -254,8 +255,7 @@ def build_pipeline_snapshot(
     registry: Optional[MetricsRegistry] = None,
 ) -> Dict[str, object]:
     """Per-stage occupancy of the pipelined index build in one dict
-    (docs/14-build-pipeline.md; consumed by bench config 13 and
-    scripts/bench_scale.py). ``*_busy_s`` sums WORKER time per stage, so
+    (docs/14-build-pipeline.md). ``*_busy_s`` sums WORKER time per stage, so
     with the pipeline on, busy sums legitimately exceed ``wall_s`` —
     that excess IS the overlap (serial mode: they add up to ≤ wall).
     ``*_occupancy`` divides by wall: the stage nearest its worker count
@@ -332,8 +332,8 @@ def compile_snapshot(
     lowering ran vs the pipeline cache served (a repeated-structure burst
     keeps ``pipelines_lowered`` flat while ``cache_hits`` climbs), which
     kinds lowered, what the fused arms dispatched, and what degradation
-    dropped. Consumed by ``QueryServer.stats()["compile"]`` and bench
-    config 16 (docs/17-plan-compilation.md)."""
+    dropped. Consumed by ``QueryServer.stats()["compile"]``
+    (docs/17-plan-compilation.md)."""
     r = registry if registry is not None else metrics
     out: Dict[str, object] = {
         "pipelines_lowered": r.counter("compile.lowered"),
@@ -375,7 +375,7 @@ def result_cache_snapshot(
     or declined (cold structure vs byte economics), what GDSF/budget
     pressure evicted, hits/misses, and misses that were only stale by
     version token. Consumed by ``QueryServer.stats()["result_cache"]``
-    and bench config 21 (docs/17-plan-compilation.md)."""
+    (docs/17-plan-compilation.md)."""
     r = registry if registry is not None else metrics
     out: Dict[str, object] = {}
     for level, prefix in (
@@ -400,8 +400,8 @@ def serve_snapshot(registry: Optional[MetricsRegistry] = None) -> Dict[str, int]
     """The serve-tier counter family in one dict — what admission let
     in, shed, or breaker-rejected, what the overload ladder disabled,
     and what the degradation paths absorbed (worker kills, host
-    latches). Consumed by ``QueryServer.stats()["serve_counters"]`` and
-    the multitenant bench config (docs/16-multitenant-serving.md)."""
+    latches). Consumed by ``QueryServer.stats()["serve_counters"]``
+    (docs/16-multitenant-serving.md)."""
     r = registry if registry is not None else metrics
     return {
         "submitted": r.counter("serve.submitted"),

@@ -16,9 +16,8 @@ queries' traces therefore never interleave (the PR-10 scoped-registry
 attribution bug class, closed by construction).
 
 Cost model: with no active trace, ``span()``/``annotate()`` are one
-contextvar read — the <3% serve-burst overhead gate in bench.py config
-10 holds because untraced *and* traced paths stay allocation-light (a
-span is one slotted object and two clock reads). Tracing is on by
+contextvar read, and traced paths stay allocation-light too (a span is
+one slotted object and two clock reads). Tracing is on by
 default (``hyperspace.telemetry.tracing=off`` disables trace creation
 at the query entry points; the library span sites then no-op).
 
@@ -207,7 +206,7 @@ def active() -> Optional[Span]:
 class _Activation:
     """Class-based context manager for QueryTrace.activate() — span
     sites sit on the serve hot path, so the machinery avoids the
-    generator-contextmanager overhead (the <3% bench gate's budget)."""
+    generator-contextmanager overhead."""
 
     __slots__ = ("_trace", "_token")
 

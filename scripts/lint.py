@@ -3,7 +3,7 @@
 
 Usage:
     python scripts/lint.py                      # tier-1 targets, both phases
-    python scripts/lint.py hyperspace_tpu scripts bench.py
+    python scripts/lint.py hyperspace_tpu scripts
     python scripts/lint.py --format json hyperspace_tpu
     python scripts/lint.py --no-project somefile.py   # per-file rules only
     python scripts/lint.py --changed HEAD~1     # full model, report changed
@@ -50,7 +50,7 @@ from hyperspace_tpu.analysis.rules import REGISTRY  # noqa: E402
 
 # the tier-1 surface: what a bare ``python scripts/lint.py`` lints and
 # what tests/test_lint.py holds at zero unsuppressed findings
-DEFAULT_TARGETS = ("hyperspace_tpu", "scripts", "bench.py")
+DEFAULT_TARGETS = ("hyperspace_tpu", "scripts")
 
 
 def main(argv=None) -> int:

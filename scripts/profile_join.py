@@ -30,7 +30,7 @@ from pathlib import Path
 REPO = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(REPO))
 
-# host-side artifact: pin CPU at the config level (bench_scale rationale)
+# host-side artifact: pin CPU at the config level
 os.environ["JAX_PLATFORMS"] = "cpu"
 import jax  # noqa: E402
 

@@ -18,10 +18,6 @@ if "xla_force_host_platform_device_count" not in flags:
         flags + " --xla_force_host_platform_device_count=8"
     ).strip()
 
-# Hermetic probe tests: the engine-probe verdict must come from THIS
-# process's measurements, never a previous run's disk memo.
-os.environ["HYPERSPACE_TPU_PROBE_CACHE"] = ""
-
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")

@@ -6,8 +6,7 @@ each stage mid-build.
 The invariant every parity test here enforces: pipelining must never
 change ONE BYTE of the built index — chunk order is preserved through
 ordered ingest, runs carry sequence numbers, and every merge is stable by
-run order, so serial and pipelined builds are interchangeable (bench
-config 13 gates on exactly this).
+run order, so serial and pipelined builds are interchangeable.
 """
 
 import threading
@@ -425,54 +424,6 @@ def test_abort_idempotent_and_reusable_writer(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# probe-cache key: host parallelism folds into the persisted winner
-# ---------------------------------------------------------------------------
-def test_probe_cache_key_includes_host_width(tmp_path, monkeypatch):
-    from hyperspace_tpu.index import stream_builder as sb
-
-    cache = tmp_path / "probe" / "engine_probe.json"
-    monkeypatch.setenv("HYPERSPACE_TPU_PROBE_CACHE", str(cache))
-    monkeypatch.setattr(sb.os, "cpu_count", lambda: 16)
-    sb._ENGINE_CACHE.clear()
-    try:
-        key_w1 = sb._engine_cache_key(512, host_width=1)
-        sb._persist_winner(key_w1, "host")
-        # a width-1 writer (serial pipeline) honors the verdict…
-        w1 = sb.StreamingIndexWriter(
-            ["orderkey"], 4, tmp_path / "a", chunk_capacity=512,
-            engine="auto", pipeline=BuildPipelineConfig.serial(),
-        )
-        assert w1._route_engine(512) == "host"
-        # …while a 16-wide pipeline must NOT inherit it: different key,
-        # fresh probe (chunk 0 of auto mode = the host probe)
-        sb._ENGINE_CACHE.clear()
-        w16 = sb.StreamingIndexWriter(
-            ["orderkey"], 4, tmp_path / "b", chunk_capacity=512,
-            engine="auto",
-            pipeline=pipelined(spill_compute_workers=16),
-        )
-        assert w16.pipeline.host_width() == 16
-        assert w16._route_engine(512) == "probe-host"
-        # and the two verdicts persist side by side
-        sb._persist_winner(w16._cache_key(), "device")
-        assert sb._load_persisted_winner(key_w1) == "host"
-        assert sb._load_persisted_winner(w16._cache_key()) == "device"
-    finally:
-        sb._ENGINE_CACHE.clear()
-
-
-def test_default_cache_key_matches_default_pipeline():
-    from hyperspace_tpu.index import stream_builder as sb
-
-    assert (
-        sb._engine_cache_key(1024)
-        == sb._engine_cache_key(
-            1024, host_width=BuildPipelineConfig.default().host_width()
-        )
-    )
-
-
-# ---------------------------------------------------------------------------
 # k-way merge: parity + asymptotics (no full re-sort on sorted runs)
 # ---------------------------------------------------------------------------
 def _sorted_runs(rng, n_runs, rows, key_low, key_high):
@@ -742,19 +693,17 @@ def test_device_inflight_chunks_bounded(tmp_path, monkeypatch):
 
     monkeypatch.setattr(ops_build, "build_partition_single", wrapped)
     b = sample(8192, seed=71)
-    from hyperspace_tpu.index.stream_builder import DeviceBuildConfig
-
     write_index_data_streaming(
         chunks_of(b, 512),
-        ["orderkey"],
+        # a string key declines run staging, so every chunk takes THIS
+        # per-chunk dispatch path, the one the bound protects (the staged
+        # path holds slots per run merge, tested separately)
+        ["flag", "orderkey"],
         4,
         tmp_path / "o",
         chunk_capacity=512,
         engine="device",
         pipeline=pipelined(spill_compute_workers=8, spill_write_workers=2),
-        # per-chunk mode: THIS dispatch path is what the bound protects
-        # (the staged path holds slots per run merge, tested separately)
-        device=DeviceBuildConfig.per_chunk(),
     )
     assert inflight["peak"] <= sb.DEVICE_INFLIGHT_CHUNKS
     assert inflight["peak"] >= 2  # the pipeline did run ahead of the fetch
@@ -783,7 +732,7 @@ def test_worker_gauges_do_not_accumulate_across_builds(tmp_path):
 def test_create_action_pipeline_off_matches_on(tmp_path):
     """End-to-end through the session/create path: pipeline=off and the
     default pipelined build produce identical index bytes and identical
-    query results (the bench-13 gate as a unit test)."""
+    query results."""
     from hyperspace_tpu.hyperspace import Hyperspace
     from hyperspace_tpu.index.index_config import IndexConfig
     from hyperspace_tpu.plan.expr import col
@@ -837,13 +786,13 @@ def test_create_action_pipeline_off_matches_on(tmp_path):
 
 # ---------------------------------------------------------------------------
 # device-resident run staging (docs/14-build-pipeline.md, device build):
-# double-buffered H2D slab pair + on-device k-way run merge. The parity
-# invariant extends config 13's: the staged path must not change ONE BYTE
-# of the built index vs the per-chunk round trip (runChunks=1), because
-# runs reserve their first chunk's sequence slot and the device merge is
-# stable by chunk order exactly like the host merge is by run order.
+# double-buffered H2D slab pair + on-device k-way run merge of RUN_CHUNKS
+# chunks. The parity invariant: the staged path must not change ONE BYTE
+# of the built index vs the host engine, because runs reserve their first
+# chunk's sequence slot and the device merge is stable by chunk order
+# exactly like the host merge is by run order.
 # ---------------------------------------------------------------------------
-from hyperspace_tpu.index.stream_builder import DeviceBuildConfig  # noqa: E402
+from hyperspace_tpu.index.stream_builder import RUN_CHUNKS  # noqa: E402
 from hyperspace_tpu.residency import slabs as slab_budget  # noqa: E402
 
 
@@ -866,19 +815,18 @@ def _bucket_bytes(out_dir):
     }
 
 
-def _staged_build(tmp_path, tag, device, keys=("orderkey", "qty"),
-                  batch=None, pipeline=None, chunk=512):
+def _staged_build(tmp_path, tag, keys=("orderkey", "qty"), batch=None,
+                  pipeline=None, chunk=512, engine="device"):
     out = tmp_path / tag
     write_index_data_streaming(
-        chunks_of(batch if batch is not None else _int_sample(2048 + 100), 
+        chunks_of(batch if batch is not None else _int_sample(2048 + 100),
                   chunk),
         list(keys),
         8,
         out,
         chunk_capacity=chunk,
-        engine="device",
+        engine=engine,
         pipeline=pipeline or BuildPipelineConfig.serial(),
-        device=device,
     )
     return _bucket_bytes(out)
 
@@ -943,29 +891,6 @@ def test_device_run_merge_matches_host_merge(r, width):
     )
 
 
-def test_probe_cache_key_includes_device_mode():
-    """The host_width lesson applied to the device engine: a per-chunk
-    round-trip verdict must not bind a double-buffered staged run —
-    the modes get separate probe-cache slots (and the default key is
-    the default mode's)."""
-    from hyperspace_tpu.index import stream_builder as sb
-
-    per_chunk = sb._engine_cache_key(
-        512, device_mode=DeviceBuildConfig.per_chunk().mode_token()
-    )
-    staged = sb._engine_cache_key(
-        512, device_mode=DeviceBuildConfig(True, 4).mode_token()
-    )
-    assert per_chunk != staged
-    assert sb._engine_cache_key(512) == sb._engine_cache_key(
-        512, device_mode=DeviceBuildConfig.default().mode_token()
-    )
-    # and a writer's key carries its own mode
-    assert DeviceBuildConfig.per_chunk().mode_token() in map(
-        str, per_chunk
-    )
-
-
 def _tied_sample(n, seed):
     """Few distinct orderkeys, each on many rows: the ties of lineitem's
     l_orderkey, many times over."""
@@ -988,56 +913,74 @@ def _tied_sample(n, seed):
 def test_staged_device_build_matches_per_chunk_bytes(
     tmp_path, full_chunks, keys, make
 ):
-    """Byte parity per-chunk vs staged (serial AND pipelined), with a
-    partial tail chunk in the stream; the staged side must also pay
-    runChunks-fold fewer blocking D2H calls."""
+    """Byte parity host engine vs staged device build (serial AND
+    pipelined), with a partial tail chunk in the stream; the staged side
+    pays one blocking D2H call per run, not one per chunk."""
     from hyperspace_tpu.telemetry.metrics import metrics
 
     b = make(full_chunks * 512 + 100, seed=29)
-    metrics.reset()
-    a_bytes = _staged_build(
-        tmp_path, "per_chunk", DeviceBuildConfig.per_chunk(), keys=keys,
-        batch=b,
+    h_bytes = _staged_build(
+        tmp_path, "host", keys=keys, batch=b, engine="host"
     )
-    a_calls = metrics.counter("build.stream.d2h_calls")
     metrics.reset()
-    s_bytes = _staged_build(
-        tmp_path, "staged", DeviceBuildConfig(True, 4), keys=keys, batch=b
-    )
+    s_bytes = _staged_build(tmp_path, "staged", keys=keys, batch=b)
     s_calls = metrics.counter("build.stream.d2h_calls")
     assert metrics.counter("build.device.staged_chunks") == full_chunks
     assert metrics.counter("build.device.staged_runs") == 1
-    assert a_bytes == s_bytes
-    # per-chunk pays one blocking fetch a full chunk + 1 tail; the
-    # staged run pays ONE (+ the tail's per-chunk fetch)
-    assert a_calls == full_chunks + 1 and s_calls == 2
+    assert h_bytes == s_bytes
+    # the staged run pays ONE blocking fetch for its full chunks (+ the
+    # tail's per-chunk fetch)
+    assert s_calls == 2
     p_bytes = _staged_build(
-        tmp_path, "staged_pipe", DeviceBuildConfig(True, 4), keys=keys,
-        batch=b, pipeline=pipelined(),
+        tmp_path, "staged_pipe", keys=keys, batch=b, pipeline=pipelined(),
     )
-    assert p_bytes == a_bytes
+    assert p_bytes == h_bytes
     assert slab_budget.held_bytes() == 0
 
 
 def test_string_key_declines_staging_with_parity(tmp_path):
     """Per-chunk vocab codes are not comparable across chunks, so a
     string KEY routes every chunk per-chunk (counted decline) — and the
-    result is still byte-identical to runChunks=1."""
+    result is still byte-identical to the host engine's."""
     from hyperspace_tpu.telemetry.metrics import metrics
 
     b = sample(2048, seed=31)  # has the "flag" string column
     metrics.reset()
     s_bytes = _staged_build(
-        tmp_path, "str_staged", DeviceBuildConfig(True, 4),
-        keys=("orderkey", "flag"), batch=b,
+        tmp_path, "str_staged", keys=("orderkey", "flag"), batch=b,
     )
     assert metrics.counter("build.device.staging_declined.string_key") > 0
     assert metrics.counter("build.device.staged_chunks") == 0
-    a_bytes = _staged_build(
-        tmp_path, "str_per_chunk", DeviceBuildConfig.per_chunk(),
-        keys=("orderkey", "flag"), batch=b,
+    h_bytes = _staged_build(
+        tmp_path, "str_host", keys=("orderkey", "flag"), batch=b,
+        engine="host",
     )
-    assert s_bytes == a_bytes
+    assert s_bytes == h_bytes
+
+
+def test_staged_runs_group_run_chunks_then_tail(tmp_path):
+    """Nine staged full chunks and a partial tail spill as runs of 4, 4
+    and 1 chunks (the tail flushes the pending one), then the tail's own
+    per-chunk run."""
+    from hyperspace_tpu.telemetry.metrics import metrics
+
+    assert RUN_CHUNKS == 4
+    cap = 512
+    b = _int_sample(9 * cap + 100, seed=53)
+    metrics.reset()
+    files = write_index_data_streaming(
+        chunks_of(b, cap), ["orderkey"], 8, tmp_path / "o",
+        chunk_capacity=cap, engine="device",
+        pipeline=BuildPipelineConfig.serial(), finalize_mode="runs",
+    )
+    assert [layout.read_batch(p).num_rows for p in files] == [
+        4 * cap, 4 * cap, cap, 100,
+    ]
+    assert metrics.counter("build.device.staged_chunks") == 9
+    assert metrics.counter("build.device.staged_runs") == 3
+    assert metrics.counter("build.device.staging_declined.tail") == 1
+    assert metrics.counter("build.stream.d2h_calls") == 4
+    assert slab_budget.held_bytes() == 0
 
 
 def test_budget_decline_routes_per_chunk(tmp_path, monkeypatch):
@@ -1048,9 +991,7 @@ def test_budget_decline_routes_per_chunk(tmp_path, monkeypatch):
     monkeypatch.setenv("HYPERSPACE_TPU_HBM_BUDGET_MB", "0")
     b = _int_sample(4 * 512, seed=37)
     metrics.reset()
-    out = _staged_build(
-        tmp_path, "nobudget", DeviceBuildConfig(True, 4), batch=b
-    )
+    out = _staged_build(tmp_path, "nobudget", batch=b)
     assert metrics.counter("build.device.staging_declined.budget") > 0
     assert metrics.counter("build.device.staged_runs") == 0
     assert metrics.counter("build.stream.d2h_calls") == 4
@@ -1097,8 +1038,7 @@ def test_device_loss_mid_slab_upload_clean_teardown(tmp_path, monkeypatch):
     monkeypatch.setattr(ops_build, "stage_chunk_packed", dying)
     with pytest.raises(RuntimeError, match="mid slab upload"):
         _staged_build(
-            tmp_path, "loss_upload", DeviceBuildConfig(True, 4),
-            batch=_int_sample(4 * 512, seed=41),
+            tmp_path, "loss_upload", batch=_int_sample(4 * 512, seed=41)
         )
     assert _no_pool_threads()
     assert not (tmp_path / "loss_upload" / ".spill").exists()
@@ -1117,9 +1057,7 @@ def test_device_loss_mid_device_merge_clean_teardown_and_host_parity(
 
     monkeypatch.setattr(ops_build, "merge_staged_chunks", dying)
     with pytest.raises(RuntimeError, match="mid run merge"):
-        _staged_build(
-            tmp_path, "loss_merge", DeviceBuildConfig(True, 2), batch=b
-        )
+        _staged_build(tmp_path, "loss_merge", batch=b)
     assert _no_pool_threads()
     assert not (tmp_path / "loss_merge" / ".spill").exists()
     assert slab_budget.held_bytes() == 0
@@ -1132,9 +1070,7 @@ def test_device_loss_mid_device_merge_clean_teardown_and_host_parity(
         chunk_capacity=512, engine="host",
         pipeline=BuildPipelineConfig.serial(),
     )
-    dev_bytes = _staged_build(
-        tmp_path, "dev_ok", DeviceBuildConfig(True, 2), batch=b
-    )
+    dev_bytes = _staged_build(tmp_path, "dev_ok", batch=b)
     assert _bucket_bytes(host_out) == dev_bytes
 
 
@@ -1153,8 +1089,8 @@ def test_failure_with_async_d2h_in_flight_clean_teardown(
     monkeypatch.setattr(sb.layout, "write_batch", dying)
     with pytest.raises(OSError, match="in-flight D2H"):
         _staged_build(
-            tmp_path, "loss_d2h", DeviceBuildConfig(True, 2),
-            batch=_int_sample(6 * 512, seed=47), pipeline=pipelined(),
+            tmp_path, "loss_d2h", batch=_int_sample(6 * 512, seed=47),
+            pipeline=pipelined(),
         )
     assert _no_pool_threads()
     assert not (tmp_path / "loss_d2h" / ".spill").exists()

@@ -168,7 +168,7 @@ def test_agg_over_scan_pipeline_parity_and_single_dispatch(env):
     assert_row_parity(off, on)
     assert metrics.counter("compile.run.agg_scan") == 1
     # the WHOLE pipeline (filter scan + aggregate) shipped at most one
-    # D2H between arms — the acceptance bound bench config 16 gates
+    # D2H between arms — the acceptance bound
     assert qm.snapshot()["counters"].get("compile.fused.dispatches", 0) <= 1
 
 

@@ -235,8 +235,7 @@ print("SHOULD NOT REACH", flush=True)
 
 
 def test_sigkill_mid_spill_cancel_recovers_and_gcs_spill(tmp_path):
-    env = {**os.environ, "JAX_PLATFORMS": "cpu",
-           "HYPERSPACE_TPU_PROBE_CACHE": ""}
+    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
     p = subprocess.Popen(
         [sys.executable, "-c", _KILL_BUILD_WORKER, str(tmp_path), str(REPO)],
         stdout=subprocess.PIPE, stderr=subprocess.STDOUT, env=env,

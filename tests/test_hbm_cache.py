@@ -5,8 +5,8 @@ through index_scan, first-touch population, and budget eviction.
 Round-3 verdict missing #1: the scan re-uploaded index columns per query,
 so the device could never win end-to-end. These tests pin the resident
 protocol's CORRECTNESS on the CPU backend (force mode + the Pallas
-interpreter); the recorded win on the real chip is bench.py's resident
-config."""
+interpreter); the chip's numbers come from the benchmark (benchmark/,
+PERF.md)."""
 
 import time
 

@@ -1,7 +1,7 @@
 """Tier-1 enforcement: the tree stays hslint-clean.
 
 This is the teeth of the analyzer — every rule violation introduced
-anywhere in ``hyperspace_tpu/``, ``scripts/`` or ``bench.py`` fails this
+anywhere in ``hyperspace_tpu/`` or ``scripts/`` fails this
 test unless it carries a per-line ``# hslint: disable=HSxxx`` suppression
 with a justification. Fixture-level rule behavior is covered in
 ``test_analysis_rules.py``; this file only pins the zero-findings
@@ -14,7 +14,7 @@ import sys
 from pathlib import Path
 
 REPO = Path(__file__).resolve().parent.parent
-LINT_TARGETS = ["hyperspace_tpu", "scripts", "bench.py"]
+LINT_TARGETS = ["hyperspace_tpu", "scripts"]
 
 
 def test_tree_has_zero_unsuppressed_findings():

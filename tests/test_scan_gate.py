@@ -1,5 +1,5 @@
 """The scan's measured device gate (exec/scan_gate.py): probe state
-machine, link short-circuit, disk persistence, and the end-to-end routing
+machine, link short-circuit, and the end-to-end routing
 through index_scan. Round-2 verdict weak #2 asked for exactly this —
 a measured gate in place of the static MIN_DEVICE_ROWS constant."""
 
@@ -73,25 +73,6 @@ def test_no_device_available_decides_host(gate, monkeypatch):
     gate.wait_probe(n)
     assert gate.decide(n) == "host"
     assert gate.snapshot()[str(n)]["by"] == "no-device"
-
-
-def test_verdict_persists_to_disk_memo(tmp_path, monkeypatch):
-    cache = tmp_path / "probe.json"
-    monkeypatch.setenv("HYPERSPACE_TPU_PROBE_CACHE", str(cache))
-    g1 = ScanGate()
-    monkeypatch.setattr(g1, "_time_link", lambda a, r: 10.0)
-    n = PROBE_MIN_ROWS
-    g1.decide(n)
-    g1.record_host(n, host_s=0.001, arrays=_arrays())
-    g1.wait_probe(n)
-    assert g1.decide(n) == "host"
-    assert cache.exists()
-    # fresh gate (= fresh process): verdict from disk, no probe
-    g2 = ScanGate()
-    metrics.reset()
-    assert g2.decide(n) == "host"
-    assert g2.snapshot()[str(n)]["source"] == "disk"
-    assert metrics.counter("scan.gate.winner_from_disk_cache") == 1
 
 
 def test_index_scan_routes_through_gate(tmp_workspace, monkeypatch):

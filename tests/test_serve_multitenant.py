@@ -569,7 +569,7 @@ def test_stats_and_explain_name_tenant_and_pinned_version(env):
 # soak: concurrent ingest + refresh + mixed-tenant bursts + device loss
 # ---------------------------------------------------------------------------
 def test_soak_mixed_tenant_burst_with_refresh_and_device_loss(env, monkeypatch):
-    """The acceptance scenario (bench config 15's twin): 3 weighted
+    """The acceptance scenario: 3 weighted
     tenants burst through the server while a refresh lands mid-burst and
     the device dies once mid-batch. Invariants: every ticket RESOLVES;
     every completed result matches the pre- or post-refresh snapshot

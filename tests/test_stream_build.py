@@ -132,114 +132,11 @@ def test_host_engine_identical_to_device(tmp_path):
     assert bucket_contents(d1, "qty") == bucket_contents(d2, "qty")
 
 
-def test_auto_engine_probes_and_routes(tmp_path, monkeypatch):
-    from hyperspace_tpu.index import stream_builder as sb
-    from hyperspace_tpu.telemetry.metrics import metrics
-
-    b = sample(3000, seed=9)
-    metrics.reset()
-    sb._ENGINE_CACHE.clear()  # force a fresh probe (memoized per process)
-    # pin the full probe sequence: at test scale the link probe's fixed
-    # overhead can legitimately rule the device out before any compile
-    monkeypatch.setattr(
-        sb.StreamingIndexWriter, "_link_rules_out_device", lambda self, s: False
-    )
-    try:
-        write_index_data_streaming(
-            chunks_of(b, 500), ["orderkey"], 4, tmp_path / "o",
-            chunk_capacity=500, engine="auto",
-        )
-        snap = metrics.snapshot()
-        # both probes ran and a winner was chosen for the remaining chunks
-        assert "build.engine.probe_device" in snap["timers_s"]
-        assert "build.engine.probe_host" in snap["timers_s"]
-        assert (
-            snap["counters"].get("build.engine.auto_chose_host", 0)
-            + snap["counters"].get("build.engine.auto_chose_device", 0)
-        ) == 1
-        total = snap["counters"].get("build.engine.host", 0) + snap[
-            "counters"
-        ].get("build.engine.device", 0)
-        assert total == snap["counters"]["build.stream.chunks"]
-        # the winner is memoized PER (platform, capacity): a second auto
-        # build at the same capacity probes nothing ...
-        metrics.reset()
-        write_index_data_streaming(
-            chunks_of(b, 500), ["orderkey"], 4, tmp_path / "o2",
-            chunk_capacity=500, engine="auto",
-        )
-        snap2 = metrics.snapshot()
-        assert "build.engine.probe_device" not in snap2["timers_s"]
-        assert "build.engine.probe_host" not in snap2["timers_s"]
-        # ... while a different chunk capacity re-probes (the device/host
-        # ratio flips with chunk size, so the memo must not cross over)
-        metrics.reset()
-        write_index_data_streaming(
-            chunks_of(b, 250), ["orderkey"], 4, tmp_path / "o3",
-            chunk_capacity=250, engine="auto",
-        )
-        assert "build.engine.probe_host" in metrics.snapshot()["timers_s"]
-    finally:
-        sb._ENGINE_CACHE.clear()
-
-
-def test_partial_tail_chunk_never_memoizes(tmp_path):
-    """A build smaller than the chunk capacity probes nothing and writes
-    nothing to the per-capacity engine memo — a 100-row tail is an
-    unrepresentative sample that would poison every later build at that
-    capacity."""
-    from hyperspace_tpu.index import stream_builder as sb
-    from hyperspace_tpu.telemetry.metrics import metrics
-
-    sb._ENGINE_CACHE.clear()
-    metrics.reset()
-    b = sample(100, seed=30)
-    try:
-        write_index_data_streaming(
-            chunks_of(b, 100), ["orderkey"], 4, tmp_path / "o",
-            chunk_capacity=512, engine="auto",
-        )
-        snap = metrics.snapshot()
-        assert "build.engine.probe_host" not in snap["timers_s"]
-        assert "build.engine.probe_device" not in snap["timers_s"]
-        assert sb._ENGINE_CACHE == {}
-        # routed by the in-memory size policy (host below the threshold)
-        assert snap["counters"].get("build.engine.host") == 1
-    finally:
-        sb._ENGINE_CACHE.clear()
-
-
-def test_auto_engine_link_probe_short_circuit(tmp_path, monkeypatch):
-    """When the raw device round trip of a chunk already exceeds the host
-    sort, the device engine is ruled out BEFORE any XLA compile: no
-    device chunk runs, and the decision is memoized."""
-    from hyperspace_tpu.index import stream_builder as sb
-    from hyperspace_tpu.telemetry.metrics import metrics
-
-    b = sample(2500, seed=10)
-    metrics.reset()
-    sb._ENGINE_CACHE.clear()
-    monkeypatch.setattr(
-        sb.StreamingIndexWriter, "_link_rules_out_device", lambda self, s: True
-    )
-    try:
-        write_index_data_streaming(
-            chunks_of(b, 400), ["orderkey"], 4, tmp_path / "o",
-            chunk_capacity=400, engine="auto",
-        )
-        snap = metrics.snapshot()
-        assert "build.engine.probe_device" not in snap["timers_s"]
-        assert snap["counters"].get("build.engine.device", 0) == 0
-        assert snap["counters"].get("build.engine.auto_chose_host_by_link") == 1
-        assert sb._ENGINE_CACHE[sb._engine_cache_key(512)] == "host"
-    finally:
-        sb._ENGINE_CACHE.clear()
-
-
-def test_inmemory_engine_routing_and_parity(tmp_path):
+def test_inmemory_engine_routing_and_parity(tmp_path, monkeypatch):
     """The in-memory (single-launch) build routes small batches to the
     host twin by default — one kernel launch cannot amortize a fresh XLA
     compile — and both engines write byte-identical buckets."""
+    from hyperspace_tpu import ops
     from hyperspace_tpu.index import builder
     from hyperspace_tpu.telemetry.metrics import metrics
 
@@ -254,9 +151,111 @@ def test_inmemory_engine_routing_and_parity(tmp_path):
     )
     assert metrics.snapshot()["counters"].get("build.engine.device") == 1
     assert bucket_contents(auto) == bucket_contents(forced)
-    # above the threshold, one launch can amortize the compile → device
-    assert builder._route_inmemory_engine("auto", 1 << 23) == "device"
-    assert builder._route_inmemory_engine("host", 1 << 23) == "host"
+    # above the threshold on an accelerator, one launch can amortize the
+    # compile → device
+    monkeypatch.setattr(ops, "configured_platform", lambda: "tpu")
+    assert builder.resolve_build_engine("auto", 1 << 23, False) == "device"
+    assert builder.resolve_build_engine("host", 1 << 23, False) == "host"
+
+
+@pytest.mark.parametrize(
+    "platform, engine, n_rows, streaming, touch_ok, expected",
+    [
+        pytest.param("cpu", "auto", 512, True, True, "host",
+                     id="cpu-streaming"),
+        pytest.param("cpu", "auto", 1 << 23, False, True, "host",
+                     id="cpu-inmemory-large"),
+        pytest.param("tpu", "auto", 512, True, True, "device",
+                     id="accel-streaming-full-chunk"),
+        pytest.param("tpu", "auto", 100, True, True, "device",
+                     id="accel-streaming-tail"),
+        pytest.param("tpu", "auto", (1 << 22) - 1, False, True, "host",
+                     id="accel-inmemory-below-floor"),
+        pytest.param("tpu", "auto", 1 << 22, False, True, "device",
+                     id="accel-inmemory-at-floor"),
+        pytest.param("gpu", "auto", 512, True, False, "host",
+                     id="auto-device-unreachable"),
+        pytest.param("cpu", "device", 100, False, True, "device",
+                     id="pinned-device"),
+        pytest.param("tpu", "host", 1 << 23, True, True, "host",
+                     id="pinned-host"),
+    ],
+)
+def test_build_engine_rule(
+    monkeypatch, platform, engine, n_rows, streaming, touch_ok, expected
+):
+    """The one static rule both writers resolve their engine by: host on
+    a CPU platform, for an in-memory build below the floor, or when the
+    first device touch failed (counted); device otherwise, for every
+    chunk of a streaming build, the tail included."""
+    from hyperspace_tpu import ops
+    from hyperspace_tpu.index import builder
+    from hyperspace_tpu.telemetry.metrics import metrics
+    from hyperspace_tpu.utils import deviceprobe
+
+    assert builder.INMEMORY_HOST_MAX_ROWS == 1 << 22
+    monkeypatch.setattr(ops, "configured_platform", lambda: platform)
+    monkeypatch.setattr(
+        deviceprobe, "_FIRST_TOUCH",
+        {"ok": touch_ok, "error": None if touch_ok else "RuntimeError()"},
+    )
+    metrics.reset()
+    assert builder.resolve_build_engine(engine, n_rows, streaming) == expected
+    assert metrics.counter("build.engine.device_unreachable") == (
+        0 if touch_ok else 1
+    )
+
+
+@pytest.mark.parametrize(
+    "platform, engine",
+    [("tpu", "auto"), ("gpu", "device")],
+    ids=["tpu-auto", "pinned-device"],
+)
+def test_build_engine_rule_raises_where_device_required(
+    monkeypatch, platform, engine
+):
+    """A failed first touch raises DeviceFault on a TPU platform or with
+    the device pinned — it never routes such a build host-side."""
+    from hyperspace_tpu import ops
+    from hyperspace_tpu.index import builder
+    from hyperspace_tpu.utils import deviceprobe
+
+    monkeypatch.setattr(ops, "configured_platform", lambda: platform)
+    monkeypatch.setattr(
+        deviceprobe, "_FIRST_TOUCH", {"ok": False, "error": "RuntimeError()"}
+    )
+    with pytest.raises(deviceprobe.DeviceFault):
+        builder.resolve_build_engine(engine, 512, True)
+
+
+@pytest.mark.parametrize("mode", ["streaming", "inmemory"])
+def test_build_leaves_no_state_in_home(tmp_path, monkeypatch, mode):
+    """An auto build that resolves to the device writes nothing under
+    $HOME: the engine follows the platform, so no verdict is kept for a
+    later process."""
+    from hyperspace_tpu import ops
+    from hyperspace_tpu.index import builder
+    from hyperspace_tpu.telemetry.metrics import metrics
+
+    home = tmp_path / "home"
+    home.mkdir()
+    monkeypatch.setenv("HOME", str(home))
+    # an accelerator platform whose Pallas kernels stay off (only "tpu"
+    # turns them on); the CPU backend does the device engine's work
+    monkeypatch.setattr(ops, "configured_platform", lambda: "gpu")
+    monkeypatch.setattr(builder, "INMEMORY_HOST_MAX_ROWS", 1024)
+    b = sample(3000, seed=41)
+    metrics.reset()
+    if mode == "streaming":
+        write_index_data_streaming(
+            chunks_of(b, 512), ["orderkey"], 4, tmp_path / "o",
+            chunk_capacity=512, engine="auto",
+        )
+    else:
+        write_index_data(b, ["orderkey"], 4, tmp_path / "o", engine="auto")
+    assert metrics.counter("build.engine.device") >= 1
+    assert metrics.counter("build.engine.host") == 0
+    assert sorted(home.rglob("*")) == []
 
 
 def test_streaming_string_key_cross_chunk_vocabs(tmp_path):
@@ -503,54 +502,6 @@ def test_streaming_failure_tears_down_pipeline(tmp_path, monkeypatch):
     assert not (tmp_path / "o" / ".spill").exists()
 
 
-def test_probe_winner_persists_across_processes(tmp_path, monkeypatch):
-    """The probe verdict is a machine property (platform + link + chunk
-    capacity), so a fresh process reads the winner from the disk memo
-    instead of re-paying the probe's compile + round trip (the cost that
-    made round 2's cold build trail the external baseline)."""
-    from hyperspace_tpu.index import stream_builder as sb
-    from hyperspace_tpu.telemetry.metrics import metrics
-
-    cache = tmp_path / "probe-cache" / "engine_probe.json"
-    monkeypatch.setenv("HYPERSPACE_TPU_PROBE_CACHE", str(cache))
-    b = sample(3000, seed=11)
-    sb._ENGINE_CACHE.clear()
-    metrics.reset()
-    monkeypatch.setattr(
-        sb.StreamingIndexWriter, "_link_rules_out_device", lambda self, s: True
-    )
-    try:
-        write_index_data_streaming(
-            chunks_of(b, 500), ["orderkey"], 4, tmp_path / "o",
-            chunk_capacity=500, engine="auto",
-        )
-        assert cache.exists()
-        key = sb._engine_cache_key(512)
-        assert sb._load_persisted_winner(key) == "host"
-        # "new process": in-memory memo cleared; disk verdict honored, no probe
-        sb._ENGINE_CACHE.clear()
-        metrics.reset()
-        write_index_data_streaming(
-            chunks_of(b, 500), ["orderkey"], 4, tmp_path / "o2",
-            chunk_capacity=500, engine="auto",
-        )
-        snap = metrics.snapshot()
-        assert "build.engine.probe_host" not in snap["timers_s"]
-        assert snap["counters"].get("build.engine.winner_from_disk_cache") == 1
-        # a corrupt cache file is ignored, never fatal
-        cache.write_text("{not json")
-        assert sb._load_persisted_winner(key) is None
-        sb._ENGINE_CACHE.clear()
-        metrics.reset()
-        write_index_data_streaming(
-            chunks_of(b, 500), ["orderkey"], 4, tmp_path / "o3",
-            chunk_capacity=500, engine="auto",
-        )
-        assert "build.engine.probe_host" in metrics.snapshot()["timers_s"]
-    finally:
-        sb._ENGINE_CACHE.clear()
-
-
 def test_sum_of_all_null_group_is_null():
     """SQL NULL semantics: sum over a group whose float values are all NULL
     is NULL (NaN), matching avg/min/max of the same group — on both the
@@ -597,46 +548,3 @@ def test_exact_int_sum_guard_handles_int64_min():
     out = hash_aggregate(batch, ["g"], [AggSpec("sum", "v", "s")])
     # exact int64 wrap-around semantics, not a float64 rounding
     assert out.columns["s"].data[0] == np.int64(lo + 3)
-
-
-def test_persisted_device_verdict_not_applied_to_partial_builds(
-    tmp_path, monkeypatch
-):
-    """A disk verdict of "device" must not route a sub-capacity build in a
-    fresh process — that build would pay the cold XLA compile the size
-    policy exists to avoid. A "host" verdict (always compile-free) and an
-    expired entry fall back correctly too."""
-    import time as _time
-
-    from hyperspace_tpu.index import stream_builder as sb
-
-    cache = tmp_path / "engine_probe.json"
-    monkeypatch.setenv("HYPERSPACE_TPU_PROBE_CACHE", str(cache))
-    key = sb._engine_cache_key(512)
-    sb._persist_winner(key, "device")
-    sb._ENGINE_CACHE.clear()
-    try:
-        w = sb.StreamingIndexWriter(
-            ["orderkey"], 4, tmp_path / "o", chunk_capacity=512, engine="auto"
-        )
-        # partial chunk: size policy, not the persisted device verdict
-        assert w._route_engine(100) == "host"
-        assert sb._ENGINE_CACHE == {}
-        # full-capacity chunk: verdict applies (compile amortizable)
-        assert w._route_engine(512) == "device"
-        # host verdicts apply even to partial chunks
-        sb._ENGINE_CACHE.clear()
-        sb._persist_winner(key, "host")
-        w2 = sb.StreamingIndexWriter(
-            ["orderkey"], 4, tmp_path / "o2", chunk_capacity=512, engine="auto"
-        )
-        assert w2._route_engine(100) == "host"
-        # expired entries are ignored
-        sb._ENGINE_CACHE.clear()
-        sb._persist_winner(key, "host")
-        monkeypatch.setattr(
-            _time, "time", lambda: _time.time_ns() / 1e9 + sb.PROBE_CACHE_TTL_S + 60
-        )
-        assert sb._load_persisted_winner(key) is None
-    finally:
-        sb._ENGINE_CACHE.clear()
