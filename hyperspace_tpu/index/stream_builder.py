@@ -19,6 +19,10 @@ the explicit TPU-native pipeline with the same bounded-memory property:
   unification because codes are order-preserving), then the final bucket
   file is written.
 
+A device build whose chunks all land in ONE staged run skips the spill and
+the merge: bucket b is rows ``off[b]:off[b+1]`` of the run's device order,
+gathered straight from the source chunks into its final file.
+
 Peak host memory is O(in-flight chunks + largest bucket), independent of
 dataset size. HBM holds the in-flight padded chunks. That is the "HBM
 residency management … bucket-at-a-time scheduling" hard part of
@@ -66,7 +70,12 @@ from ..parallel.pool import (
 )
 from ..residency import slabs as slab_budget
 from ..storage import layout
-from ..storage.columnar import Column, ColumnarBatch, is_string
+from ..storage.columnar import (
+    Column,
+    ColumnarBatch,
+    is_string,
+    unify_dictionaries,
+)
 from ..telemetry.metrics import metrics
 from ..telemetry.trace import add_bytes as _trace_bytes
 from ..telemetry.trace import span as _trace_span
@@ -197,6 +206,22 @@ def merge_sorted_runs(runs: List[ColumnarBatch], key_names: List[str]) -> Column
     return merged.take(order)
 
 
+def _share_string_vocabs(batches: List[ColumnarBatch]) -> List[ColumnarBatch]:
+    """``batches`` with each string column re-encoded once onto the one
+    vocab that ``ColumnarBatch.gather_concat`` unifies them to, so that
+    many gathers over the same batches share it instead of each
+    re-encoding every batch."""
+    if len(batches) < 2:
+        return batches
+    cols = [dict(b.columns) for b in batches]
+    for name, col in batches[0].columns.items():
+        if is_string(col.dtype_str):
+            unified = unify_dictionaries([b.columns[name] for b in batches])
+            for c, u in zip(cols, unified):
+                c[name] = u
+    return [ColumnarBatch(c) for c in cols]
+
+
 class _HostSlabPair:
     """The fixed pair of host staging buffers under the device engine's
     H2D: the dispatch loop ROTATES slots instead of allocating per chunk,
@@ -212,7 +237,12 @@ class _HostSlabPair:
         self._fences: List[Optional[object]] = [None, None]
         self._turn = 0
 
-    def stage(self, encoded: Dict[str, np.ndarray]) -> Dict[str, np.ndarray]:
+    def stage(
+        self, encoded: Dict[str, np.ndarray], cap: int, fill: Dict[str, int]
+    ) -> Dict[str, np.ndarray]:
+        """Copy one chunk's transport buffers into the next slot, each
+        ``cap`` rows; a short (tail) chunk's pad rows take ``fill``, an
+        in-range key, so they pack inside the chunk's plan."""
         import jax
 
         i = self._turn
@@ -222,10 +252,13 @@ class _HostSlabPair:
             self._fences[i] = None
         bufs = self._bufs[i]
         if bufs is None:
-            bufs = {k: np.empty(a.shape, a.dtype) for k, a in encoded.items()}
+            bufs = {k: np.empty(cap, a.dtype) for k, a in encoded.items()}
             self._bufs[i] = bufs
         for k, a in encoded.items():
-            np.copyto(bufs[k], a)
+            n = len(a)
+            np.copyto(bufs[k][:n], a)
+            if n < cap:
+                bufs[k][n:] = fill[k]
         metrics.incr("build.device.slab_rotations")
         return bufs
 
@@ -243,14 +276,19 @@ class _HostSlabPair:
 class _DeviceRunStager:
     """Accumulates device-sorted chunks into HBM-resident runs
     (docs/14-build-pipeline.md, device-resident build): chunk k's packed
-    composite + permutation stay ON DEVICE until ``RUN_CHUNKS`` chunks
-    have landed — or the run's 63-bit pack budget would overflow, or
+    composite + permutation stay ON DEVICE until a chunk arrives at a run
+    of ``RUN_CHUNKS`` — or the run's 63-bit pack budget would overflow, or
     finalize arrives — then ONE on-device k-way merge produces the run
-    order and ONE non-blocking D2H ships it to the spill stages. The
-    writer guarantees runs never interleave with per-chunk spills: any
-    chunk that cannot stage flushes the pending run FIRST, so run
-    sequence numbers (hence merge-stability tie order, hence the built
-    index bytes) are exactly the serial build's.
+    order (a one-chunk run ships its own permutation) and ONE
+    non-blocking D2H ships it to the spill stages, or, when the build is
+    that one run, to finalize's direct bucket write. The partial tail
+    stages like a full chunk (its pad rows sort last); it is always the
+    last chunk of its run, so ``r·cap + perm`` still indexes the
+    concatenation of the run's chunks. The writer guarantees runs never
+    interleave with per-chunk spills: any chunk that cannot stage
+    flushes the pending run FIRST, so run sequence numbers (hence
+    merge-stability tie order, hence the built index bytes) are exactly
+    the serial build's.
 
     HBM discipline: the worst-case footprint — slab pair + R staged
     chunks + the merge working set — is RESERVED against the shared
@@ -281,7 +319,7 @@ class _DeviceRunStager:
         if self._reserved is not None:
             return self._reserved
         cap = self.w.chunk_capacity
-        slab_bytes = 2 * sum(int(a.nbytes) for a in encoded.values())
+        slab_bytes = 2 * cap * sum(a.dtype.itemsize for a in encoded.values())
         staged = self.STAGED_BYTES_PER_ROW * cap * RUN_CHUNKS
         self._reserved = slab_budget.try_reserve(
             self._budget_tag, slab_bytes + staged
@@ -297,6 +335,11 @@ class _DeviceRunStager:
             bounds: List[tuple], plan: List[tuple]) -> None:
         from ..ops.build import stage_chunk_packed
 
+        if len(self.pending) >= RUN_CHUNKS:
+            # the pending run is full: it flushes as this chunk starts the
+            # next, so a build of at most RUN_CHUNKS chunks stays one run
+            # until finalize
+            self.flush()
         if self.pending:
             union = [
                 (min(a, mn), max(b, mx))
@@ -314,12 +357,15 @@ class _DeviceRunStager:
             union = list(bounds)
         if not self.pending:
             # the run's on-disk order slot is its FIRST chunk's ingest
-            # position — reserved now so later per-chunk spills (the
-            # tail, an ineligible chunk) always order after it
+            # position — reserved now so later per-chunk spills (an
+            # ineligible chunk) always order after it
             self.seq = self.w._next_seq()
-        bufs = self.slab.stage(encoded)
+        cap = self.w.chunk_capacity
+        fill = {k: mn for k, (mn, _) in zip(self.w.indexed_cols, bounds)}
+        bufs = self.slab.stage(encoded, cap, fill)
         staged, h2d_bytes = stage_chunk_packed(
-            bufs, batch.schema(), self.w.indexed_cols, self.w.num_buckets, plan
+            bufs, batch.schema(), self.w.indexed_cols, self.w.num_buckets,
+            plan, batch.num_rows,
         )
         self.slab.fence(staged.packed)
         metrics.incr("build.stream.h2d_bytes", h2d_bytes)
@@ -328,16 +374,16 @@ class _DeviceRunStager:
         self.union = union
         self.pending.append(staged)
         self.batches.append(batch)
-        if len(self.pending) >= RUN_CHUNKS:
-            self.flush()
 
-    def flush(self) -> None:
+    def flush(self, direct: bool = False) -> Optional[tuple]:
         """Merge the pending chunks into one sorted run on device and
-        hand its (non-blocking) D2H to the spill stages; the next
-        chunk's kernel overlaps the fetch. No-op when nothing pends."""
+        issue its non-blocking D2H; the next chunk's kernel overlaps the
+        fetch. The run goes to the spill stages, or, with ``direct``,
+        back to the caller as ``(batches, fetch)``, where ``fetch()``
+        blocks for the run's (order, counts). No-op when nothing pends."""
         r = len(self.pending)
         if r == 0:
-            return
+            return None
         from ..ops.build import merge_staged_chunks, run_pack_plan
 
         w = self.w
@@ -345,6 +391,9 @@ class _DeviceRunStager:
         assert run_plan is not None  # add() flushed before overflow
         staged, batches, seq = self.pending, self.batches, self.seq
         self.pending, self.batches, self.union, self.seq = [], [], None, None
+        # the tail is the run's last chunk: its pad rows sort last, so the
+        # order's first n entries are the real rows
+        n = sum(b.num_rows for b in batches)
         # an un-fetched merged run pins HBM exactly like an un-fetched
         # chunk: same in-flight slot discipline (failure-aware)
         w._device_slots.acquire()
@@ -364,26 +413,33 @@ class _DeviceRunStager:
         metrics.incr("build.device.staged_runs")
         _trace_bytes("d2h_bytes", d2h_bytes)
 
-        def finish(order_dev=order_dev, counts_dev=counts_dev,
-                   batches=batches, d2h_bytes=d2h_bytes):
-            from ..ops.build import _canonicalize_f64
-
+        def fetch():
             try:
-                order = np.asarray(order_dev).astype(np.int64, copy=False)
+                order = np.asarray(order_dev)[:n].astype(np.int64, copy=False)
                 counts = np.asarray(counts_dev)[: w.num_buckets].astype(
                     np.int64, copy=False
                 )
                 metrics.incr("build.stream.d2h_calls")
                 metrics.incr("build.stream.d2h_bytes", d2h_bytes)
-                # gather payloads straight from the R source chunks in
-                # merged order — no materialized concat copy
-                out = ColumnarBatch.gather_concat(batches, order)
-                _canonicalize_f64(out)
-                return out, counts
+                return order, counts
             finally:
                 w._device_slots.release()
 
+        if direct:
+            return batches, fetch
+
+        def finish():
+            from ..ops.build import _canonicalize_f64
+
+            order, counts = fetch()
+            # gather payloads straight from the R source chunks in
+            # merged order — no materialized concat copy
+            out = ColumnarBatch.gather_concat(batches, order)
+            _canonicalize_f64(out)
+            return out, counts
+
         w._enqueue_spill(finish, seq=seq)
+        return None
 
     def drop(self) -> None:
         """Abort-path teardown: device references released, budget
@@ -476,23 +532,17 @@ class StreamingIndexWriter:
         self._t_pipeline_done: Optional[float] = None
 
     def _try_stage_chunk(self, batch: ColumnarBatch) -> bool:
-        """Route one chunk into the device run stager if eligible.
-        Ineligible chunks FLUSH any pending run first — runs must never
-        interleave with per-chunk spills, because stability tie order IS
-        the on-disk run order — then return False for the per-chunk
-        path. Every decline is counted (the host tail is never silent,
-        the compile/agg decline discipline applied here)."""
+        """Route one chunk, full or the partial tail, into the device run
+        stager if eligible. Ineligible chunks FLUSH any pending run first
+        — runs must never interleave with per-chunk spills, because
+        stability tie order IS the on-disk run order — then return False
+        for the per-chunk path. Every decline is counted (the host tail
+        is never silent, the compile/agg decline discipline applied
+        here)."""
         if RUN_CHUNKS * self.chunk_capacity > (1 << 31) - 1:
             # the merged order ships as int32 (4 B/row, matching the
             # per-chunk permutation): runs beyond 2^31 rows cannot
             metrics.incr("build.device.staging_declined.width")
-            return False
-        if batch.num_rows != self.chunk_capacity:
-            # the partial tail routes per-chunk (its pad rows would need
-            # a validity operand through the merge); it arrives last, so
-            # flushing first preserves run order
-            metrics.incr("build.device.staging_declined.tail")
-            self._flush_staged()
             return False
         dtypes = batch.schema()
         if any(is_string(dtypes[k]) for k in self.indexed_cols):
@@ -791,7 +841,9 @@ class StreamingIndexWriter:
     # -- finalize -------------------------------------------------------------
     def finalize(self) -> List[Path]:
         """Merge spilled runs bucket-at-a-time and write the final index
-        files. Returns the written paths (sorted)."""
+        files — or, when the build is one staged run, write each bucket
+        straight from the run's order. Returns the written paths
+        (sorted)."""
         if self._finalized:
             raise HyperspaceException("Writer already finalized.")
         if self._pending:
@@ -804,11 +856,16 @@ class StreamingIndexWriter:
             self._pending_rows = 0
             self._process_chunk(tail)
         # build.drain: the wait for the in-flight device merges, their
-        # D2H and gather, and the spill writes. A staged run may still
-        # pend when the source was an exact multiple of the chunk
-        # capacity (no tail to force the flush)
+        # D2H and gather, and the spill writes. The last staged run still
+        # pends here; when it is the build's only run its order stays in
+        # memory for the direct bucket write instead of spilling
+        run = None
         with _trace_span("build.drain", chunks=self._chunk_seq):
-            self._flush_staged()
+            if self._one_staged_run():
+                batches, fetch = self._stager.flush(direct=True)
+                run = (batches, *fetch())
+            else:
+                self._flush_staged()
             self._drain_spills()
         if self._stager is not None:
             # flushed + drained: nothing pends — drop releases the slab
@@ -827,12 +884,73 @@ class StreamingIndexWriter:
             )
         self._finalized = True
         # build.bucket_merge: the final per-bucket read, merge and write
-        # (or, in runs mode, the rename of the spills into place)
-        with _trace_span("build.bucket_merge", runs=len(self._spills)):
-            return self._write_final()
+        # (in runs mode, the rename of the spills into place; for a
+        # one-run build, the gather and write from the run's order)
+        with _trace_span(
+            "build.bucket_merge",
+            runs=1 if run is not None else len(self._spills),
+            direct=run is not None,
+        ):
+            return self._write_final(run)
 
-    def _write_final(self) -> List[Path]:
-        """The final index files from the drained spills (sorted)."""
+    def _one_staged_run(self) -> bool:
+        """Whether the whole build is the one staged run still pending:
+        its sequence slot is the only one reserved (no earlier run, no
+        per-chunk or sharded spill). Runs mode keeps its spill, which it
+        promotes to the final file."""
+        st = self._stager
+        return (
+            self.finalize_mode == "merge"
+            and st is not None
+            and bool(st.pending)
+            and self._chunk_seq == 1
+        )
+
+    def _write_run_buckets(
+        self, batches: List[ColumnarBatch], order: np.ndarray,
+        counts: np.ndarray,
+    ) -> List[Path]:
+        """Each non-empty bucket's file straight from the build's one run:
+        the run is bucket-grouped and key-sorted, so bucket b is rows
+        ``off[b]:off[b+1]`` of its order, gathered from the source chunks
+        — the bytes a spill of the run, re-read and merged, would give.
+        The gathers fan out across the pool at the merge's width."""
+        from ..ops.build import _canonicalize_f64
+
+        off = np.concatenate([[0], np.cumsum(counts)])
+        batches = _share_string_vocabs(batches)
+        self.out_dir.mkdir(parents=True, exist_ok=True)
+
+        def write_bucket(b: int):
+            out = ColumnarBatch.gather_concat(
+                batches, order[off[b] : off[b + 1]]
+            )
+            _canonicalize_f64(out)
+            t_w = time.perf_counter()
+            p = self.out_dir / layout.bucket_file_name(b)
+            layout.write_batch(
+                p, out, sorted_by=self.indexed_cols, bucket=b,
+                extra=self.extra_meta,
+            )
+            return p, time.perf_counter() - t_w
+
+        buckets = [b for b in range(self.num_buckets) if counts[b] > 0]
+        workers = self.pipeline.merge_workers if self.pipeline.enabled else 1
+        results = run_parallel(
+            [lambda b=b: write_bucket(b) for b in buckets],
+            workers,
+            name="bucket-merge",
+        )
+        metrics.record_time(
+            "build.stream.merge_write", sum(w_s for _, w_s in results)
+        )
+        metrics.incr("build.stream.direct_finalize")
+        return [p for p, _ in results]
+
+    def _write_final(self, run: Optional[tuple] = None) -> List[Path]:
+        """The final index files (sorted): from ``run`` — the build's one
+        staged run as (batches, order, counts) — or from the drained
+        spills."""
         t0 = time.perf_counter()
         written: List[Path] = []
         if self._spills and self.finalize_mode == "runs":
@@ -861,7 +979,9 @@ class StreamingIndexWriter:
                 metrics.record_time("build.stream.steady", st["steady_total_s"])
                 metrics.incr("build.stream.steady_rows", int(st["steady_rows"]))
             return sorted(written)
-        if self._spills:
+        if run is not None:
+            written = self._write_run_buckets(*run)
+        elif self._spills:
             # per-spill cumulative row offsets of each bucket segment; one
             # reader per spill (footer parsed + vocab decoded once, not per
             # (bucket, run) pair); readers are shared by the merge workers

@@ -423,11 +423,12 @@ def _single_staged_kernel_packed(
     bucketize + radix pack + single-operand sort, but the sorted packed
     COMPOSITE stays on device alongside the permutation — the merge
     operand of the on-device run merge (_staged_merge_fn). Nothing is
-    fetched here; the only D2H the staged path ever pays is the merged
-    run's permutation, one call per ``RUN_CHUNKS`` chunks
-    (index/stream_builder.py). Staged chunks
-    are always full-capacity (the tail routes per-chunk), so there is no
-    n_valid operand: every row is real."""
+    fetched here; the only D2H the staged path ever pays is the run's
+    order, one call per run of up to ``RUN_CHUNKS`` chunks
+    (index/stream_builder.py). The true row count enters as the device
+    scalar ``n_valid``, so one compiled program serves full chunks and
+    the partial tail: pad rows take bucket ``num_buckets`` (which the
+    pack plan budgets for), sort last and are not counted."""
     cache_key = ("perm-packed-staged", dtypes_key, key_names, num_buckets, narrow)
     fn = _single_kernel_cache.get(cache_key)
     if fn is not None:
@@ -436,10 +437,11 @@ def _single_staged_kernel_packed(
     keys = list(key_names)
 
     @jax.jit
-    def build_bucket_sort_staged(arrays, vh, mins, shifts):
+    def build_bucket_sort_staged(arrays, vh, n_valid, mins, shifts):
         bucket = device_bucket_ids(arrays, dtypes, keys, vh, num_buckets)
         m = bucket.shape[0]
         iota = lax.iota(jnp.int32, m)
+        bucket = jnp.where(iota < n_valid, bucket, num_buckets)
         packed = _pack_composite(arrays, keys, bucket, mins, shifts, narrow)
         packed_sorted, perm = lax.sort([packed, iota], num_keys=1)
         # the run merge unpacks int64 composites whatever the chunk's width
@@ -559,13 +561,15 @@ def stage_chunk_packed(
     key_names: List[str],
     num_buckets: int,
     plan: List[Tuple[int, int]],
+    n_valid: int,
 ) -> Tuple[StagedChunk, int]:
-    """Dispatch one full-capacity chunk through the staged kernel and
-    leave its sorted composite + permutation resident on device.
-    ``host_bufs`` are the chunk's transport buffers — a slot of the
-    writer's slab pair (pre-staged, pinnable, reused every other chunk).
-    Returns the staged handle and the H2D byte count. Caller guarantees:
-    no string key columns, full-capacity chunk, ``plan`` fits 63 bits."""
+    """Dispatch one chunk through the staged kernel and leave its sorted
+    composite + permutation resident on device. ``host_bufs`` are the
+    chunk's capacity-sized transport buffers — a slot of the writer's
+    slab pair (pre-staged, pinnable, reused every other chunk) — whose
+    first ``n_valid`` rows are real; the rest hold an in-range key and
+    sort last. Returns the staged handle and the H2D byte count. Caller
+    guarantees: no string key columns, ``plan`` fits 63 bits."""
     h2d_bytes = 0
     arrays = {}
     for k in key_names:
@@ -579,7 +583,8 @@ def stage_chunk_packed(
         key_dtypes, tuple(key_names), num_buckets, _narrow_pack(plan, num_buckets)
     )
     metrics.incr("build.engine.device_radix")
-    packed, perm, counts = kernel(arrays, {}, mins_dev, shifts_dev)
+    n_dev = jnp.asarray(n_valid, dtype=jnp.int32)
+    packed, perm, counts = kernel(arrays, {}, n_dev, mins_dev, shifts_dev)
     return StagedChunk(packed, perm, counts, plan), h2d_bytes
 
 
@@ -593,7 +598,14 @@ def merge_staged_chunks(
     ride the link while the next chunk's
     kernel runs, and the spill-compute worker's blocking fetch finds
     them already landing. Returns the un-fetched (order, counts) device
-    arrays; order indexes the concatenation of the R original chunks."""
+    arrays; order indexes the concatenation of the R original chunks.
+    A run of one chunk is already sorted: its own permutation and counts
+    ship, and no merge program compiles."""
+    if len(staged) == 1:
+        order_dev, counts_dev = staged[0].perm, staged[0].counts
+        for arr in (order_dev, counts_dev):
+            arr.copy_to_host_async()
+        return order_dev, counts_dev
     nkeys = len(run_plan)
     packed = jnp.stack([s.packed for s in staged])
     perms = jnp.stack([s.perm for s in staged])

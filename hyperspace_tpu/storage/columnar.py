@@ -375,17 +375,25 @@ class ColumnarBatch:
         ends = np.cumsum(sizes)
         chunk_ix = np.searchsorted(ends, indices, side="right")
         local_ix = indices - (ends - sizes)[chunk_ix]
-        masks = [chunk_ix == ci for ci in range(len(batches))]
+        # each batch's output positions and source rows, found once and
+        # shared by every column
+        picks = []
+        for ci in range(len(batches)):
+            at = np.flatnonzero(chunk_ix == ci)
+            picks.append((at, local_ix[at]))
         out: Dict[str, Column] = {}
         for n in names:
             cols = [b.columns[n] for b in batches]
             vocab = None
             if is_string(cols[0].dtype_str):
-                cols = unify_dictionaries(cols)
+                # batches already sharing one (sorted) vocab object are
+                # unified: re-encoding would rewrite every code unchanged
+                if any(c.vocab is not cols[0].vocab for c in cols[1:]):
+                    cols = unify_dictionaries(cols)
                 vocab = cols[0].vocab
             acc = np.empty(len(indices), dtype=cols[0].data.dtype)
-            for c, m in zip(cols, masks):
-                acc[m] = c.data[local_ix[m]]
+            for c, (at, src) in zip(cols, picks):
+                acc[at] = c.data[src]
             out[n] = Column(cols[0].dtype_str, acc, vocab)
         return ColumnarBatch(out)
 

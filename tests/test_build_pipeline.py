@@ -866,7 +866,9 @@ def test_device_run_merge_matches_host_merge(r, width):
             (min(a, mn), max(c, mx))
             for (a, c), (mn, mx) in zip(union, bounds)
         ]
-        s, _ = ops_build.stage_chunk_packed(encoded, b.schema(), ["k"], nb, plan)
+        s, _ = ops_build.stage_chunk_packed(
+            encoded, b.schema(), ["k"], nb, plan, cap
+        )
         staged.append(s)
     run_plan = ops_build.run_pack_plan(union, nb)
     order_dev, counts_dev = ops_build.merge_staged_chunks(staged, run_plan, nb)
@@ -901,21 +903,23 @@ def _tied_sample(n, seed):
 
 
 @pytest.mark.parametrize(
-    "full_chunks, keys, make",
+    "full_chunks, keys, make, runs",
     [
-        (4, ("orderkey", "qty"), _int_sample),
-        # the sf1-build shape in miniature: two staged chunks, then a
-        # partial tail that flushes them as one 2-chunk run
-        (2, ("orderkey",), _tied_sample),
+        # four full chunks fill a run; the tail stages as a run of its own
+        (4, ("orderkey", "qty"), _int_sample, 2),
+        # the sf1-build shape in miniature: two staged chunks and the
+        # partial tail make one 3-chunk run, ties spread over all three
+        (2, ("orderkey",), _tied_sample, 1),
     ],
     ids=["distinct", "tied"],
 )
 def test_staged_device_build_matches_per_chunk_bytes(
-    tmp_path, full_chunks, keys, make
+    tmp_path, full_chunks, keys, make, runs
 ):
     """Byte parity host engine vs staged device build (serial AND
-    pipelined), with a partial tail chunk in the stream; the staged side
-    pays one blocking D2H call per run, not one per chunk."""
+    pipelined), with a partial tail chunk in the stream that stages
+    through the kernel's n_valid operand; the staged side pays one
+    blocking D2H call per run, not one per chunk."""
     from hyperspace_tpu.telemetry.metrics import metrics
 
     b = make(full_chunks * 512 + 100, seed=29)
@@ -925,12 +929,13 @@ def test_staged_device_build_matches_per_chunk_bytes(
     metrics.reset()
     s_bytes = _staged_build(tmp_path, "staged", keys=keys, batch=b)
     s_calls = metrics.counter("build.stream.d2h_calls")
-    assert metrics.counter("build.device.staged_chunks") == full_chunks
-    assert metrics.counter("build.device.staged_runs") == 1
+    assert metrics.counter("build.device.staged_chunks") == full_chunks + 1
+    assert metrics.counter("build.device.staging_declined.tail") == 0
+    assert metrics.counter("build.device.staged_runs") == runs
+    assert metrics.counter("build.stream.direct_finalize") == (runs == 1)
     assert h_bytes == s_bytes
-    # the staged run pays ONE blocking fetch for its full chunks (+ the
-    # tail's per-chunk fetch)
-    assert s_calls == 2
+    # each staged run, the tail's included, pays ONE blocking fetch
+    assert s_calls == runs
     p_bytes = _staged_build(
         tmp_path, "staged_pipe", keys=keys, batch=b, pipeline=pipelined(),
     )
@@ -960,8 +965,7 @@ def test_string_key_declines_staging_with_parity(tmp_path):
 
 def test_staged_runs_group_run_chunks_then_tail(tmp_path):
     """Nine staged full chunks and a partial tail spill as runs of 4, 4
-    and 1 chunks (the tail flushes the pending one), then the tail's own
-    per-chunk run."""
+    and 2 chunks: the tail stages and joins the last run."""
     from hyperspace_tpu.telemetry.metrics import metrics
 
     assert RUN_CHUNKS == 4
@@ -974,12 +978,100 @@ def test_staged_runs_group_run_chunks_then_tail(tmp_path):
         pipeline=BuildPipelineConfig.serial(), finalize_mode="runs",
     )
     assert [layout.read_batch(p).num_rows for p in files] == [
-        4 * cap, 4 * cap, cap, 100,
+        4 * cap, 4 * cap, cap + 100,
     ]
-    assert metrics.counter("build.device.staged_chunks") == 9
+    assert metrics.counter("build.device.staged_chunks") == 10
     assert metrics.counter("build.device.staged_runs") == 3
-    assert metrics.counter("build.device.staging_declined.tail") == 1
-    assert metrics.counter("build.stream.d2h_calls") == 4
+    assert metrics.counter("build.device.staging_declined.tail") == 0
+    assert metrics.counter("build.stream.d2h_calls") == 3
+    assert slab_budget.held_bytes() == 0
+
+
+def _vocab_chunks(full_chunks, cap, seed):
+    """Chunks that each carry their own string vocab (one value only a
+    single chunk holds) and float64 zeros of both signs: the shapes the
+    direct bucket write must gather exactly as a spilled run reads."""
+    rng = np.random.default_rng(seed)
+    out = []
+    for c in range(full_chunks + 1):
+        n = cap if c < full_chunks else 100
+        price = rng.choice([0.0, -0.0, 1.5, 2.25], n)
+        out.append(ColumnarBatch.from_pydict(
+            {
+                "orderkey": rng.integers(0, 10**6, n).astype(np.int64),
+                "price": price.astype(np.float64),
+                "flag": rng.choice(
+                    [b"A", b"N", b"R", b"C%d" % c], n
+                ).astype(object),
+            },
+            schema={"orderkey": "int64", "price": "float64", "flag": "string"},
+        ))
+    return out
+
+
+@pytest.mark.parametrize(
+    "full_chunks, direct",
+    [(2, 1), (9, 0)],
+    ids=["one-run", "nine-plus-tail"],
+)
+@pytest.mark.parametrize("pipe", ["serial", "pipelined"])
+def test_direct_finalize_matches_merge_bytes(
+    tmp_path, monkeypatch, full_chunks, direct, pipe
+):
+    """A build that is one staged run writes its buckets straight from
+    the run's order: every bucket file is byte-identical to the spill,
+    re-read and merge of the same chunks, and no spill directory is
+    made. A nine-chunk-plus-tail build is three runs and still merges."""
+    from hyperspace_tpu.telemetry.metrics import metrics
+
+    cap = 512
+    config = BuildPipelineConfig.serial() if pipe == "serial" else pipelined()
+
+    def build(tag):
+        out = tmp_path / tag
+        write_index_data_streaming(
+            iter(_vocab_chunks(full_chunks, cap, seed=71)), ["orderkey"], 8,
+            out, chunk_capacity=cap, engine="device", pipeline=config,
+        )
+        return _bucket_bytes(out)
+
+    metrics.reset()
+    d_bytes = build("direct")
+    assert metrics.counter("build.stream.direct_finalize") == direct
+    assert not (tmp_path / "direct" / ".spill").exists()
+    monkeypatch.setattr(
+        StreamingIndexWriter, "_one_staged_run", lambda self: False
+    )
+    metrics.reset()
+    m_bytes = build("merged")
+    assert metrics.counter("build.stream.direct_finalize") == 0
+    assert len(d_bytes) == 8
+    assert d_bytes == m_bytes
+    assert slab_budget.held_bytes() == 0
+
+
+@pytest.mark.parametrize("pipe", ["serial", "pipelined"])
+def test_one_chunk_build_compiles_no_merge(tmp_path, monkeypatch, pipe):
+    """A source below one chunk is one staged run of the tail alone: the
+    staged kernel's own permutation ships, no merge program is built,
+    and the bytes equal the host engine's."""
+    from hyperspace_tpu.ops import build as ops_build
+    from hyperspace_tpu.telemetry.metrics import metrics
+
+    def no_merge(*a, **k):
+        raise AssertionError("a one-chunk run built a merge program")
+
+    b = _int_sample(300, seed=73)
+    h_bytes = _staged_build(tmp_path, "host", batch=b, engine="host")
+    monkeypatch.setattr(ops_build, "_staged_merge_fn", no_merge)
+    metrics.reset()
+    config = BuildPipelineConfig.serial() if pipe == "serial" else pipelined()
+    s_bytes = _staged_build(tmp_path, "one", batch=b, pipeline=config)
+    assert metrics.counter("build.device.staged_chunks") == 1
+    assert metrics.counter("build.device.staged_runs") == 1
+    assert metrics.counter("build.stream.d2h_calls") == 1
+    assert metrics.counter("build.stream.direct_finalize") == 1
+    assert s_bytes == h_bytes
     assert slab_budget.held_bytes() == 0
 
 

@@ -67,6 +67,38 @@ def test_unify_dictionaries_missing_value():
     assert u1.vocab is u2.vocab or list(u1.vocab) == list(u2.vocab)
 
 
+@pytest.mark.parametrize("vocab", ["own", "shared"])
+def test_gather_concat_equals_concat_take(vocab):
+    """Rows gathered straight from several batches equal the concat's
+    take, string codes and vocab included, whether the batches carry
+    their own vocabs or already share one (then it is kept as is)."""
+    rng = np.random.default_rng(5)
+    parts = [
+        ColumnarBatch.from_pydict(
+            {
+                "k": rng.integers(0, 100, n).astype(np.int64),
+                "s": rng.choice(["a", "b", "x%d" % i], n).astype(object),
+            },
+            {"k": "int64", "s": "string"},
+        )
+        for i, n in enumerate([7, 0, 5, 9])
+    ]
+    if vocab == "shared":
+        shared = unify_dictionaries([p.columns["s"] for p in parts])
+        parts = [
+            ColumnarBatch({"k": p.columns["k"], "s": u})
+            for p, u in zip(parts, shared)
+        ]
+    idx = rng.permutation(21)[:15]
+    got = ColumnarBatch.gather_concat(parts, idx)
+    want = ColumnarBatch.concat(parts).take(idx)
+    for name in ("k", "s"):
+        np.testing.assert_array_equal(got.columns[name].data, want.columns[name].data)
+    assert list(got.columns["s"].vocab) == list(want.columns["s"].vocab)
+    if vocab == "shared":
+        assert got.columns["s"].vocab is parts[0].columns["s"].vocab
+
+
 def test_concat_schema_mismatch():
     b1 = ColumnarBatch.from_pydict({"a": np.arange(2)})
     b2 = ColumnarBatch.from_pydict({"b": np.arange(2)})

@@ -131,6 +131,29 @@ def test_build_step_compiles(one_chip):
 
 
 @pytest.mark.parametrize("narrow", [True, False], ids=["int32", "int64"])
+def test_staged_step_compiles(one_chip, narrow):
+    # the staged build's per-chunk kernel, which keeps its sorted
+    # composite on device and takes the real row count as the n_valid
+    # operand (full chunks and the partial tail), at a small chunk for
+    # the same reason
+    import jax.numpy as jnp
+
+    from hyperspace_tpu.ops.build import _single_staged_kernel_packed
+
+    rows = 4096
+    kernel = _single_staged_kernel_packed(
+        (("l_orderkey", "int64"),), ("l_orderkey",), 200, narrow
+    )
+    kernel.lower(
+        {"l_orderkey": _spec((rows,), jnp.int64, one_chip)},
+        {},
+        _spec((), jnp.int32, one_chip),
+        _spec((1,), jnp.int64, one_chip),
+        _spec((1,), jnp.int32, one_chip),
+    ).compile()
+
+
+@pytest.mark.parametrize("narrow", [True, False], ids=["int32", "int64"])
 def test_run_merge_compiles(one_chip, narrow):
     # the staged build's device run merge (one stable sort of two
     # re-packed runs), at a small chunk for the same reason

@@ -254,7 +254,7 @@ def test_build_programs_carry_their_names():
     cases = [
         (B._single_perm_kernel(dtypes, ("k",), 8), (arrays, {}, n)),
         (B._single_perm_kernel_packed(dtypes, ("k",), 8, True), (arrays, {}, n, mins, shifts)),
-        (B._single_staged_kernel_packed(dtypes, ("k",), 8, True), (arrays, {}, mins, shifts)),
+        (B._single_staged_kernel_packed(dtypes, ("k",), 8, True), (arrays, {}, n, mins, shifts)),
         (
             B._staged_merge_fn(1, True),
             (S((2, 1024), jnp.int64), S((2, 1024), jnp.int32), S((2, 8), jnp.int32),
