@@ -11,14 +11,19 @@ TINY = np.finfo(np.float64).tiny
 def compare_answer(got: dict, want: dict, columns, keys) -> tuple:
     """(wrong, gap) of one answer. ``wrong``: the row count or an exact
     (integer or date) column differs. ``gap``: the largest relative gap
-    of a float value, |got - want| / |want|, rows ordered by ``keys``."""
+    of a float value, |got - want| / |want|, rows ordered by ``keys``,
+    or, with no ``keys``, in the order each answer gives them (an answer
+    whose order is part of it)."""
     n = len(want[columns[0]])
     if len(got[columns[0]]) != n:
         return True, 0.0
     if n == 0:
         return False, 0.0
-    ga = np.lexsort([got[k] for k in reversed(keys)])
-    wa = np.lexsort([want[k] for k in reversed(keys)])
+    if keys:
+        ga = np.lexsort([got[k] for k in reversed(keys)])
+        wa = np.lexsort([want[k] for k in reversed(keys)])
+    else:
+        ga = wa = np.arange(n)
     wrong, gap = False, 0.0
     for c in columns:
         x, y = got[c][ga], want[c][wa]

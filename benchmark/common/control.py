@@ -43,11 +43,10 @@ def control_numbers(root: Path, workload: str, seed: int, per_client: int = 8) -
             numbers = {"rows_wrong": ref_mod.rows_wrong(got, source, names),
                        "rows_misbucketed": 0, "order_breaks": 0}
         else:
-            from . import queries
-
-            li = ref_mod.concat(dep.tables["lineitem"])
-            want_ref = ref_mod.Reference(li)
-            ctrl_ref = ref_mod.Reference(li, dtype=np.float32)
+            shapes = bench.shapes(traffic)
+            tables = {t: ref_mod.concat(dep.tables[t]) for t in config["tables"]}
+            want_ref = ref_mod.Reference(tables)
+            ctrl_ref = ref_mod.Reference(tables, dtype=np.float32)
             keyspaces = {c: Keyspace(dep.column(*c.split(".")), seed) for c in key_columns(traffic)}
             wrong, gap, seen = 0, 0.0, {}
             for c in range(traffic["clients"]):
@@ -56,11 +55,8 @@ def control_numbers(root: Path, workload: str, seed: int, per_client: int = 8) -
                     shape, params = next(s)
                     key = (shape, tuple(sorted(params.items())))
                     if key not in seen:
-                        columns, keys = queries.SHAPES[shape]
-                        seen[key] = compare.compare_answer(
-                            ctrl_ref.answer(shape, params), want_ref.answer(shape, params),
-                            columns, keys,
-                        )
+                        seen[key] = shapes[shape].compare(
+                            ctrl_ref.answer(shape, params), want_ref.answer(shape, params))
                     wrong, gap = wrong + seen[key][0], max(gap, seen[key][1])
             numbers = {"answers_failed": 0, "answers_wrong": wrong, "max_rel_gap": gap}
         numbers.update(fallback_counters=0, device_paths_missing=0)

@@ -5,8 +5,9 @@ Everything a cell needs is found by name under ``root``:
 ``BENCHMARK.json`` names the cell's configuration file and traffic mix;
 the mix is ``benchmark/traffic/<traffic>.json``; each metric is read by
 ``benchmark/metrics/<metric>.py`` (a ``read(run)`` that returns a number,
-or None where it finds nothing to read); the configuration names its
-plain reference module.
+or None where it finds nothing to read); each query shape the mix names
+is ``benchmark/shapes/<shape>.py`` (``queries.py``); the configuration
+names its tables and its plain reference module.
 """
 
 from __future__ import annotations
@@ -74,6 +75,11 @@ class Bench:
     def reference(self, config: dict):
         return load_module(self.root / config["reference"])
 
+    def shapes(self, mix: dict) -> dict:
+        """{name: queries.Shape} of every shape the mix's requests name."""
+        names = sorted({r["shape"] for r in mix.get("requests", ())})
+        return {n: queries.Shape(load_module(self.root / "benchmark" / "shapes" / f"{n}.py")) for n in names}
+
 
 @dataclass
 class Record:
@@ -114,6 +120,7 @@ class Run:
     gc_pauses: list = field(default_factory=list)  # (generation, seconds) in the window
     rows: dict = field(default_factory=dict)  # rows per table
     compile: dict = field(default_factory=dict)
+    shapes: dict = field(default_factory=dict)  # name -> queries.Shape the mix names
 
 
 def _annotate(on: bool, name: str):
@@ -188,9 +195,9 @@ class Profiler:
 # ---------------------------------------------------------------------------
 # query cells
 # ---------------------------------------------------------------------------
-def _serve(server, dep, shape, params, rec: Record, annotate: bool) -> Record:
-    df = queries.dataframe(dep.session, dep.paths, shape, params)
-    columns = queries.SHAPES[shape][0]
+def _serve(server, dep, shapes: dict, rec: Record, annotate: bool) -> Record:
+    shape = shapes[rec.shape]
+    df = shape.dataframe(dep.session, dep.paths, rec.params)
     with _annotate(annotate, "bench.submit"):
         rec.t0 = time.perf_counter()
         try:
@@ -206,11 +213,11 @@ def _serve(server, dep, shape, params, rec: Record, annotate: bool) -> Record:
             batch = None
         rec.t1 = time.perf_counter()
     if batch is not None:
-        rec.answer = queries.arrays(batch, columns)
+        rec.answer = queries.arrays(batch, shape.columns)
     return rec
 
 
-def _closed_loop(server, dep, streams, seconds: float, annotate: bool) -> tuple:
+def _closed_loop(server, dep, shapes: dict, streams, seconds: float, annotate: bool) -> tuple:
     """Each client sends its next request when the last one returns,
     until ``seconds`` have passed; requests in flight then finish.
     Returns (records, window start, last completion)."""
@@ -223,7 +230,7 @@ def _closed_loop(server, dep, streams, seconds: float, annotate: bool) -> tuple:
         end = clock["end"]
         while time.perf_counter() < end:
             shape, params = next(streams[c])
-            out[c].append(_serve(server, dep, shape, params, Record(c, shape, params), annotate))
+            out[c].append(_serve(server, dep, shapes, Record(c, shape, params), annotate))
 
     threads = [threading.Thread(target=client, args=(c,), daemon=True) for c in range(len(streams))]
     for t in threads:
@@ -238,7 +245,7 @@ def _closed_loop(server, dep, streams, seconds: float, annotate: bool) -> tuple:
     return records, start, last
 
 
-def _bursts(dep, traffic, keyspaces, seed):
+def _bursts(dep, shapes, traffic, keyspaces, seed):
     """Warm the batched programs of every batch size the window can form:
     a paused one-worker server takes a burst of n requests, then starts
     and serves them as one batch."""
@@ -251,7 +258,7 @@ def _bursts(dep, traffic, keyspaces, seed):
             max_workers=1, autostart=False, recovery_sweep_interval_s=0))
         try:
             reqs = [next(s) for _ in range(n)]
-            tickets = [server.submit(queries.dataframe(dep.session, dep.paths, sh, p)) for sh, p in reqs]
+            tickets = [server.submit(shapes[sh].dataframe(dep.session, dep.paths, p)) for sh, p in reqs]
             server.start()
             for t in tickets:
                 t.result(timeout=600)
@@ -276,15 +283,15 @@ def run_queries(run: Run, dep: Deployment, clock, t_setup0: float) -> None:
             firsts.setdefault(shape, params)
         for _ in range(2):
             for shape, params in firsts.items():
-                rec = _serve(server, dep, shape, params, Record(-1, shape, params), False)
+                rec = _serve(server, dep, run.shapes, Record(-1, shape, params), False)
                 if not rec.ok:
                     raise RuntimeError(f"warm-up {shape} failed: {rec.error}")
             hbm_cache.wait_background(600)
         log(f"set-up: first touches and residency {time.perf_counter() - t_setup0:.3f} s")
-        _bursts(dep, traffic, keyspaces, run.seed)
+        _bursts(dep, run.shapes, traffic, keyspaces, run.seed)
         if traffic["warmup"]["seconds"] > 0:
             warm = [Stream(traffic, keyspaces, run.seed, c, 1) for c in range(clients)]
-            recs, _, _ = _closed_loop(server, dep, warm, traffic["warmup"]["seconds"], False)
+            recs, _, _ = _closed_loop(server, dep, run.shapes, warm, traffic["warmup"]["seconds"], False)
             bad = [r.error for r in recs if not r.ok]
             if bad:
                 raise RuntimeError(f"warm-up requests failed: {bad[:3]}")
@@ -295,7 +302,7 @@ def run_queries(run: Run, dep: Deployment, clock, t_setup0: float) -> None:
         run.setup_s = time.perf_counter() - t_setup0
         profiler = Profiler(dep.ws / "profile", run.trace)
         with profiler, _gc_pauses(run.gc_pauses):
-            records, start, last = _closed_loop(server, dep, streams, run.seconds, run.trace)
+            records, start, last = _closed_loop(server, dep, run.shapes, streams, run.seconds, run.trace)
         run.counters = guard.moved(before, guard.counters())
         run.compile = {k: v - c0[k] for k, v in clock.reading().items()}
         run.records, run.window_s = records, last - start
@@ -309,7 +316,7 @@ def run_queries(run: Run, dep: Deployment, clock, t_setup0: float) -> None:
 
 def check_queries(run: Run, dep: Deployment, ref_mod) -> dict:
     """The numbers compared for a query cell."""
-    ref = ref_mod.Reference(ref_mod.concat(dep.tables["lineitem"]))
+    ref = ref_mod.Reference({t: ref_mod.concat(dep.tables[t]) for t in run.config["tables"]})
     done = [r for r in run.records if r.ok]
     cap = run.traffic.get("compare_at_most", len(done))
     pick = sorted(stream(run.seed, 6).permutation(len(done))[:cap]) if len(done) > cap else range(len(done))
@@ -319,8 +326,7 @@ def check_queries(run: Run, dep: Deployment, ref_mod) -> dict:
         key = (r.shape, tuple(sorted(r.params.items())))
         if key not in cache:
             cache[key] = ref.answer(r.shape, r.params)
-        columns, keys = queries.SHAPES[r.shape]
-        w, g = compare.compare_answer(r.answer, cache[key], columns, keys)
+        w, g = run.shapes[r.shape].compare(r.answer, cache[key])
         wrong += w
         gap = max(gap, g)
     return {
@@ -407,7 +413,7 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float, trace: bool,
     cell = bench.cell(workload)
     config = bench.config(cell["config"])
     traffic = bench.traffic(cell["traffic"])
-    run = Run(cell, config, traffic, seed, seconds, trace)
+    run = Run(cell, config, traffic, seed, seconds, trace, shapes=bench.shapes(traffic))
     work = Path(root) / WORK
     shutil.rmtree(work, ignore_errors=True)
     dep = Deployment(config, seed, work)

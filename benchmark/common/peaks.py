@@ -1,9 +1,9 @@
 """Published per-chip peaks, keyed by JAX's ``device_kind``.
 
 Source: Google Cloud documentation, "TPU v5e": 16 GB of HBM at 819 GB/s,
-197 TFLOP/s bf16, 393 TOP/s int8 (the table of ``ops/device_bench.py``,
-PR 21, copied here so that the program cannot change the yardstick). A
-device kind that is not here is an error, never a default.
+197 TFLOP/s bf16, 393 TOP/s int8. The table lives with the benchmark so
+that the program cannot change the yardstick. A device kind that is not
+here is an error, never a default.
 """
 
 from __future__ import annotations

@@ -1,26 +1,37 @@
-"""The query shapes the traffic mixes name, as DataFrames of the program
-(the entry the window drives), with the columns an answer is compared on
-and the key columns it is ordered by. The same shapes, computed without
-the program, are in ``configs/tpch_reference.py``."""
+"""The query shapes the traffic mixes name. Shape ``<name>`` is the file
+``benchmark/shapes/<name>.py``, which ``harness.Bench.shapes`` finds by
+name as it finds the metric readers, and defines:
+
+- ``COLUMNS``: the columns an answer is compared on;
+- ``KEYS``: the columns its rows are ordered by before comparing;
+- ``dataframe(session, paths, params)``: the program's DataFrame for one
+  request (the entry the window drives), ``paths`` holding every table
+  the configuration lists;
+- optionally ``compare(got, want) -> (wrong, gap)``, for an answer whose
+  row order is part of it (``ORDER BY ... LIMIT``); by default
+  ``compare.compare_answer`` over ``COLUMNS``, rows ordered by ``KEYS``.
+
+The same shapes, computed without the program, are in the reference
+module the configuration names."""
 
 from __future__ import annotations
 
-POINT_COLUMNS = ["l_orderkey", "l_partkey", "l_quantity", "l_extendedprice", "l_shipdate"]
-
-# shape -> (columns compared, ordering keys)
-SHAPES = {
-    "point": (POINT_COLUMNS, POINT_COLUMNS),
-}
+from . import compare
 
 
-def dataframe(session, paths: dict, shape: str, params: dict):
-    """The program's DataFrame for one request."""
-    from hyperspace_tpu.plan.expr import col, lit
+class Shape:
+    """One query shape, loaded from its file."""
 
-    li = session.read.parquet(str(paths["lineitem"]))
-    if shape == "point":
-        return li.filter(col("l_orderkey") == lit(int(params["key"]))).select(*POINT_COLUMNS)
-    raise KeyError(f"unknown query shape {shape!r}")
+    def __init__(self, module):
+        self.columns = list(module.COLUMNS)
+        self.keys = list(module.KEYS)
+        self.dataframe = module.dataframe
+        self._compare = getattr(module, "compare", None)
+
+    def compare(self, got: dict, want: dict) -> tuple:
+        if self._compare is not None:
+            return self._compare(got, want)
+        return compare.compare_answer(got, want, self.columns, self.keys)
 
 
 def arrays(batch, columns) -> dict:

@@ -1,6 +1,7 @@
 """The system under test, set up from a configuration file: the TPC-H
-tables written as parquet source files, a session pinned to what the
-configuration states, its covering indexes, and the appended RF1 sets."""
+tables it lists written as parquet source files, a session pinned to
+what the configuration states, its covering indexes, and the appended
+RF1 sets."""
 
 from __future__ import annotations
 
@@ -21,8 +22,9 @@ class Deployment:
 
     # -- data ----------------------------------------------------------------
     def generate(self) -> None:
-        li, od = tpch.tpch_tables(self.config["scale_factor"], self.seed)
-        for name, table in (("lineitem", li), ("orders", od)):
+        """Every table the configuration lists, from the seed."""
+        made = tpch.tables(self.config["tables"], self.config["scale_factor"], self.seed)
+        for name, table in made.items():
             tpch.write_files(table, self.paths[name], self.config["tables"][name]["files"])
             self.tables[name].append(table)
 

@@ -1,5 +1,7 @@
-"""TPC-H ``lineitem`` and ``orders``, every column, shaped after dbgen,
-from a seed.
+"""TPC-H tables, every column, shaped after dbgen, from a seed:
+``lineitem`` and ``orders`` together, ``customer`` and ``part`` each on
+its own seeded stream, so that listing them leaves the first two as
+they are. ``tables`` makes the tables a configuration lists.
 
 ``tpch_tables`` and ``write_files`` started as copies of
 ``chip_smoke.py``'s (PR 21), so that a change to the program cannot
@@ -87,6 +89,12 @@ def _pick(rng, values, n: int, codes=None):
     return pa.array(values).take(pa.array(codes))
 
 
+def retail_price(partkey: np.ndarray) -> np.ndarray:
+    """P_RETAILPRICE of each part key (TPC-H v3 4.2.3), which
+    ``l_extendedprice`` multiplies by the quantity."""
+    return (90_000 + (partkey // 10) % 20_001 + 100 * (partkey % 1_000)) / 100.0
+
+
 def _lines_for(rng, pool, counts, o_orderkey, o_orderdate, n_parts, n_supp):
     """lineitem's 16 columns for orders with ``counts`` lines each, by
     dbgen's rules (TPC-H v3 4.2.3), and the orders' total prices and
@@ -102,8 +110,7 @@ def _lines_for(rng, pool, counts, o_orderkey, o_orderdate, n_parts, n_supp):
     i = rng.integers(0, 4, n_lines)
     l_suppkey = (l_partkey + i * (n_supp // 4 + (l_partkey - 1) // n_supp)) % n_supp + 1
     l_quantity = rng.integers(1, 51, n_lines).astype(np.float64)
-    retail = (90_000 + (l_partkey // 10) % 20_001 + 100 * (l_partkey % 1_000)) / 100.0
-    l_extendedprice = np.round(l_quantity * retail, 2)
+    l_extendedprice = np.round(l_quantity * retail_price(l_partkey), 2)
     l_discount = rng.integers(0, 11, n_lines) / 100.0
     l_tax = rng.integers(0, 9, n_lines) / 100.0
     ordered = np.repeat(o_orderdate, counts)
@@ -230,6 +237,151 @@ def rf1_sets(scale: float, seed: int, n_sets: int):
                          o_orderdate, _scaled(scale, 1_000))
         out.append((pa.table(lines), pa.table(orders)))
     return out
+
+
+# customer's and part's value lists (dists.dss; TPC-H v3 4.2.2.13, 4.2.3)
+SEGMENTS = ["AUTOMOBILE", "BUILDING", "FURNITURE", "MACHINERY", "HOUSEHOLD"]
+COLORS = (
+    "almond antique aquamarine azure beige bisque black blanched blue blush brown burlywood "
+    "burnished chartreuse chiffon chocolate coral cornflower cornsilk cream cyan dark deep dim "
+    "dodger drab firebrick floral forest frosted gainsboro ghost goldenrod green grey honeydew hot "
+    "indian ivory khaki lace lavender lawn lemon light lime linen magenta maroon medium metallic "
+    "midnight mint misty moccasin navajo navy olive orange orchid pale papaya peach peru pink plum "
+    "powder puff purple red rose rosy royal saddle salmon sandy seashell sienna sky slate smoke "
+    "snow spring steel tan thistle tomato turquoise violet wheat white yellow"
+).split()
+TYPES = [f"{a} {b} {c}"
+         for a in ("STANDARD", "SMALL", "MEDIUM", "LARGE", "ECONOMY", "PROMO")
+         for b in ("ANODIZED", "BURNISHED", "PLATED", "POLISHED", "BRUSHED")
+         for c in ("TIN", "NICKEL", "BRASS", "STEEL", "COPPER")]
+CONTAINERS = [f"{a} {b}" for a in ("SM", "LG", "MED", "JUMBO", "WRAP")
+              for b in ("CASE", "BOX", "BAG", "JAR", "PKG", "PACK", "CAN", "DRUM")]
+# dbgen's v-string alphabet: 64 characters
+ALNUM = np.frombuffer(b"0123456789abcdefghijklmnopqrstuvwxyz ABCDEFGHIJKLMNOPQRSTUVWXYZ,", np.uint8)
+
+
+def _digits(values: np.ndarray, width: int = 0):
+    """Each value's decimal digits as a string, zero-padded to ``width``."""
+    import pyarrow as pa
+    import pyarrow.compute as pc
+
+    s = pa.array(values).cast(pa.string())
+    return pc.utf8_lpad(s, width, "0") if width else s
+
+
+def _vstring(rng, n: int, lo: int, hi: int):
+    """dbgen's random v-string [lo, hi] (4.2.2.7): lengths uniform,
+    characters drawn from its 64."""
+    import pyarrow as pa
+
+    offsets = np.zeros(n + 1, np.int64)
+    np.cumsum(rng.integers(lo, hi + 1, n), out=offsets[1:])
+    data = ALNUM[rng.integers(0, len(ALNUM), int(offsets[-1]))]
+    return pa.Array.from_buffers(
+        pa.string(), n, [None, pa.py_buffer(offsets.astype(np.int32)), pa.py_buffer(data.tobytes())])
+
+
+def _phone(rng, nation: np.ndarray):
+    """dbgen's phone number (4.2.2.9): country code nationkey + 10, then
+    three random local groups, as CC-LLL-LLL-LLLL."""
+    import pyarrow.compute as pc
+
+    n = len(nation)
+    return pc.binary_join_element_wise(
+        _digits(nation + 10), _digits(rng.integers(100, 1_000, n)), _digits(rng.integers(100, 1_000, n)),
+        _digits(rng.integers(1_000, 10_000, n)), "-")
+
+
+def _part_names(rng, n: int):
+    """P_NAME: five distinct colors, in the order drawn, space-separated."""
+    import pyarrow as pa
+    import pyarrow.compute as pc
+
+    picks = np.zeros((n, 5), np.int64)
+    for k in range(5):
+        # the v-th color of those not yet picked: step over each earlier
+        # pick, smallest first
+        v = rng.integers(0, len(COLORS) - k, n)
+        for prev in np.sort(picks[:, :k], axis=1).T:
+            v += v >= prev
+        picks[:, k] = v
+    colors = pa.array(COLORS)
+    return pc.binary_join_element_wise(*[colors.take(pa.array(picks[:, k])) for k in range(5)], " ")
+
+
+def customer(scale: float, seed: int):
+    """customer's 8 columns (TPC-H v3 4.2.3): keys 1..SF x 150,000, the
+    range ``o_custkey`` is drawn from."""
+    import pyarrow as pa
+    import pyarrow.compute as pc
+
+    rng = np.random.default_rng(np.random.SeedSequence([seed, 7]))
+    pool = text_pool(rng)
+    n = sizes(scale)[3]
+    key = np.arange(1, n + 1, dtype=np.int64)
+    nation = rng.integers(0, 25, n).astype(np.int64)
+    return pa.table({
+        "c_custkey": key,
+        "c_name": pc.binary_join_element_wise("Customer#", _digits(key, 9), ""),
+        "c_address": _vstring(rng, n, 10, 40),
+        "c_nationkey": nation,
+        "c_phone": _phone(rng, nation),
+        "c_acctbal": rng.integers(-99_999, 1_000_000, n) / 100.0,
+        "c_mktsegment": _pick(rng, SEGMENTS, n),
+        "c_comment": _text(rng, pool, n, 73),
+    })
+
+
+def part(scale: float, seed: int):
+    """part's 9 columns (TPC-H v3 4.2.3): keys 1..SF x 200,000, the range
+    ``l_partkey`` is drawn from; P_RETAILPRICE by the formula that
+    ``l_extendedprice`` multiplies by the quantity."""
+    import pyarrow as pa
+
+    rng = np.random.default_rng(np.random.SeedSequence([seed, 8]))
+    pool = text_pool(rng)
+    n = sizes(scale)[2]
+    key = np.arange(1, n + 1, dtype=np.int64)
+    names = _part_names(rng, n)
+    mfgr = rng.integers(0, 5, n)
+    brand = mfgr * 5 + rng.integers(0, 5, n)
+    return pa.table({
+        "p_partkey": key,
+        "p_name": names,
+        "p_mfgr": _pick(rng, [f"Manufacturer#{m}" for m in range(1, 6)], n, mfgr),
+        "p_brand": _pick(rng, [f"Brand#{m}{b}" for m in range(1, 6) for b in range(1, 6)], n, brand),
+        "p_type": _pick(rng, TYPES, n),
+        "p_size": rng.integers(1, 51, n).astype(np.int32),
+        "p_container": _pick(rng, CONTAINERS, n),
+        "p_retailprice": retail_price(key),
+        "p_comment": _text(rng, pool, n, 14),
+    })
+
+
+def _lineitem_orders(scale: float, seed: int) -> dict:
+    li, od = tpch_tables(scale, seed)
+    return {"lineitem": li, "orders": od}
+
+
+# table -> what makes it, as {table: pyarrow table}, with any table made
+# alongside it
+MAKERS = {
+    "lineitem": _lineitem_orders,
+    "orders": _lineitem_orders,
+    "customer": lambda scale, seed: {"customer": customer(scale, seed)},
+    "part": lambda scale, seed: {"part": part(scale, seed)},
+}
+
+
+def tables(names, scale: float, seed: int) -> dict:
+    """{table: pyarrow table} of the TPC-H tables ``names``, in that order."""
+    made = {}
+    for name in names:
+        if name not in made:
+            if name not in MAKERS:
+                raise KeyError(f"no generator for TPC-H table {name!r} (have {sorted(MAKERS)})")
+            made.update(MAKERS[name](scale, seed))
+    return {name: made[name] for name in names}
 
 
 def write_files(table, directory: Path, n_files: int, prefix: str = "part") -> list:

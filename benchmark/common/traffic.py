@@ -7,7 +7,16 @@ parameter is a literal or a draw:
 
 - ``{"zipf": theta, "over": "table.column"}``: a key drawn Zipf(theta)
   over the distinct values of the column present in the table (base and
-  appended), popularity ranked by a permutation seeded by the run's seed.
+  appended), popularity ranked by a permutation seeded by the run's seed;
+- ``{"choice": [v, ...]}``: one of the values, uniformly (TPC-H's
+  substitution parameters picked from a list, such as Q3's SEGMENT);
+- ``{"uniform": [lo, hi]}``: an integer uniformly in lo..hi, both
+  included; a pair of ISO dates (``"1995-03-01"``) gives a day, as the
+  number of days since 1970-01-01, the representation of a date in the
+  shapes and the references (Q3's DATE).
+
+Every draw of a client comes from its own seeded generator, so a
+client's requests repeat from the seed.
 
 Each client cycles through ``requests``, client c starting at request
 c, so that the clients start on different shapes.
@@ -61,6 +70,12 @@ class Stream:
             return spec
         if "zipf" in spec:
             return self._zipf_key(spec)
+        if "choice" in spec:
+            values = spec["choice"]
+            return values[int(self.rng.integers(len(values)))]
+        if "uniform" in spec:
+            lo, hi = (_day(v) if isinstance(v, str) else int(v) for v in spec["uniform"])
+            return int(self.rng.integers(lo, hi + 1))
         raise KeyError(f"unknown parameter draw {spec!r}")
 
     def __iter__(self):
@@ -70,6 +85,11 @@ class Stream:
         r = self.requests[self.i % len(self.requests)]
         self.i += 1
         return r["shape"], {k: self._draw(v) for k, v in r.get("params", {}).items()}
+
+
+def _day(iso: str) -> int:
+    """Days since 1970-01-01 of an ISO date."""
+    return int(np.datetime64(iso, "D").astype(np.int64))
 
 
 def key_columns(mix: dict) -> set:

@@ -38,8 +38,11 @@ def concat(tables) -> dict:
 
 
 class Reference:
-    def __init__(self, lineitem: dict, dtype=np.float64):
-        self.li = {k: v.astype(dtype) if v.dtype.kind == "f" else v for k, v in lineitem.items()}
+    """The query shapes over ``tables`` ({table: {column: array}}, every
+    table the configuration lists); the point lookup reads ``lineitem``."""
+
+    def __init__(self, tables: dict, dtype=np.float64):
+        self.li = {k: v.astype(dtype) if v.dtype.kind == "f" else v for k, v in tables["lineitem"].items()}
         self.dtype = dtype
         self._li_order = None
 

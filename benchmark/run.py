@@ -11,6 +11,7 @@ or fewer chips than the cell asks for.
 from __future__ import annotations
 
 import argparse
+import ctypes
 import importlib.util
 import json
 import os
@@ -19,6 +20,20 @@ import time
 from pathlib import Path
 
 ROOT = Path(__file__).resolve().parent.parent
+# glibc's mallopt parameters a config may pin under "malloc"
+MALLOPT = {"trim_threshold": -1, "mmap_threshold": -3}
+
+
+def pin_malloc(params: dict) -> None:
+    """Fix the glibc allocator thresholds that the config names. A fixed
+    mmap threshold also stops glibc from moving it as large buffers are
+    freed, which leaves each process mapping and trimming alike."""
+    if not params:
+        return
+    libc = ctypes.CDLL("libc.so.6")
+    for key, value in params.items():
+        if libc.mallopt(MALLOPT[key], int(value)) != 1:
+            raise RuntimeError(f"mallopt refused {key}={value}")
 
 
 def main(argv=None) -> int:
@@ -44,6 +59,7 @@ def main(argv=None) -> int:
     os.environ["JAX_COMPILATION_CACHE_DIR"] = str(ROOT / ".jax_cache")
     for k, v in config.get("env", {}).items():
         os.environ[k] = str(v)
+    pin_malloc(config.get("malloc", {}))
 
     import jax
 
