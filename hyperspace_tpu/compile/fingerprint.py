@@ -33,10 +33,12 @@ from ..plan.ir import (
     Filter,
     IndexScan,
     Join,
+    Limit,
     LogicalPlan,
     Project,
     Repartition,
     Scan,
+    Sort,
     Union,
 )
 
@@ -95,8 +97,14 @@ def _node_sig(n: LogicalPlan) -> Tuple:
         return (
             "A",
             tuple(n.group_by),
-            tuple((a.fn, a.column, a.name) for a in n.aggs),
+            # an expression's literals are part of the program, not
+            # operands: its repr keeps them
+            tuple((a.fn, a.column, a.name, repr(a.expr)) for a in n.aggs),
         )
+    if isinstance(n, Sort):
+        return ("O", tuple(n.keys), tuple(n.ascending))
+    if isinstance(n, Limit):
+        return ("L", n.n)
     if isinstance(n, BucketUnion):
         cols, nb = n.bucket_spec
         return ("BU", tuple(cols), nb)

@@ -111,6 +111,10 @@ def classify_shape(plan: LogicalPlan, mesh=None) -> Shape:
         projects.append(node)
         node = node.child
     if isinstance(node, Aggregate):
+        if any(a.expr is not None for a in node.aggs):
+            # an expression input is computed by the interpreter's
+            # hash_aggregate; the fused pipelines read columns
+            return Shape("interpret")
         inner = node.child
         inner_projects: List[Project] = []
         while isinstance(inner, Project):

@@ -8,7 +8,8 @@ is emitted exactly when a rewrite fired (HyperspaceEvent.scala:150-156).
 
 from __future__ import annotations
 
-from typing import List
+from dataclasses import dataclass
+from typing import List, Union
 
 from .exceptions import HyperspaceException
 from .plan.expr import Expr
@@ -17,6 +18,22 @@ from .session import HyperspaceSession
 from .storage.columnar import ColumnarBatch
 from .telemetry import HyperspaceIndexUsageEvent
 from .telemetry.logging import EventLogging
+
+
+@dataclass(frozen=True)
+class SortOrder:
+    """One ORDER BY key: a column and its direction (``asc``/``desc``)."""
+
+    column: str
+    ascending: bool = True
+
+
+def asc(column: str) -> SortOrder:
+    return SortOrder(column, True)
+
+
+def desc(column: str) -> SortOrder:
+    return SortOrder(column, False)
 
 
 class DataFrame(EventLogging):
@@ -83,6 +100,33 @@ class DataFrame(EventLogging):
         return GroupedData(self, tuple(resolved))
 
     groupBy = group_by
+
+    def order_by(self, *keys: Union[str, SortOrder]) -> "DataFrame":
+        """ORDER BY: each key a column name (ascending) or ``asc(c)`` /
+        ``desc(c)``."""
+        from .plan.ir import Sort
+        from .utils import resolver
+
+        if not keys:
+            raise HyperspaceException("order_by() needs at least one key.")
+        out = self.plan.output_columns()
+        names, ascending = [], []
+        for k in keys:
+            k = k if isinstance(k, SortOrder) else SortOrder(k)
+            match = resolver.resolve(k.column, out)
+            if match is None:
+                raise HyperspaceException(f"Unknown sort column: {k.column}.")
+            names.append(match)
+            ascending.append(k.ascending)
+        return DataFrame(self.session, Sort(tuple(names), tuple(ascending), self.plan))
+
+    orderBy = order_by
+
+    def limit(self, n: int) -> "DataFrame":
+        """LIMIT: the first ``n`` rows."""
+        from .plan.ir import Limit
+
+        return DataFrame(self.session, Limit(int(n), self.plan))
 
     # -- actions -------------------------------------------------------------
     def optimized_plan(self, log_usage: bool = False) -> LogicalPlan:
@@ -217,7 +261,10 @@ class GroupedData:
         self._group_by = group_by
 
     def agg(self, *specs) -> DataFrame:
-        from .plan.aggregates import AggSpec, validate_specs
+        from dataclasses import replace as dc_replace
+
+        from .plan.aggregates import AggSpec, input_dtype, validate_specs
+        from .plan.expr import resolve_expr_columns
         from .plan.ir import Aggregate
         from .utils import resolver
 
@@ -234,9 +281,10 @@ class GroupedData:
                     raise HyperspaceException(
                         f"Unknown aggregate column: {s.column}."
                     )
-                from dataclasses import replace as dc_replace
-
                 s = dc_replace(s, column=match)
+            if s.expr is not None:
+                s = dc_replace(s, expr=resolve_expr_columns(s.expr, out))
+                input_dtype(s, self._df.plan.output_schema())  # raises if unknown
             resolved.append(s)
         validate_specs(tuple(resolved), self._group_by)
         return DataFrame(

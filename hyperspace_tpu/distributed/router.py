@@ -101,13 +101,13 @@ def _partial_specs(aggs: List[AggSpec]) -> List[AggSpec]:
     out: List[AggSpec] = []
     for a in aggs:
         if a.fn == "count":
-            out.append(AggSpec("count", a.column, f"__pc_{a.name}"))
+            out.append(AggSpec("count", a.column, f"__pc_{a.name}", a.expr))
         elif a.fn in ("sum", "avg"):
-            out.append(AggSpec("sum", a.column, f"__ps_{a.name}"))
-            out.append(AggSpec("count", a.column, f"__pn_{a.name}"))
+            out.append(AggSpec("sum", a.column, f"__ps_{a.name}", a.expr))
+            out.append(AggSpec("count", a.column, f"__pn_{a.name}", a.expr))
         elif a.fn in ("min", "max"):
             tag = "m" if a.fn == "min" else "M"
-            out.append(AggSpec(a.fn, a.column, f"__p{tag}_{a.name}"))
+            out.append(AggSpec(a.fn, a.column, f"__p{tag}_{a.name}", a.expr))
         else:
             raise HyperspaceException(f"Unsupported router aggregate {a.fn}.")
     return out

@@ -125,17 +125,40 @@ def _segment_minmax(
     return Column(col.dtype_str, out)
 
 
+def materialize_agg_inputs(batch: ColumnarBatch, aggs: Sequence[AggSpec]):
+    """(batch, aggs) with each expression input (``AggSpec.expr``)
+    computed on the host into a column of its own and the spec pointed at
+    it; specs over columns pass through unchanged."""
+    from dataclasses import replace
+
+    from ..plan.expr import eval_value, value_dtype
+
+    if not any(a.expr is not None for a in aggs):
+        return batch, list(aggs)
+    out, specs = dict(batch.columns), []
+    schema = batch.schema()
+    for a in aggs:
+        if a.expr is None:
+            specs.append(a)
+            continue
+        name = f"\x00agg:{a.name}"
+        out[name] = Column(value_dtype(a.expr, schema), eval_value(a.expr, batch))
+        specs.append(replace(a, column=name, expr=None))
+    return ColumnarBatch(out), specs
+
+
 @metrics.timer("aggregate.total")
 def hash_aggregate(
     batch: ColumnarBatch,
     group_by: Sequence[str],
     aggs: Sequence[AggSpec],
 ) -> ColumnarBatch:
-    schema = batch.schema()
-    missing = [c for c in list(group_by) + [a.column for a in aggs if a.column]
-               if c not in schema]
+    missing = [c for c in list(group_by) + [c for a in aggs for c in a.input_columns()]
+               if c not in batch.columns]
     if missing:
         raise HyperspaceException(f"Aggregate references unknown columns {missing}.")
+    batch, aggs = materialize_agg_inputs(batch, aggs)
+    schema = batch.schema()
     n = batch.num_rows
     if not group_by:
         # global aggregate: one group covering every row (n=0 → one group

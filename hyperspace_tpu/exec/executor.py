@@ -29,10 +29,12 @@ from ..plan.ir import (
     Filter,
     IndexScan,
     Join,
+    Limit,
     LogicalPlan,
     Project,
     Repartition,
     Scan,
+    Sort,
     Union,
 )
 from ..plan.rules.join_rule import align_condition_sides, extract_equi_condition
@@ -229,6 +231,18 @@ class Executor:
             return self._exec_join(plan)
         if isinstance(plan, Aggregate):
             return self._exec_aggregate(plan, predicate)
+        if isinstance(plan, Limit):
+            from .order import limit_batch
+
+            out = self._try_join_topk(plan) if self.mesh is not None else None
+            if out is None:
+                out = limit_batch(self._exec(plan.child, None), plan.n)
+            return self._apply_predicate(out, predicate)
+        if isinstance(plan, Sort):
+            from .order import sort_batch
+
+            out = sort_batch(self._exec(plan.child, None), plan.keys, plan.ascending)
+            return self._apply_predicate(out, predicate)
         if isinstance(plan, Union):
             return self._exec_union(plan, predicate, columns)
         if isinstance(plan, (BucketUnion, Repartition)):
@@ -250,11 +264,14 @@ class Executor:
         so lowering can never reorder the arm preference."""
         from .aggregate import hash_aggregate
 
-        if self.mesh is not None:
+        # the fused arms read their inputs as resident or bucketed
+        # columns; an expression input is computed by hash_aggregate
+        fusable = not any(a.expr is not None for a in plan.aggs)
+        if fusable and self.mesh is not None:
             fused = self._try_distributed_aggregate(plan)
             if fused is not None:
                 return self._apply_predicate(fused, predicate)
-        fused = self._try_join_aggregate(plan)
+        fused = self._try_join_aggregate(plan) if fusable else None
         if fused is not None:
             return self._apply_predicate(fused, predicate)
         need = plan.input_columns()
@@ -891,6 +908,52 @@ class Executor:
         from .scan_gate import scan_gate
 
         scan_gate.note_resident_bypass("join")
+        return out
+
+    def _try_join_topk(self, plan: Limit) -> Optional[ColumnarBatch]:
+        """The mesh arm of ``Limit(Sort(Aggregate(Join...)))`` — TPC-H
+        Q3's filtered join-aggregate top-k (exec.join_residency): each
+        chip ranks the groups of the buckets it owns and only its best
+        candidates come home. None routes the exact host path: another
+        shape, a decline (counted ``hbm.mesh.join_topk.declined.<why>``),
+        a region not yet resident (scheduled here, for the next query),
+        or a device failure (the region is dropped)."""
+        from ..telemetry.metrics import metrics
+        from .hbm_cache import residency_mode
+        from .join_residency import match_join_topk
+        from .mesh_cache import mesh_cache
+
+        shape, why = match_join_topk(plan)
+        if shape is None:
+            if why:
+                metrics.incr(f"hbm.mesh.join_topk.declined.{why}")
+            return None
+        if residency_mode() == "off":
+            metrics.incr("hbm.mesh.join_topk.declined.residency")
+            return None
+        region = mesh_cache.topk_for(shape, self.mesh)
+        if region is None:
+
+            def loader():
+                dim = None
+                if shape.dim_scan is not None:
+                    dim = self._exec(shape.dim_scan, None, list(shape.columns("d")))
+                return (
+                    self._load_index_by_bucket(shape.l_node, None),
+                    self._load_index_by_bucket(shape.o_node, None),
+                    dim,
+                )
+
+            mesh_cache.note_touch_topk(shape, loader, self.mesh)
+            return None
+        try:
+            out = mesh_cache.join_topk(region, shape)
+        except Exception:  # noqa: BLE001 - device loss degrades to host
+            mesh_cache.drop(region)
+            metrics.incr("scan.resident_join_topk.device_failed")
+            return None
+        if out is not None:
+            metrics.incr("scan.path.resident_join_topk_mesh")
         return out
 
     def _note_join_touch(self, res, left_plan, right_plan, payload) -> None:

@@ -59,6 +59,7 @@ Env knobs (module-level, matching the scan gate's style):
 
 from __future__ import annotations
 
+import atexit
 import os
 import threading
 import time
@@ -800,6 +801,19 @@ def _hybrid_batched_counts_fn(
     return fn
 
 
+# the background populate threads of both residency caches (one registry:
+# wait_background joins them all; joined at exit once the first starts)
+_BG_LOCK = threading.Lock()
+_BG_THREADS: list = []
+
+
+def _join_bg() -> None:
+    with _BG_LOCK:
+        threads = list(_BG_THREADS)
+    for t in threads:
+        t.join(30.0)
+
+
 class ResidentCacheBase:
     """Shared plumbing of the single-chip and mesh resident caches: table
     registry + LRU-against-budget, pending/failed population memos, and
@@ -1068,15 +1082,13 @@ class ResidentCacheBase:
             metrics.incr(f"{self._metric_prefix}.delta.registered")
 
     def wait_background(self, timeout_s: float = 30.0) -> None:
-        """Join in-flight background populations (tables AND deltas) —
-        benches, the multichip dryrun and tests need deterministic
-        residency after scheduling a first touch."""
-        with self._lock:
-            threads = [
-                t
-                for t in getattr(self, "_bg_threads", ())
-                if t.is_alive()
-            ]
+        """Join in-flight background populations (tables, deltas and join
+        regions) of BOTH caches — benches, the multichip dryrun and tests
+        need deterministic residency after scheduling a first touch, and
+        a caller holding the single-chip cache need not know whether its
+        session populates the mesh twin."""
+        with _BG_LOCK:
+            threads = [t for t in _BG_THREADS if t.is_alive()]
         for t in threads:
             t.join(timeout_s)
 
@@ -1134,21 +1146,11 @@ class ResidentCacheBase:
         shutdown races the jax runtime's teardown; joining live uploads
         at exit keeps teardown clean (same rationale as the scan gate's
         probe join)."""
-        with self._lock:
-            threads = getattr(self, "_bg_threads", None)
-            if threads is None:
-                threads = self._bg_threads = []
-                import atexit
-
-                atexit.register(self._join_bg)
-            threads[:] = [x for x in threads if x.is_alive()]
-            threads.append(t)
-
-    def _join_bg(self) -> None:
-        with self._lock:
-            threads = list(getattr(self, "_bg_threads", ()))
-        for t in threads:
-            t.join(30.0)
+        with _BG_LOCK:
+            if not _BG_THREADS:  # the first thread: never empty after it
+                atexit.register(_join_bg)
+            _BG_THREADS[:] = [x for x in _BG_THREADS if x.is_alive()]
+            _BG_THREADS.append(t)
 
     def reset(self) -> None:
         with self._lock:

@@ -60,6 +60,7 @@ from typing import Dict, Optional, Tuple
 
 import numpy as np
 
+from ..exceptions import HyperspaceException
 from ..plan.ir import (
     BucketUnion,
     Filter,
@@ -940,12 +941,12 @@ def ranges_fn():
         import jax
         import jax.numpy as jnp
 
-        def fn(l_codes, r_codes):
+        def resident_join_ranges(l_codes, r_codes):
             lo = jnp.searchsorted(r_codes, l_codes, side="left")
             hi = jnp.searchsorted(r_codes, l_codes, side="right")
             return lo.astype(jnp.int32), (hi - lo).astype(jnp.int32)
 
-        _RANGES_FN = jax.jit(fn)
+        _RANGES_FN = jax.jit(resident_join_ranges)
     return _RANGES_FN
 
 
@@ -965,20 +966,21 @@ def ranges_fn_packed(r_pack):
 
     from ..ops.bitpack import unpack_for_jnp
 
-    def body(l_codes, r_words, r_refs):
+    def resident_join_ranges(l_codes, r_words, r_refs):
         r_codes = unpack_for_jnp(r_words, r_refs, r_pack)
         lo = jnp.searchsorted(r_codes, l_codes, side="left")
         hi = jnp.searchsorted(r_codes, l_codes, side="right")
         return lo.astype(jnp.int32), (hi - lo).astype(jnp.int32)
 
-    fn = jax.jit(body)
+    fn = jax.jit(resident_join_ranges)
     memo.put(key, fn)
     return fn
 
 
 def join_agg_fn(plan: AggPlan, n_l: int, n_r: int, r_pack=None):
-    """Jitted fused join-aggregate for the single-chip cache, memoized
-    on the plan STRUCTURE + shapes (hbm_cache compile-cache discipline).
+    """Jitted fused join-aggregate for the single-chip cache (profile
+    name ``jit_resident_join_agg``), memoized on the plan STRUCTURE +
+    shapes (hbm_cache compile-cache discipline).
     With ``r_pack`` set the signature grows a refs operand and the FoR
     decode fuses ahead of the sorted-intersection (ranges_fn_packed
     rationale)."""
@@ -997,7 +999,7 @@ def join_agg_fn(plan: AggPlan, n_l: int, n_r: int, r_pack=None):
     if r_pack is not None:
         from ..ops.bitpack import unpack_for_jnp
 
-        def body(l_codes, r_words, r_refs, slots, flat):
+        def resident_join_agg(l_codes, r_words, r_refs, slots, flat):
             r_codes = unpack_for_jnp(r_words, r_refs, r_pack)
             outs, _ = _core_agg(
                 jnp, jax, specs, span, uniq, l_codes, r_codes, slots, flat
@@ -1006,20 +1008,20 @@ def join_agg_fn(plan: AggPlan, n_l: int, n_r: int, r_pack=None):
 
     else:
 
-        def body(l_codes, r_codes, slots, flat):
+        def resident_join_agg(l_codes, r_codes, slots, flat):
             outs, _ = _core_agg(
                 jnp, jax, specs, span, uniq, l_codes, r_codes, slots, flat
             )
             return tuple(outs)
 
-    fn = jax.jit(body)
+    fn = jax.jit(resident_join_agg)
     memo.put(key, fn)
     return fn
 
 
 def mesh_join_agg_fn(mesh, plan: AggPlan, cap_l: int, cap_r: int):
-    """Jitted shard_map fused join-aggregate: per-device partials over
-    the full slot space, then psum/pmin/pmax into ONE replicated group
+    """Jitted shard_map fused join-aggregate (profile name
+    ``jit_mesh_join_agg``): per-device partials over the full slot space, then psum/pmin/pmax into ONE replicated group
     table — the two-phase distributed aggregate with zero shuffles."""
     key = ("jaggM", mesh, plan.signature(), cap_l, cap_r)
     memo = _fn_cache()
@@ -1064,15 +1066,18 @@ def mesh_join_agg_fn(mesh, plan: AggPlan, cap_l: int, cap_r: int):
     in_specs = (p_dev, p_dev, p_dev, tuple(p_dev for _ in range(n_flat)))
     n_out = 1 + sum(len(c.ops) for c in plan.cols)
     out_specs = tuple(PartitionSpec() for _ in range(n_out))
-    fn = jax.jit(
-        shard_map(
-            shard_fn,
-            mesh=mesh,
-            in_specs=in_specs,
-            out_specs=out_specs,
-            check_vma=False,
-        )
+    sharded = shard_map(
+        shard_fn,
+        mesh=mesh,
+        in_specs=in_specs,
+        out_specs=out_specs,
+        check_vma=False,
     )
+
+    def mesh_join_agg(*args):
+        return sharded(*args)
+
+    fn = jax.jit(mesh_join_agg)
     memo.put(key, fn)
     return fn
 
@@ -1136,3 +1141,698 @@ def finish_join_agg(region, plan: AggPlan, group_by, aggs, outs) -> ColumnarBatc
                 vals = np.where(nn_k == 0, np.nan, vals)
             out[a.name] = Column(dt, vals.astype(numpy_dtype(dt)))
     return ColumnarBatch(out)
+
+
+# ---------------------------------------------------------------------------
+# the filtered join-aggregate top-k (the mesh arm of ORDER BY ... LIMIT)
+# ---------------------------------------------------------------------------
+# TPC-H Q3's shape: Limit(Sort(Aggregate(Join(Join(O, L), dim)))) over a
+# bucketed pair of covering indexes (O's join key unique, L the many
+# side), a dimension side joined to a foreign key of the pair, a filter on
+# every side, sums over L per join key, ORDER BY the first sum and LIMIT
+# n. Every group is one O row, which lives whole on the chip that owns its
+# bucket (b % D), so each chip ranks its own groups and only its best
+# candidates leave it. The join's structure is static between index
+# versions: the region holds each O row's range of L rows, and a query
+# computes only its masks, sums and top-k.
+
+TOPK_SLACK = 8  # candidates a chip ships beyond the limit (ties on device)
+_MAX_SCALE = 9  # decimal places a DECIMAL column may carry exactly
+_SUM_BOUND = 1 << 62
+
+
+@dataclass(frozen=True)
+class TopkShape:
+    """What a ``Limit(Sort(Aggregate(...)))`` plan asks of the arm."""
+
+    l_node: object  # IndexScan of the many side
+    l_pred: object  # Expr or None
+    o_node: object  # IndexScan of the side whose join key is unique
+    o_pred: object
+    l_key: str
+    o_key: str
+    dim_scan: object  # Scan of the dimension side, or None
+    dim_pred: object
+    dim_key: Optional[str]
+    fk: Optional[str]  # the pair's column the dimension key joins
+    fk_side: str  # "l" | "o"
+    group_by: tuple
+    key_group: str  # the group column that is the join key
+    aggs: tuple
+    sort_keys: tuple
+    ascending: tuple
+    n: int
+
+    def columns(self, side: str) -> tuple:
+        """The columns the region holds for ``side`` ("l", "o", "d")."""
+        if side == "d":
+            need = [self.dim_key, *sorted(self.dim_pred.columns() if self.dim_pred else ())]
+        elif side == "l":
+            need = sorted(self.l_pred.columns()) if self.l_pred else []
+            need += [c for a in self.aggs for c in a.input_columns()]
+        else:
+            need = sorted(self.o_pred.columns()) if self.o_pred else []
+            need += [g for g in self.group_by if g != self.key_group]
+        if self.fk is not None and side == self.fk_side:
+            need.append(self.fk)
+        return tuple(dict.fromkeys(need))
+
+    def region_key(self) -> tuple:
+        dim = ()
+        if self.dim_scan is not None:
+            dim = tuple((f.name, f.size, f.modified_time) for f in self.dim_scan.relation.files)
+        # a join region's key (so refresh invalidation and the registry
+        # read it alike) and what the arm holds besides
+        return join_region_key(
+            self.l_node.entry.content.files(), self.o_node.entry.content.files(),
+            [self.l_key], [self.o_key],
+        ) + (("topk", dim, self.dim_key, self.fk, self.fk_side,
+              self.columns("l"), self.columns("o"), self.columns("d") if self.dim_scan else ()),)
+
+
+def _filtered_side(plan):
+    """(leaf, predicate) of ``[Project|Filter]* leaf``, the filters
+    conjoined; the leaf an IndexScan or Scan."""
+    from ..plan.expr import And
+
+    pred, node = None, plan
+    while isinstance(node, (Project, Filter)):
+        if isinstance(node, Filter):
+            pred = node.condition if pred is None else And(pred, node.condition)
+        node = node.child
+    return node, pred
+
+
+def match_join_topk(limit):
+    """(TopkShape, "") when ``limit`` is the arm's shape, else (None,
+    why): why is "" for a plan of another shape, else the reason a
+    Limit(Sort(Aggregate(Join))) plan cannot ride (counted by the
+    caller)."""
+    from ..plan.ir import Aggregate, IndexScan as _IS, Join as _J, Scan, Sort
+    from ..plan.rules.join_rule import align_condition_sides, extract_equi_condition
+
+    sort = limit.child
+    if not isinstance(sort, Sort) or not isinstance(sort.child, Aggregate):
+        return None, ""
+    agg = sort.child
+    outer = agg.child
+    while isinstance(outer, Project):
+        outer = outer.child
+    if not isinstance(outer, _J):
+        return None, ""
+    if outer.join_type != "inner":
+        return None, "shape"
+
+    def strip(p):
+        while isinstance(p, Project):
+            p = p.child
+        return p
+
+    pair, dim, dim_pairs = outer, None, None
+    if isinstance(strip(outer.left), _J) or isinstance(strip(outer.right), _J):
+        left_pair = isinstance(strip(outer.left), _J)
+        pair_side, dim = (outer.left, outer.right) if left_pair else (outer.right, outer.left)
+        pair = strip(pair_side)
+        if isinstance(strip(dim), _J) or pair.join_type != "inner":
+            return None, "shape"
+        dim_pairs = extract_equi_condition(outer.condition)
+        dim_pairs = dim_pairs and align_condition_sides(
+            dim_pairs, dim.output_columns(), pair_side.output_columns())
+        if not dim_pairs or len(dim_pairs) != 1:
+            return None, "condition"
+    keys = extract_equi_condition(pair.condition)
+    keys = keys and align_condition_sides(keys, pair.left.output_columns(), pair.right.output_columns())
+    if not keys or len(keys) != 1:
+        return None, "condition"
+    (xs, xp), (ys, yp) = _filtered_side(pair.left), _filtered_side(pair.right)
+    if not all(isinstance(s, _IS) and s.use_bucket_spec for s in (xs, ys)):
+        return None, "shape"
+    if xs.entry.num_buckets != ys.entry.num_buckets:
+        return None, "buckets"
+    xk, yk = keys[0]
+    if [c.lower() for c in xs.entry.indexed_columns] != [xk.lower()] or [
+        c.lower() for c in ys.entry.indexed_columns
+    ] != [yk.lower()]:
+        return None, "keys"
+    d_scan, d_pred = (None, None) if dim is None else _filtered_side(dim)
+    if dim is not None and not isinstance(d_scan, Scan):
+        return None, "shape"
+    # roles: the sums read L; the other group columns are O's
+    group_by = tuple(agg.group_by)
+    key_group = next((g for g in group_by if g.lower() in (xk.lower(), yk.lower())), None)
+    if key_group is None:
+        return None, "group"
+    if any(a.fn not in ("sum", "count") or (a.fn == "count" and a.input_columns())
+           for a in agg.aggs):
+        return None, "agg"
+    agg_cols = {c.lower() for a in agg.aggs for c in a.input_columns()}
+    other = {g.lower() for g in group_by if g != key_group}
+    def cols(scan):
+        return {c.lower() for c in scan.required_columns}
+
+    roles = None
+    for (ls, lp, lk), (os_, op, ok) in (((xs, xp, xk), (ys, yp, yk)), ((ys, yp, yk), (xs, xp, xk))):
+        if agg_cols <= cols(ls) and other <= cols(os_):
+            roles = (ls, lp, lk, os_, op, ok)
+            break
+    if roles is None:
+        return None, "group"
+    ls, lp, lk, os_, op, ok = roles
+    fk, fk_side, dim_key = None, "o", None
+    if dim_pairs:
+        dim_key, fk = dim_pairs[0]
+        fk_side = "o" if fk.lower() in cols(os_) else "l"
+    if sort.keys[0] not in [a.name for a in agg.aggs]:
+        return None, "order"
+    if limit.n < 1:
+        return None, "limit"
+    return TopkShape(
+        ls, lp, os_, op, lk, ok, d_scan, d_pred, dim_key, fk, fk_side, group_by, key_group,
+        tuple(agg.aggs), tuple(sort.keys), tuple(sort.ascending), int(limit.n),
+    ), ""
+
+
+@dataclass
+class TopkColumn:
+    """One resident column of a top-k region: ``array`` is int32 where
+    the values fit, else int64; a DECIMAL (float64) column is its exact
+    value times 10**scale as int64, verified when the region is built.
+    ``bound`` is the largest magnitude of those integers."""
+
+    array: object
+    dtype_str: str
+    scale: int
+    bound: int
+
+
+@dataclass
+class MeshTopkRegion:
+    """The top-k arm's resident state on the mesh (join-region registry:
+    shared budget, eviction, invalidation). Per chip d, over the buckets
+    it owns: L's columns in join-key order, each O row's range [lo, hi)
+    of L rows, O's columns; the dimension side on every chip."""
+
+    key: tuple
+    mesh: object
+    n_devices: int
+    cap_l: int
+    cap_o: int
+    l_cols: Dict[str, TopkColumn]
+    r_cols: Dict[str, TopkColumn]  # O's, the join key as well (a join region's right side)
+    lo: object
+    hi: object
+    max_fanout: int  # most L rows one O row matches
+    dim_mode: str  # "dense" | "sorted" | "none"
+    dim_index: object  # dense: row of key - mn (-1 absent); sorted: the keys; none: 1 slot
+    dim_cols: Dict[str, TopkColumn]
+    dim_vocab: Dict[str, object]  # host vocab of each string dimension column
+    dim_min: int
+    dim_rows: int
+    dim_cap: int  # the dimension columns' padded length
+    n_l: int = 0
+    n_r: int = 0
+    nbytes: int = 0
+    last_used: float = field(default_factory=time.monotonic)
+
+
+def _decimal_scale(values: np.ndarray) -> Optional[int]:
+    """The fewest decimal places that hold every float64 exactly: values
+    equal to round(v * 10**s) / 10**s; None for NaN (NULL), infinities or
+    more than _MAX_SCALE places."""
+    if len(values) and not np.isfinite(values).all():
+        return None
+    for s in range(_MAX_SCALE + 1):
+        scaled = np.round(values * 10.0**s)
+        if np.abs(scaled).max(initial=0) >= 2**53:
+            return None
+        if np.array_equal(scaled / 10.0**s, values):
+            return s
+    return None
+
+
+def _topk_encode(col: Column, decimal: bool):
+    """(host int array, scale, bound) of one column, or None (why)."""
+    a = col.data
+    if is_string(col.dtype_str):
+        return None
+    if a.dtype.kind == "f":
+        if not decimal:
+            return None
+        s = _decimal_scale(a.astype(np.float64))
+        if s is None:
+            return None
+        ints = np.round(a.astype(np.float64) * 10.0**s).astype(np.int64)
+    elif a.dtype.kind in "iub":
+        s, ints = 0, a.astype(np.int64)
+    else:
+        return None
+    bound = int(np.abs(ints).max(initial=0))
+    if bound <= I32_MAX:
+        ints = ints.astype(np.int32)
+    return ints, s, bound
+
+
+def build_topk_region(cache, shape: TopkShape, l_groups, o_groups, dim_batch, mesh):
+    """(region, why) — why names a refusal: "not_unique" (O's join key or
+    the dimension key repeats), "encoding" (a column cannot ride exactly),
+    "budget", "device"; "" with a region."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    import jax
+
+    from ..parallel.mesh import owner_of_bucket
+    from ..utils.deviceprobe import first_device_touch_ok
+    from ..utils.intmath import next_pow2
+    from .hbm_cache import _budget_bytes, residency_mode
+
+    pfx = cache._metric_prefix
+    if not first_device_touch_ok(required=residency_mode() == "force"):
+        metrics.incr(f"{pfx}.device_unreachable")
+        return None, "device"
+    t0 = time.perf_counter()
+    D = int(mesh.devices.size)
+
+    def per_device(groups, names):
+        out = []
+        for d in range(D):
+            parts = [groups[b].select(list(names)) for b in sorted(groups)
+                     if owner_of_bucket(int(b), D) == d and groups[b].num_rows]
+            out.append(ColumnarBatch.concat(parts) if parts else None)
+        return out
+
+    l_names = list(dict.fromkeys([shape.l_key, *shape.columns("l")]))
+    o_names = list(dict.fromkeys([shape.o_key, *shape.columns("o")]))
+    l_dev, o_dev = per_device(l_groups, l_names), per_device(o_groups, o_names)
+    l_rows = [0 if b is None else b.num_rows for b in l_dev]
+    o_rows = [0 if b is None else b.num_rows for b in o_dev]
+    k = shape.n + TOPK_SLACK
+    cap_l = next_pow2(max(max(l_rows), 1))
+    cap_o = next_pow2(max(max(o_rows), k, 1))
+    decimals = {c for a in shape.aggs for c in a.input_columns()}
+
+    lo = np.zeros((D, cap_o), np.int32)
+    hi = np.zeros((D, cap_o), np.int32)
+    # per chip: each side's rows in join-key order, and each O row's
+    # range of L rows
+    l_sorted, o_sorted = [], []
+    fanout = 0
+    for d in range(D):
+        lb, ob = l_dev[d], o_dev[d]
+        lk = np.zeros(0, np.int64) if lb is None else lb.columns[shape.l_key].data.astype(np.int64)
+        lorder = np.argsort(lk, kind="stable")
+        l_sorted.append(None if lb is None else lb.take(lorder))
+        if ob is None:
+            o_sorted.append(None)
+            continue
+        ok = ob.columns[shape.o_key].data.astype(np.int64)
+        oorder = np.argsort(ok, kind="stable")
+        ok = ok[oorder]
+        if len(ok) > 1 and not (np.diff(ok) > 0).all():
+            return None, "not_unique"
+        o_sorted.append(ob.take(oorder))
+        lo[d, : len(ok)] = np.searchsorted(lk[lorder], ok, "left")
+        hi[d, : len(ok)] = np.searchsorted(lk[lorder], ok, "right")
+        fanout = max(fanout, int((hi[d] - lo[d]).max(initial=0)))
+
+    def encode_side(batches, names, cap, decimal_cols):
+        """{name: (D x cap host ints, dtype, scale, bound)} or None."""
+        out = {}
+        for name in names:
+            per = []
+            for b in batches:
+                got = None if b is None else _topk_encode(b.columns[name], name in decimal_cols)
+                if b is not None and got is None:
+                    return None
+                per.append(got)
+            present = [g for g in per if g is not None]
+            if not present:
+                return None
+            dtype_str = next(b for b in batches if b is not None).columns[name].dtype_str
+            scale = max(g[1] for g in present)
+            bound = max(g[2] * 10 ** (scale - g[1]) for g in present)
+            packed = np.zeros((D, cap), np.int32 if bound <= I32_MAX else np.int64)
+            for d, g in enumerate(per):
+                if g is not None:
+                    packed[d, : len(g[0])] = g[0].astype(np.int64) * 10 ** (scale - g[1])
+            out[name] = (packed, dtype_str, scale, bound)
+        return out
+
+    l_enc = encode_side(l_sorted, shape.columns("l"), cap_l, decimals)
+    o_enc = encode_side(o_sorted, o_names, cap_o, set())
+    if l_enc is None or o_enc is None:
+        return None, "encoding"
+
+    # no dimension side: a one-slot placeholder operand
+    dim_mode, dim_index, dim_min, dim_rows, d_enc, vocab = "none", np.zeros(1, np.int32), 0, 0, {}, {}
+    dim_cap = 1
+    if shape.dim_scan is not None:
+        keys = dim_batch.columns[shape.dim_key]
+        if is_string(keys.dtype_str) or keys.data.dtype.kind not in "iu":
+            return None, "encoding"
+        kv = keys.data.astype(np.int64)
+        dim_rows = len(kv)
+        if len(np.unique(kv)) != dim_rows:
+            return None, "not_unique"
+        order = np.argsort(kv, kind="stable")
+        kv = kv[order]
+        dim_cap = next_pow2(max(dim_rows, 1))
+        # kv: the dimension's keys, read from its source files (host)
+        dim_min = int(kv.min(initial=0)) if dim_rows else 0
+        span = int(kv.max(initial=0)) - dim_min + 1 if dim_rows else 1
+        if span <= 4 * dim_rows + 1024:
+            dim_mode = "dense"
+            dim_index = np.full(next_pow2(span), -1, np.int32)
+            dim_index[kv - dim_min] = np.arange(dim_rows, dtype=np.int32)
+        else:
+            dim_mode = "sorted"
+            dim_index = np.full(dim_cap, np.iinfo(np.int64).max, np.int64)
+            dim_index[:dim_rows] = kv
+        for name in shape.columns("d"):
+            if name == shape.dim_key:
+                continue
+            col = dim_batch.columns[name]
+            data = col.data[order]
+            if is_string(col.dtype_str):
+                vocab[name] = col.vocab
+                ints, scale, bound = data.astype(np.int32), 0, int(np.abs(data).max(initial=0))
+            else:
+                got = _topk_encode(Column(col.dtype_str, data), False)
+                if got is None:
+                    return None, "encoding"
+                ints, scale, bound = got
+            packed = np.zeros(dim_cap, ints.dtype)
+            packed[:dim_rows] = ints
+            d_enc[name] = (packed, col.dtype_str, scale, bound)
+
+    nbytes = int(lo.nbytes + hi.nbytes) + sum(
+        int(v[0].nbytes) for enc in (l_enc, o_enc, d_enc) for v in enc.values()
+    ) + int(dim_index.nbytes) * D
+    nbytes += sum(int(v[0].nbytes) * (D - 1) for v in d_enc.values())
+    with cache._lock:
+        headroom = _budget_bytes() - sum(t.nbytes for t in cache._tables)
+    if nbytes > headroom:
+        metrics.incr(f"{pfx}.join.over_budget_refused")
+        return None, "budget"
+    sharded = NamedSharding(mesh, PartitionSpec(mesh.axis_names[0], None))
+    replicated = NamedSharding(mesh, PartitionSpec())
+    try:
+        def put(enc, sharding):
+            return {n: TopkColumn(jax.device_put(a, sharding), dt, s, b)
+                    for n, (a, dt, s, b) in enc.items()}
+
+        l_cols, o_cols, dim_cols = put(l_enc, sharded), put(o_enc, sharded), put(d_enc, replicated)
+        dev_lo, dev_hi = jax.device_put(lo, sharded), jax.device_put(hi, sharded)
+        dev_index = jax.device_put(dim_index, replicated)
+        from ..ops import fence_chain
+
+        fence_chain([c.array for side in (l_cols, o_cols, dim_cols) for c in side.values()]
+                    + [dev_lo, dev_hi, dev_index])
+    except Exception:  # noqa: BLE001 - device loss: no residency
+        metrics.incr(f"{pfx}.join.transfer_error")
+        return None, "device"
+    metrics.incr(f"{pfx}.join.h2d_bytes", nbytes)
+    _trace_bytes("h2d_bytes", nbytes)
+    metrics.record_time(f"{pfx}.join_topk.prefetch", time.perf_counter() - t0)
+    return MeshTopkRegion(
+        shape.region_key(), mesh, D, cap_l, cap_o, l_cols, o_cols, dev_lo, dev_hi, fanout,
+        dim_mode, dev_index, dim_cols, vocab, dim_min, dim_rows, dim_cap,
+        n_l=sum(l_rows), n_r=sum(o_rows), nbytes=nbytes,
+    ), ""
+
+
+def _lit_scale(v) -> Optional[tuple]:
+    """(integer, scale) of a numeric literal held exactly in decimal."""
+    if isinstance(v, (bool, np.bool_)):
+        return None
+    if isinstance(v, (int, np.integer)):
+        return int(v), 0
+    if isinstance(v, (float, np.floating)):
+        s = _decimal_scale(np.array([float(v)]))
+        return None if s is None else (int(round(float(v) * 10**s)), s)
+    return None
+
+
+def fixed_point(expr, cols: Dict[str, TopkColumn]):
+    """(structure, scale, bound) of a sum's input evaluated exactly in
+    scaled int64 over the region's columns, or None: ``/``, a literal
+    that is no exact decimal, or an intermediate that could pass 2**62.
+    ``structure`` is a nested tuple the device body evaluates."""
+    from ..plan.expr import Arith, Col, Lit
+
+    if isinstance(expr, str):
+        expr = Col(expr)
+    if isinstance(expr, Col):
+        c = cols.get(expr.name)
+        return None if c is None else (("col", expr.name), c.scale, c.bound)
+    if isinstance(expr, Lit):
+        got = _lit_scale(expr.value)
+        return None if got is None else (("lit", got[0]), got[1], abs(got[0]))
+    if not isinstance(expr, Arith) or expr.op == "div":
+        return None
+    a, b = fixed_point(expr.left, cols), fixed_point(expr.right, cols)
+    if a is None or b is None:
+        return None
+    if expr.op == "mul":
+        out = (("mul", a[0], b[0]), a[1] + b[1], a[2] * b[2])
+    else:
+        s = max(a[1], b[1])
+        fa, fb = 10 ** (s - a[1]), 10 ** (s - b[1])
+        out = ((expr.op, ("mul", a[0], ("lit", fa)), ("mul", b[0], ("lit", fb))), s,
+               a[2] * fa + b[2] * fb)
+    return out if out[2] < _SUM_BOUND else None
+
+
+def _eval_fixed(jnp, structure, arrays):
+    op = structure[0]
+    if op == "col":
+        return arrays[structure[1]].astype(jnp.int64)
+    if op == "lit":
+        return jnp.int64(structure[1])
+    a = _eval_fixed(jnp, structure[1], arrays)
+    b = _eval_fixed(jnp, structure[2], arrays)
+    return a * b if op == "mul" else (a + b if op == "add" else a - b)
+
+
+@dataclass(frozen=True)
+class TopkPlan:
+    """The static half of one top-k dispatch: the compile-cache key."""
+
+    caps: tuple  # (D, cap_l, cap_o, dim_mode, dim_cap)
+    l_cols: tuple  # names of the L operands, in order
+    o_cols: tuple
+    d_cols: tuple
+    l_pred: Optional[str]  # narrowed predicate structures
+    o_pred: Optional[str]
+    d_pred: Optional[str]
+    sums: tuple  # per aggregate: fixed-point structure, or None for count(*)
+    rank: int  # the aggregate the first sort key names
+    descending: bool
+    fk: Optional[str]
+    fk_side: str
+    dim_min: int
+    out_cols: tuple  # O columns shipped with each candidate
+    k: int
+    n: int
+
+
+def _bind_predicate(pred, cols: Dict[str, TopkColumn], vocab=None):
+    """(narrowed expr, structure, literals) of a side's filter over the
+    region's columns, literals integers, or None when it cannot ride:
+    a float column, a literal that is no integer, or an unknown node."""
+    from ..plan.expr import bind_string_literals
+    from .hbm_cache import _expr_structure
+
+    if pred is None:
+        return None, None, []
+    if any(c not in cols or cols[c].scale for c in pred.columns()):
+        return None
+    if vocab:
+        shim = ColumnarBatch({n: Column("string", np.empty(0, np.int32), v) for n, v in vocab.items()})
+        try:
+            pred = bind_string_literals(pred, shim)
+        except HyperspaceException:  # unbindable shape: host
+            return None
+    if any(cols[c].dtype_str in ("float32", "float64") for c in pred.columns()):
+        return None
+    lits: list = []
+    _literals(pred, lits)
+    if not all(isinstance(v, (int, np.integer)) and not isinstance(v, bool) for v in lits):
+        return None
+    try:
+        structure = _expr_structure(pred)
+    except TypeError:  # a node the traced evaluator does not take
+        return None
+    return pred, structure, [int(v) for v in lits]
+
+
+def _literals(e, out: list) -> None:
+    """A narrowed predicate's literal values, in the order
+    hbm_cache._eval_with_literals consumes them."""
+    from ..plan.expr import And, Cmp, Lit, Not, Or
+
+    if isinstance(e, (And, Or)):
+        _literals(e.left, out)
+        _literals(e.right, out)
+    elif isinstance(e, Not):
+        _literals(e.child, out)
+    elif isinstance(e, Cmp):
+        out.extend(s.value for s in (e.left, e.right) if isinstance(s, Lit))
+
+
+def plan_topk(region: MeshTopkRegion, shape: TopkShape):
+    """(TopkPlan, narrowed predicates, literal vectors) or (None, why)."""
+    preds, lits = [], []
+    for side, pred, cols, vocab in (
+        ("l", shape.l_pred, region.l_cols, None),
+        ("o", shape.o_pred, region.r_cols, None),
+        ("d", shape.dim_pred, region.dim_cols, region.dim_vocab),
+    ):
+        got = _bind_predicate(pred, cols, vocab)
+        if got is None:
+            return None, "predicate"
+        preds.append(got)
+        lits.append(np.array(got[2] or [0], dtype=np.int64))
+    sums = []
+    for a in shape.aggs:
+        if a.fn == "count":
+            sums.append(None)
+            continue
+        got = fixed_point(a.expr if a.expr is not None else a.column, region.l_cols)
+        if got is None or got[2] * max(region.max_fanout, 1) >= _SUM_BOUND:
+            return None, "expression"
+        sums.append(got)
+    rank = [a.name for a in shape.aggs].index(shape.sort_keys[0])
+    out_cols = tuple([shape.o_key] + [g for g in shape.group_by if g != shape.key_group])
+    if shape.n + TOPK_SLACK > region.cap_o:
+        return None, "limit"
+    plan = TopkPlan(
+        (region.n_devices, region.cap_l, region.cap_o, region.dim_mode, region.dim_cap),
+        tuple(sorted(shape.columns("l"))), tuple(sorted(region.r_cols)),
+        tuple(sorted(region.dim_cols)),
+        preds[0][1], preds[1][1], preds[2][1],
+        tuple(None if s is None else (s[0], s[1]) for s in sums),
+        rank, not shape.ascending[0], shape.fk, shape.fk_side, region.dim_min,
+        out_cols, shape.n + TOPK_SLACK, shape.n,
+    )
+    return (plan, tuple(p[0] for p in preds), tuple(lits)), ""
+
+
+def mesh_topk_fn(mesh, plan: TopkPlan, preds):
+    """The jitted top-k program (profile name ``jit_mesh_join_topk``):
+    per chip, the three masks, each sum per O row as an exact int64
+    prefix difference over its L rows, and the chip's best ``k`` O rows
+    by the ranked sum; returns (D, k) candidates and a (D,) flag that a
+    chip's ties at its n-th value may not all be among them. Literals
+    are traced operands, so one compile serves every draw."""
+    key = ("jtopk", mesh, plan)
+    memo = _fn_cache()
+    fn = memo.get(key)
+    if fn is not None:
+        return fn
+
+    import jax
+    import jax.numpy as jnp
+    from jax import shard_map
+    from jax.sharding import PartitionSpec
+
+    from .hbm_cache import _eval_with_literals
+
+    axis = mesh.axis_names[0]
+    l_pred, o_pred, d_pred = preds
+    D, cap_l, cap_o, dim_mode, dim_cap = plan.caps
+    k, n = plan.k, plan.n
+
+    def mask(pred, arrays, lits, size):
+        if pred is None:
+            return jnp.ones((size,), bool)
+        return _eval_with_literals(pred, arrays, lits, [0])
+
+    def shard_fn(l_arrays, o_arrays, lo, hi, d_index, d_arrays, l_lits, o_lits, d_lits):
+        la = {c: a.reshape(-1) for c, a in zip(plan.l_cols, l_arrays)}
+        oa = {c: a.reshape(-1) for c, a in zip(plan.o_cols, o_arrays)}
+        da = dict(zip(plan.d_cols, d_arrays))
+        lm = mask(l_pred, la, l_lits, cap_l)
+        om = mask(o_pred, oa, o_lits, cap_o)
+        if dim_mode != "none":
+            fk = (la if plan.fk_side == "l" else oa)[plan.fk].astype(jnp.int64)
+            dm = mask(d_pred, da, d_lits, dim_cap)
+            if dim_mode == "dense":
+                at = fk - plan.dim_min
+                inside = (at >= 0) & (at < d_index.shape[0])
+                row = d_index[jnp.clip(at, 0, d_index.shape[0] - 1)]
+                member = inside & (row >= 0) & dm[jnp.clip(row, 0, dim_cap - 1)]
+            else:
+                pos = jnp.clip(jnp.searchsorted(d_index, fk), 0, dim_cap - 1)
+                member = (d_index[pos] == fk) & dm[pos]
+            if plan.fk_side == "l":
+                lm = lm & member
+            else:
+                om = om & member
+        lo_, hi_ = lo.reshape(-1), hi.reshape(-1)
+
+        def range_sum(x):
+            cum = jnp.concatenate([jnp.zeros((1,), x.dtype), jnp.cumsum(x)])
+            return cum[hi_] - cum[lo_]
+
+        count = range_sum(lm.astype(jnp.int32))
+        sums = [count.astype(jnp.int64) if s is None else
+                range_sum(jnp.where(lm, _eval_fixed(jnp, s[0], la), jnp.int64(0)))
+                for s in plan.sums]
+        valid = om & (count > 0)
+        score = sums[plan.rank].astype(jnp.float32)
+        score = jnp.where(valid, score if plan.descending else -score, -jnp.inf)
+        best, at = jax.lax.top_k(score, k)
+        tie = (best[n - 1] > -jnp.inf) & (best[k - 1] == best[n - 1]) if k < cap_o else jnp.bool_(False)
+        outs = [valid[at]] + [s[at] for s in sums] + [oa[c][at] for c in plan.out_cols]
+        return tuple(o.reshape(1, k) for o in outs) + (tie.reshape(1),)
+
+    p_dev, rep = PartitionSpec(axis, None), PartitionSpec()
+    n_out = 1 + len(plan.sums) + len(plan.out_cols)
+    body = shard_map(
+        shard_fn,
+        mesh=mesh,
+        in_specs=(
+            tuple(p_dev for _ in plan.l_cols), tuple(p_dev for _ in plan.o_cols), p_dev, p_dev,
+            rep, tuple(rep for _ in plan.d_cols), rep, rep, rep,
+        ),
+        out_specs=tuple(p_dev for _ in range(n_out)) + (PartitionSpec(axis),),
+        check_vma=False,
+    )
+
+    def mesh_join_topk(*args):
+        return body(*args)
+
+    fn = jax.jit(mesh_join_topk)
+    memo.put(key, fn)
+    return fn
+
+
+def finish_topk(region: MeshTopkRegion, shape: TopkShape, plan: TopkPlan, outs) -> Optional[ColumnarBatch]:
+    """The answer from the chips' candidates: the valid ones as rows of
+    the Aggregate's output, ordered by the Sort's keys on the host
+    (exec.order), the first ``n``. None when a chip flagged ties past its
+    candidates (the caller routes host)."""
+    from ..plan.aggregates import input_dtype, output_dtype
+    from .order import limit_batch, sort_batch
+
+    # outs: host arrays, fetched once by the dispatch (mesh_cache.join_topk)
+    if bool(outs[-1].any()):
+        return None
+    keep = np.flatnonzero(outs[0].reshape(-1))
+    sums = [o.reshape(-1)[keep] for o in outs[1: 1 + len(plan.sums)]]
+    ocols = {c: o.reshape(-1)[keep] for c, o in zip(plan.out_cols, outs[1 + len(plan.sums): -1])}
+    schema = {**{c: region.r_cols[c].dtype_str for c in region.r_cols},
+              **{c: region.l_cols[c].dtype_str for c in region.l_cols}}
+    out: Dict[str, Column] = {}
+    for g in shape.group_by:
+        src = shape.o_key if g == shape.key_group else g
+        dt = shape.o_node.entry.schema.get(g) or shape.l_node.entry.schema.get(g) or schema[src]
+        out[g] = Column(dt, ocols[src].astype(numpy_dtype(dt)))
+    for a, s, got in zip(shape.aggs, plan.sums, sums):
+        dt = output_dtype(a, input_dtype(a, schema))
+        if dt.startswith("float"):
+            out[a.name] = Column(dt, got.astype(np.float64) / 10.0 ** s[1])
+        else:
+            out[a.name] = Column(dt, got.astype(np.int64))
+    batch = ColumnarBatch(out)
+    return limit_batch(sort_batch(batch, shape.sort_keys, shape.ascending), shape.n)

@@ -389,6 +389,16 @@ class MeshHbmCache(ResidentCacheBase):
 
     _metric_prefix = "hbm.mesh"
 
+    def __init__(self) -> None:
+        super().__init__()
+        # top-k region key -> why it can never be built (note_touch_topk)
+        self._topk_refused: dict = {}
+
+    def reset(self) -> None:
+        super().reset()
+        with self._lock:
+            self._topk_refused.clear()
+
     # -- population ----------------------------------------------------------
     def prefetch(
         self, files: List[str | Path], columns: List[str], mesh
@@ -1692,6 +1702,112 @@ class MeshHbmCache(ResidentCacheBase):
         )
         _trace_bytes("d2h_bytes", sum(int(o.nbytes) for o in outs))
         return finish_join_agg(region, plan, list(group_by), list(aggs), outs)
+
+    # -- the filtered join-aggregate top-k (ORDER BY ... LIMIT) -------------
+    def topk_for(self, shape, mesh):
+        """The resident top-k region ``shape`` reads, or None."""
+        from .hbm_cache import residency_mode
+
+        if residency_mode() == "off":
+            return None
+        try:
+            key = shape.region_key()
+        except OSError:
+            return None
+        with self._lock:
+            for j in reversed(self._joins):
+                if j.key == key and j.mesh is mesh:
+                    j.last_used = time.monotonic()
+                    return j
+        return None
+
+    def note_touch_topk(self, shape, loader, mesh) -> None:
+        """Schedule the region ``shape`` reads in the background (the
+        note_touch contract: never blocks, never throws). A region that
+        can never be built (a join key or dimension key that repeats, a
+        column that cannot ride exactly) is remembered with its reason,
+        and each later query counts ``hbm.mesh.join_topk.declined.<why>``
+        as it goes to the host."""
+        from .join_residency import build_topk_region
+
+        try:
+            key = shape.region_key() if _auto_enabled() else None
+        except OSError:  # an index file went: a refresh is under way
+            key = None
+        if key is None:
+            metrics.incr("hbm.mesh.join_topk.declined.residency")
+            return
+        pending = ("topk", key)
+        with self._lock:
+            refused = self._topk_refused.get(key)
+            busy = pending in self._pending
+            if refused is None and not busy:
+                self._pending.add(pending)
+                epoch = self._epoch
+        if refused is not None:
+            metrics.incr(f"hbm.mesh.join_topk.declined.{refused}")
+            return
+        if busy:  # the region is being built: this query goes host
+            metrics.incr("hbm.mesh.join_topk.declined.pending")
+            return
+
+        def bg():
+            why = ""
+            try:
+                groups = loader()
+                region, why = build_topk_region(self, shape, *groups, mesh)
+                if region is not None and not self._register_join(region, epoch=epoch):
+                    why = "budget"
+            except Exception:  # noqa: BLE001 - population must never fail a query
+                metrics.incr("hbm.mesh.join.populate_failed")
+            finally:
+                with self._lock:
+                    self._pending.discard(pending)
+                    if why in ("not_unique", "encoding"):
+                        if len(self._topk_refused) >= _MAX_FAILED_MEMO:
+                            self._topk_refused.clear()
+                        self._topk_refused[key] = why
+            if why:
+                metrics.incr(f"hbm.mesh.join_topk.declined.{why}")
+
+        t = threading.Thread(target=bg, daemon=True, name="hbm-mesh-topk-populate")
+        self._track_for_exit(t)
+        t.start()
+
+    def join_topk(self, region, shape):
+        """One filtered join-aggregate top-k over ``region`` (module note
+        of exec.join_residency): operands, launch, wait, the D2H of the
+        chips' candidates and the host's final choice, all in the
+        ``scan.join_topk.dispatch`` span. None when the query cannot ride
+        (counted ``hbm.mesh.join_topk.declined.<why>``; the caller routes
+        host); device errors propagate."""
+        from jax import enable_x64
+
+        from .join_residency import finish_topk, mesh_topk_fn, plan_topk
+
+        with _trace_span("scan.join_topk.dispatch", mesh=region.n_devices, k=shape.n):
+            got, why = plan_topk(region, shape)
+            if got is None:
+                metrics.incr(f"hbm.mesh.join_topk.declined.{why}")
+                return None
+            plan, preds, lits = got
+            fn = mesh_topk_fn(region.mesh, plan, preds)
+            with enable_x64(True):
+                raw = fn(
+                    tuple(region.l_cols[c].array for c in plan.l_cols),
+                    tuple(region.r_cols[c].array for c in plan.o_cols),
+                    region.lo, region.hi, region.dim_index,
+                    tuple(region.dim_cols[c].array for c in plan.d_cols),
+                    *lits,
+                )
+                outs = [np.asarray(o) for o in raw]
+            nbytes = sum(int(o.nbytes) for o in outs)
+            metrics.incr("scan.join_topk.d2h_bytes", nbytes)
+            _trace_bytes("d2h_bytes", nbytes)
+            out = finish_topk(region, shape, plan, outs)
+            if out is None:
+                metrics.incr("hbm.mesh.join_topk.declined.ties")
+            return out
 
     # -- the fused scan-aggregate query --------------------------------------
     def agg_scan(self, table: MeshResidentTable, predicate: Expr, group_by, aggs):

@@ -12,6 +12,12 @@ within one batch).
 
 NULL semantics: string NULLs are code -1; every comparison excludes them
 (SQL-style: NULL never satisfies a predicate).
+
+Arithmetic (``Arith``: ``+ - * /`` over columns and literals) evaluates
+to values with ``eval_value``, in numpy on the host and in ``jnp`` on the
+device. Its NULLs are SQL's: a float NULL is NaN and propagates through
+every operator; division by zero is NULL; integer operands (which hold
+no NULLs) stay int64 under ``+ - *``, and ``/`` is always float64.
 """
 
 from __future__ import annotations
@@ -22,7 +28,7 @@ from typing import Any, FrozenSet, List, Optional
 import numpy as np
 
 from ..exceptions import HyperspaceException
-from ..storage.columnar import ColumnarBatch, is_string
+from ..storage.columnar import ColumnarBatch, is_string, numpy_dtype
 
 
 class Expr:
@@ -56,6 +62,31 @@ class Expr:
 
     def __ge__(self, other):
         return Cmp("ge", self, _as_expr(other))
+
+    # -- arithmetic sugar ----------------------------------------------------
+    def __add__(self, other) -> "Arith":
+        return Arith("add", self, _as_expr(other))
+
+    def __radd__(self, other) -> "Arith":
+        return Arith("add", _as_expr(other), self)
+
+    def __sub__(self, other) -> "Arith":
+        return Arith("sub", self, _as_expr(other))
+
+    def __rsub__(self, other) -> "Arith":
+        return Arith("sub", _as_expr(other), self)
+
+    def __mul__(self, other) -> "Arith":
+        return Arith("mul", self, _as_expr(other))
+
+    def __rmul__(self, other) -> "Arith":
+        return Arith("mul", _as_expr(other), self)
+
+    def __truediv__(self, other) -> "Arith":
+        return Arith("div", self, _as_expr(other))
+
+    def __rtruediv__(self, other) -> "Arith":
+        return Arith("div", _as_expr(other), self)
 
     def __hash__(self) -> int:
         return hash(repr(self))
@@ -147,6 +178,28 @@ class In(Expr):
         return f"({self.child!r} in {self.values!r})"
 
 
+_ARITH_SYMBOL = {"add": "+", "sub": "-", "mul": "*", "div": "/"}
+
+
+@dataclass(frozen=True, eq=False)
+class Arith(Expr):
+    """A binary arithmetic value: ``op`` one of add, sub, mul, div."""
+
+    op: str
+    left: Expr
+    right: Expr
+
+    def __post_init__(self):
+        if self.op not in _ARITH_SYMBOL:
+            raise HyperspaceException(f"Unknown arithmetic op {self.op}.")
+
+    def columns(self) -> FrozenSet[str]:
+        return self.left.columns() | self.right.columns()
+
+    def __repr__(self) -> str:
+        return f"({self.left!r} {_ARITH_SYMBOL[self.op]} {self.right!r})"
+
+
 def col(name: str) -> Col:
     return Col(name)
 
@@ -201,6 +254,75 @@ def _apply_cmp(xp, op: str, a, b):
     if op == "ge":
         return a >= b
     raise HyperspaceException(f"Unknown comparison op {op}.")
+
+
+def value_dtype(expr: Expr, schema) -> str:
+    """The dtype string of a value expression over ``schema`` ({column:
+    dtype_str}): a column's own; int64 or float64 for a literal; int64
+    for ``+ - *`` over integers (dates count as integers) and float64
+    for ``/`` or any float operand. Strings cannot take arithmetic."""
+    if isinstance(expr, Col):
+        if expr.name not in schema:
+            raise HyperspaceException(f"Unknown column {expr.name!r}.")
+        return schema[expr.name]
+    if isinstance(expr, Lit):
+        v = expr.value
+        if isinstance(v, (bool, np.bool_)) or not isinstance(
+            v, (int, float, np.integer, np.floating)
+        ):
+            raise HyperspaceException(f"Not a numeric literal: {v!r}.")
+        return "float64" if isinstance(v, (float, np.floating)) else "int64"
+    if isinstance(expr, Arith):
+        kinds = [value_dtype(e, schema) for e in (expr.left, expr.right)]
+        if any(is_string(k) or k == "bool" for k in kinds):
+            raise HyperspaceException(f"Arithmetic over a non-number: {expr!r}.")
+        if expr.op == "div" or any(k.startswith("float") for k in kinds):
+            return "float64"
+        return "int64"
+    raise HyperspaceException(f"Not a value expression: {expr!r}.")
+
+
+def eval_value(expr: Expr, batch: ColumnarBatch, arrays=None):
+    """Evaluate a value expression (``Col``, ``Lit``, ``Arith``) to an
+    array of ``value_dtype(expr, batch.schema())``. ``arrays=None``:
+    numpy over the batch; ``arrays=dict``: jax.numpy over those device
+    arrays (the batch gives the schema). NULL propagation is NaN's:
+    float NULLs propagate, and division by zero gives NULL."""
+    if arrays is None:
+        return _eval_value(np, expr, batch, lambda name: batch.columns[name].data, batch.num_rows)
+    import jax.numpy as jnp  # type: ignore
+    from jax import enable_x64
+
+    n = next(iter(arrays.values())).shape[0] if arrays else batch.num_rows
+    with enable_x64(True):
+        return _eval_value(jnp, expr, batch, lambda name: arrays[name], n)
+
+
+def _eval_value(xp, expr: Expr, batch: ColumnarBatch, get, n: int):
+    schema = batch.schema()
+
+    def ev(e: Expr):
+        dt = value_dtype(e, schema)
+        if isinstance(e, Col):
+            return get(e.name)
+        if isinstance(e, Lit):
+            return e.value
+        a, b = ev(e.left), ev(e.right)
+        want = np.float64 if dt == "float64" else np.int64
+        a = xp.asarray(a).astype(want)
+        b = xp.asarray(b).astype(want)
+        if e.op == "add":
+            return a + b
+        if e.op == "sub":
+            return a - b
+        if e.op == "mul":
+            return a * b
+        zero = b == 0
+        return xp.where(zero, xp.nan, a / xp.where(zero, 1.0, b))
+
+    out = ev(expr)
+    want = numpy_dtype(value_dtype(expr, schema))
+    return xp.broadcast_to(xp.asarray(out).astype(want), (n,))
 
 
 def eval_mask(expr: Expr, batch: ColumnarBatch, arrays=None):
@@ -270,6 +392,12 @@ def eval_mask(expr: Expr, batch: ColumnarBatch, arrays=None):
             if is_string(lc.dtype_str):
                 m = m & (get(left.name) >= 0) & (get(right.name) >= 0)
             return m
+        if isinstance(left, (Arith, Col, Lit)) and isinstance(right, (Arith, Col, Lit)):
+            # a side that computes: compare values (NaN, the float NULL,
+            # satisfies nothing but ``ne``, as for a float column)
+            a = eval_value(left, batch, arrays)
+            b = eval_value(right, batch, arrays)
+            return _apply_cmp(xp, op, a, b)
         raise HyperspaceException(f"Unsupported comparison shape: {e!r}.")
 
     def ev_in(e: In):
@@ -313,6 +441,8 @@ def resolve_expr_columns(expr: Expr, available) -> Expr:
         if isinstance(e, In):
             child = walk(e.child)
             return In(child, e.values) if child is not e.child else e
+        if isinstance(e, Arith):
+            return Arith(e.op, walk(e.left), walk(e.right))
         if isinstance(e, Col):
             m = resolver.resolve(e.name, list(available))
             return Col(m) if m is not None and m != e.name else e

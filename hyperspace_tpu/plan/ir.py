@@ -1,7 +1,8 @@
 """The logical plan IR — the framework's replacement for Catalyst plans.
 
 Nodes are deliberately at the altitude the reference's rules actually
-consume: Scan (LogicalRelation), Filter, Project, Join, plus the two nodes
+consume: Scan (LogicalRelation), Filter, Project, Join (with Aggregate,
+Sort and Limit above them, which Spark supplies the reference), plus the two nodes
 the rewrite layer introduces — IndexScan (the swapped-in index relation,
 printing the same ``Hyperspace(Type: CI, Name, LogVersion)`` marker as
 IndexHadoopFsRelation.scala:42-47) and BucketUnion (the partition-
@@ -260,7 +261,7 @@ class Aggregate(LogicalPlan):
         return list(
             dict.fromkeys(
                 list(self.group_by)
-                + [a.column for a in self.aggs if a.column is not None]
+                + [c for a in self.aggs for c in a.input_columns()]
             )
         )
 
@@ -275,19 +276,78 @@ class Aggregate(LogicalPlan):
         return list(self.group_by) + [a.name for a in self.aggs]
 
     def output_schema(self) -> Dict[str, str]:
-        from .aggregates import output_dtype
+        from .aggregates import input_dtype, output_dtype
 
         child_schema = self.child.output_schema()
         out = {c: child_schema[c] for c in self.group_by}
         for a in self.aggs:
-            out[a.name] = output_dtype(
-                a, child_schema.get(a.column) if a.column else None
-            )
+            out[a.name] = output_dtype(a, input_dtype(a, child_schema))
         return out
 
     def describe(self) -> str:
-        parts = [f"{a.fn}({a.column or '*'}) AS {a.name}" for a in self.aggs]
+        parts = [a.describe() for a in self.aggs]
         return f"Aggregate [{', '.join(self.group_by)}] [{', '.join(parts)}]"
+
+
+@dataclass(frozen=True)
+class Sort(LogicalPlan):
+    """ORDER BY: rows ordered by ``keys``, each ascending or not
+    (``ascending``, one flag a key); Spark's NULL order: first when
+    ascending, last when descending (a float NULL is NaN). Ties keep the
+    child's row order."""
+
+    keys: Tuple[str, ...]
+    ascending: Tuple[bool, ...]
+    child: LogicalPlan
+
+    def __post_init__(self):
+        if not self.keys or len(self.keys) != len(self.ascending):
+            raise HyperspaceException("Sort needs one direction per key.")
+
+    @property
+    def children(self):
+        return (self.child,)
+
+    def with_children(self, children):
+        return replace(self, child=children[0])
+
+    def output_columns(self) -> List[str]:
+        return self.child.output_columns()
+
+    def output_schema(self) -> Dict[str, str]:
+        return self.child.output_schema()
+
+    def describe(self) -> str:
+        parts = [f"{k} {'ASC' if a else 'DESC'}" for k, a in zip(self.keys, self.ascending)]
+        return f"Sort [{', '.join(parts)}]"
+
+
+@dataclass(frozen=True)
+class Limit(LogicalPlan):
+    """LIMIT ``n``: the first ``n`` rows of the child."""
+
+    n: int
+    child: LogicalPlan
+
+    def __post_init__(self):
+        if self.n < 0:
+            raise HyperspaceException("Limit takes a count of at least 0.")
+
+    @property
+    def children(self):
+        return (self.child,)
+
+    def with_children(self, children):
+        return replace(self, child=children[0])
+
+    def output_columns(self) -> List[str]:
+        return self.child.output_columns()
+
+    def output_schema(self) -> Dict[str, str]:
+        return self.child.output_schema()
+
+    def describe(self) -> str:
+        return f"Limit [{self.n}]"
 
 
 @dataclass(frozen=True)

@@ -1,7 +1,9 @@
 """Rewrite-rule batch: JoinIndexRule before FilterIndexRule, matching the
 registration order and its rationale in the reference (package.scala:25-35:
 join rewrites are strictly more constrained, so they get first claim on
-scans; filter rewrites then pick up what's left).
+scans; filter rewrites then pick up what's left). JoinReorderRule, which
+the reference lacks, runs first: it brings a pair of indexable sides of a
+join tree together so that JoinIndexRule can see them (join_reorder.py).
 """
 
 from __future__ import annotations
@@ -12,6 +14,7 @@ from ...config import HyperspaceConf
 from ...index.log_entry import IndexLogEntry
 from ..ir import LogicalPlan
 from .filter_rule import FilterIndexRule
+from .join_reorder import JoinReorderRule
 from .join_rule import JoinIndexRule
 
 
@@ -25,6 +28,7 @@ def apply_hyperspace_rules(
     from .data_skipping_rule import DataSkippingFilterRule
 
     applied: List[IndexLogEntry] = []
+    plan = JoinReorderRule().apply(plan, indexes, conf)
     plan, a = JoinIndexRule().apply(plan, indexes, conf)
     applied.extend(a)
     plan, a = FilterIndexRule().apply(plan, indexes, conf)

@@ -263,6 +263,8 @@ def _classify_join_aggregate(
 
     if session.mesh is not None and session.mesh.devices.size > 1:
         return None
+    if any(a.expr is not None for a in agg.aggs):
+        return None  # the region's programs aggregate columns only
     oriented = orient_join_aggregate(agg)
     if oriented is None:
         return None
